@@ -8,8 +8,11 @@ hot path (``window_gather.c``).
 
 Build model: the shared object is compiled ON DEMAND from the checked-in C
 source with whatever C compiler the host has (cc/gcc/clang), cached next to
-the source, and loaded with ctypes — no pybind11, no setuptools extension
-step, no numpy C API. Hosts without a compiler simply report
+the source under a name that carries a hash of that source, and loaded with
+ctypes — no pybind11, no setuptools extension step, no numpy C API. The
+object is git-ignored; a tree copied with one lying in it (file times do not
+survive every copy) can therefore only ever load the build of the source it
+holds. Hosts without a compiler simply report
 ``available() == False`` and callers use their pure-numpy path; behavior is
 identical either way (asserted by tests/test_native.py).
 """
@@ -17,6 +20,7 @@ identical either way (asserted by tests/test_native.py).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -25,7 +29,6 @@ import threading
 import numpy as np
 
 _SRC = os.path.join(os.path.dirname(__file__), "window_gather.c")
-_SO = os.path.join(os.path.dirname(__file__), "_window_gather.so")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
@@ -45,13 +48,27 @@ def _compiler() -> str | None:
     return None
 
 
-def _build_and_load() -> ctypes.CDLL | None:
+def so_path() -> str | None:
+    """Where the build of the CURRENT source is cached, or None when the
+    source is missing."""
     try:
-        if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return ctypes.CDLL(_SO)
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha1(f.read()).hexdigest()[:12]
     except OSError:
-        # Stale/foreign cached .so (other arch/glibc) or missing source:
-        # fall through to a rebuild, or to the numpy path below.
+        return None
+    return os.path.join(os.path.dirname(_SRC), f"_window_gather-{digest}.so")
+
+
+def _build_and_load() -> ctypes.CDLL | None:
+    so = so_path()
+    if so is None:
+        return None
+    try:
+        if os.path.exists(so):
+            return ctypes.CDLL(so)
+    except OSError:
+        # Foreign cached .so (other arch/glibc): fall through to a rebuild,
+        # or to the numpy path below.
         pass
     cc = _compiler()
     if cc is None:
@@ -59,12 +76,12 @@ def _build_and_load() -> ctypes.CDLL | None:
     # Per-process tmp name: two processes building concurrently must not
     # interleave compiler output in one file — os.replace then guarantees
     # whichever finishes last installs a COMPLETE object.
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = cc.split() + ["-O3", "-shared", "-fPIC", "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return ctypes.CDLL(_SO)
+        os.replace(tmp, so)
+        return ctypes.CDLL(so)
     except (subprocess.SubprocessError, OSError):
         return None
     finally:
@@ -98,6 +115,11 @@ def _get_lib() -> ctypes.CDLL | None:
 def available() -> bool:
     """True when the native gather compiled and loaded on this host."""
     return _get_lib() is not None
+
+
+def describe() -> str:
+    """Which gather path the dataloader takes on this host, for banners."""
+    return "native (C)" if available() else "numpy (no C compiler)"
 
 
 def gather_windows(

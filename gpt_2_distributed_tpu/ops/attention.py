@@ -102,11 +102,12 @@ def select_attention_impl(impl: str, seq_len: int):
         flash_attention_bthd,
         pick_block_q,
     )
+    from gpt_2_distributed_tpu.ops.spmd import record_resolved_impl
 
-    if impl == "dense":
-        return causal_attention_bthd
-    if impl == "flash":
-        return flash_attention_bthd
+    if impl not in ("dense", "flash", "ring", "auto"):
+        raise ValueError(
+            f"unknown attention_impl {impl!r}; expected dense|flash|ring|auto"
+        )
     if impl in ("ring", "auto"):
         mesh = _ring_mesh()
         if mesh is not None:
@@ -114,14 +115,14 @@ def select_attention_impl(impl: str, seq_len: int):
                 ring_attention_bthd,
             )
 
+            record_resolved_impl("attention", "ring")
             return functools.partial(ring_attention_bthd, mesh=mesh)
-        import jax
-
         flash_ok = (
             pick_block_q(seq_len) is not None
             and jax.devices()[0].platform == "tpu"
         )
-        return flash_attention_bthd if flash_ok else causal_attention_bthd
-    raise ValueError(
-        f"unknown attention_impl {impl!r}; expected dense|flash|ring|auto"
-    )
+        impl = "flash" if flash_ok else "dense"
+    if impl == "flash":
+        return flash_attention_bthd  # reports how its kernel runs itself
+    record_resolved_impl("attention", "dense (xla)")
+    return causal_attention_bthd

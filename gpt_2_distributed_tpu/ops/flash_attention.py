@@ -102,6 +102,8 @@ from gpt_2_distributed_tpu.ops.spmd import (  # noqa: E402 — after module docs
     HEAD_AXIS_NAMES,
     dividing_axes,
     dropout_hash_bits,
+    pallas_mode,
+    record_resolved_impl,
 )
 
 
@@ -514,6 +516,7 @@ def flash_attention(
     block_k = pick_block_q(t, block_k if block_k is not None else dk_)
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    record_resolved_impl("attention", f"flash ({pallas_mode(interpret)})")
     rate = float(dropout_rate) if (not deterministic and rng is not None) else 0.0
     if rate > 0.0:
         # Fold the jax PRNG key down to one int32 kernel seed.

@@ -58,6 +58,7 @@ from gpt_2_distributed_tpu.ops.flash_attention import (
     _dropout_bits,
     pick_block_q,
 )
+from gpt_2_distributed_tpu.ops.spmd import pallas_mode, record_resolved_impl
 
 # Same rationale as flash_attention: (b, h, qi) parallel in fwd; the bwd's
 # revisited dk/dv accumulators need qi "arbitrary".
@@ -405,6 +406,7 @@ def flash_block(
         )
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    record_resolved_impl("flash_block", f"pallas ({pallas_mode(interpret)})")
     if seed is None:
         seed = jnp.zeros((1,), jnp.int32)
     scalars = jnp.concatenate([

@@ -67,31 +67,6 @@ from gpt_2_distributed_tpu.ops.spmd import (
     record_fused_fallback,
 )
 
-# jax 0.4.37 names this TPUCompilerParams; newer releases renamed it. Resolve
-# once so these kernels run under either pin (flash_attention.py predates the
-# pin and uses the new name — it only runs where that name exists).
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: the pinned 0.4.37 only has the
-    experimental location (with check_rep), newer releases promote it to
-    jax.shard_map (with check_vma). The check is off either way — the
-    kernels' replication structure is plain batch splitting, and the hash
-    seed mixing intentionally differs per shard."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
 # Per-op dropout stream salts, mixed in as the hash's head coordinate so the
 # three fused sites (and flash attention, which hashes real head indices but
 # a different seed) never share bits within one layer application.
@@ -183,7 +158,11 @@ def _mesh_axes(batch_dim: int):
     re-gather what GSPMD deliberately sharded — fall back to the unfused XLA
     path there (degraded-not-wrong). A multi-device mesh whose batch-like
     axes don't divide the batch dim also falls back: the operands may be
-    sharded, and an unwrapped Mosaic call would fail to partition."""
+    sharded, and an unwrapped Mosaic call would fail to partition.
+
+    Callers wrap the kernel in ``jax.shard_map(..., check_vma=False)``: the
+    replication structure is plain batch splitting, and ``_shard_seed``
+    makes the dropout seed differ per shard on purpose."""
     mesh = _ambient_mesh()
     if mesh is None:
         return None, ()
@@ -344,7 +323,7 @@ def _build_ln_res_drop(
                 jax.ShapeDtypeStruct((n, 1), jnp.float32),
                 jax.ShapeDtypeStruct((n, 1), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
@@ -388,7 +367,7 @@ def _build_ln_res_drop(
                 jax.ShapeDtypeStruct((1, c), jnp.float32),
                 jax.ShapeDtypeStruct((1, c), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
@@ -449,7 +428,7 @@ def _build_res_drop(rate: float, block_rows: int, c: int, salt: int, interpret: 
             ),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
@@ -557,7 +536,7 @@ def _build_bias_gelu_drop(
             ),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
@@ -591,7 +570,7 @@ def _build_bias_gelu_drop(
                 jax.ShapeDtypeStruct(h.shape, h.dtype),
                 jax.ShapeDtypeStruct((1, f), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",),
             ),
             interpret=interpret,
@@ -658,10 +637,11 @@ def fused_ln_residual_dropout(
         def _local(x, o, scale, bias, seed):
             return _call(x, o, scale, bias, _shard_seed(seed, mesh, b_axes, rate_eff))
 
-        return _shard_map(
+        return jax.shard_map(
             _local, mesh=mesh,
             in_specs=(spec, spec, P(None), P(None), P(None)),
             out_specs=(spec, spec),
+            check_vma=False,
         )(x, o, scale, bias, seed)
     return _call(x, o, scale, bias, seed)
 
@@ -707,10 +687,11 @@ def fused_residual_dropout(
         def _local(x, o, seed):
             return _call(x, o, _shard_seed(seed, mesh, b_axes, rate_eff))
 
-        return _shard_map(
+        return jax.shard_map(
             _local, mesh=mesh,
             in_specs=(spec, spec, P(None)),
             out_specs=spec,
+            check_vma=False,
         )(x, o, seed)
     return _call(x, o, seed)
 
@@ -761,9 +742,10 @@ def fused_bias_gelu_dropout(
         def _local(h, b, seed):
             return _call(h, b, _shard_seed(seed, mesh, b_axes, rate_eff))
 
-        return _shard_map(
+        return jax.shard_map(
             _local, mesh=mesh,
             in_specs=(spec, P(None), P(None)),
             out_specs=spec,
+            check_vma=False,
         )(h, b, seed)
     return _call(h, b, seed)

@@ -60,13 +60,11 @@ from jax.sharding import PartitionSpec as P
 
 from gpt_2_distributed_tpu.ops.activations import gelu_tanh
 from gpt_2_distributed_tpu.ops.fused_layer import (
-    _CompilerParams,
     _gelu_core,
     _GELU_A,
     _GELU_C0,
     _mesh_axes,
     _resolve,
-    _shard_map,
     _shard_seed,
     _threshold,
     _tile_bits,
@@ -352,7 +350,7 @@ def _build_matmul(kind: str, rate: float, bm: int, bk: int, bn: int,
                 scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             ),
             out_shape=out_shape,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -383,7 +381,7 @@ def _build_matmul(kind: str, rate: float, bm: int, bk: int, bn: int,
                 scratch_shapes=[pltpu.VMEM((bm, bk), jnp.float32)],
             ),
             out_shape=jax.ShapeDtypeStruct((n, k), g.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
             ),
             interpret=interpret,
@@ -419,7 +417,7 @@ def _build_matmul(kind: str, rate: float, bm: int, bk: int, bn: int,
                 jax.ShapeDtypeStruct((k, m), x.dtype),
                 jax.ShapeDtypeStruct((1, m), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             ),
             interpret=interpret,
@@ -533,20 +531,22 @@ def _dispatch(kind: str, x, w, b, r, rate, rng, deterministic, interpret,
 
         if kind == "resid":
             rspec = P(b_axes, *([None] * (r.ndim - 1)))
-            return _shard_map(
+            return jax.shard_map(
                 _local, mesh=mesh,
                 in_specs=(xspec, wspec, P(None), rspec, P(None)),
                 out_specs=rspec,
+                check_vma=False,
             )(x, w, b, r, seed)
 
         def _local3(x, w, b, seed):
             return _local(x, w, b, None, seed)
 
         ospec = P(b_axes, *([None] * (len(out_shape) - 1)))
-        return _shard_map(
+        return jax.shard_map(
             _local3, mesh=mesh,
             in_specs=(xspec, wspec, P(None), P(None)),
             out_specs=ospec,
+            check_vma=False,
         )(x, w, b, seed)
     return _call(x, w, b, r, seed)
 

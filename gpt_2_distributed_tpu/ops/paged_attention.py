@@ -49,12 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from gpt_2_distributed_tpu.ops.attention import MASK_VALUE
 from gpt_2_distributed_tpu.ops.flash_attention import LOG2E, NEG_INF
-
-# jax 0.4.37 names this TPUCompilerParams; newer releases renamed it
-# (same resolve-once shim as ops/fused_layer.py).
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
+from gpt_2_distributed_tpu.ops.spmd import pallas_mode, record_resolved_impl
 
 _DIMS = ("parallel", "parallel", "arbitrary")  # j carries the m/l/acc scratch
 
@@ -253,6 +248,9 @@ def paged_attention_pallas(
     m = block_table.shape[1]
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    record_resolved_impl(
+        "paged_attention", f"pallas ({pallas_mode(interpret)})"
+    )
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -280,7 +278,7 @@ def paged_attention_pallas(
         functools.partial(_paged_fwd_kernel, block_size=bs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=_DIMS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_DIMS),
         interpret=interpret,
     )(
         block_table.astype(jnp.int32),
@@ -344,4 +342,5 @@ def paged_attention(
         return paged_attention_pallas(
             q, k_pool, v_pool, block_table, lengths, interpret=interpret
         )
+    record_resolved_impl("paged_attention", "xla (gather)")
     return paged_attention_xla(q, k_pool, v_pool, block_table, lengths)

@@ -53,6 +53,7 @@ from gpt_2_distributed_tpu.ops.spmd import (
     HEAD_AXIS_NAMES,
     dividing_axes,
     dropout_hash_bits,
+    record_resolved_impl,
 )
 
 NEG_INF = -1e30  # same fill as the flash kernel (fp32 row-max stability)
@@ -326,6 +327,8 @@ def ring_attention_bthd(
             jax.devices()[0].platform == "tpu"
             and pick_block_q(T // sp) is not None
         )
+    if not use_flash:
+        record_resolved_impl("ring blocks", "xla (einsum)")  # else flash_block says
     rate = float(dropout_rate) if (not deterministic and rng is not None) else 0.0
     if rate > 0.0:
         seed = jax.random.randint(rng, (1,), 0, jnp.iinfo(jnp.int32).max, jnp.int32)

@@ -9,6 +9,8 @@ cannot drift apart.
 
 from __future__ import annotations
 
+import sys
+
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
@@ -74,6 +76,33 @@ def fused_fallback_events() -> dict[tuple[str, str], int]:
 
 def reset_fused_fallbacks() -> None:
     _FUSED_FALLBACKS.clear()
+
+
+# --- resolved-implementation visibility --------------------------------------
+#
+# Attention picks its implementation at TRACE time from what it can observe:
+# the platform (Pallas compiled by Mosaic on a TPU, interpret mode or plain
+# XLA elsewhere), the shape, the active mesh. Each choice is right for its
+# platform and silent by nature, so a run that was meant for the chip and
+# landed on the dense path, the XLA gather or the interpreter would look
+# healthy. Every dispatch site says once which way it went; `chip_smoke.py`
+# reads these lines and fails a chip run that resolved to anything else.
+
+_RESOLVED_IMPLS: set[tuple[str, str]] = set()
+
+
+def record_resolved_impl(site: str, impl: str) -> None:
+    """Print, once per process and (site, impl), the implementation ``site``
+    resolved to. stderr: several CLIs keep stdout for machine-read records."""
+    if (site, impl) in _RESOLVED_IMPLS:
+        return
+    _RESOLVED_IMPLS.add((site, impl))
+    print(f"[kernels] {site}: {impl}", file=sys.stderr, flush=True)
+
+
+def pallas_mode(interpret: bool) -> str:
+    """How a Pallas kernel runs: compiled for the chip, or interpreted."""
+    return "interpret" if interpret else "mosaic"
 
 
 def dropout_hash_bits(seed, b, h, row, col):

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 from dataclasses import dataclass
 
@@ -41,6 +42,31 @@ SP_AXIS = "sp"    # sequence/context parallel (ring attention)
 TP_AXIS = "tp"    # tensor (Megatron) parallel
 
 TRAINING_MODES = ("local", "dp", "ddp", "fsdp")
+
+
+# One entry of TPU_WORKER_HOSTNAMES as libtpu accepts it: a hostname or IP
+# address without a port, or a host:port:address triple.
+_WORKER_HOST_RE = re.compile(
+    r"[A-Za-z0-9]([A-Za-z0-9._-]*[A-Za-z0-9])?(:\d+:[A-Za-z0-9][A-Za-z0-9._:-]*)?"
+)
+
+
+def tpu_worker_hosts(value: str | None) -> list[str]:
+    """The hosts a ``TPU_WORKER_HOSTNAMES`` value names, or ``[]`` when it is
+    not a host list.
+
+    Parsed, not sniffed for a comma: where libtpu cannot determine the
+    worker set it logs a warning SENTENCE about this very variable, commas
+    included, and an environment that carries such text must not send a
+    single-host run into a rendezvous with peers that do not exist (it
+    hangs before the first step). One malformed entry disqualifies the
+    whole value."""
+    if not value:
+        return []
+    hosts = [h.strip() for h in value.split(",")]
+    if not all(_WORKER_HOST_RE.fullmatch(h) for h in hosts):
+        return []
+    return hosts
 
 
 def init_distributed(
@@ -60,6 +86,8 @@ def init_distributed(
     ``jax.process_count()`` would itself initialize the backends, which
     forbids a later ``jax.distributed.initialize``.
     """
+    # jax._src internal, re-checked under jax 0.9.0: `global_state.client` is
+    # still the only liveness probe that does not initialize the backends.
     from jax._src import distributed as _jax_distributed
 
     if getattr(_jax_distributed.global_state, "client", None) is not None:
@@ -86,18 +114,10 @@ def init_distributed(
         # scripts/run_training_tpu_pod.sh documents ("simply run this on all
         # workers"). Anything else (local runs, CPU tests, WORLD_SIZE=1/RANK=0
         # env residue without a MASTER_ADDR) is single-process: return.
-        hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
-        multi_host_tpu = "," in hostnames
-        if not multi_host_tpu:
+        if len(tpu_worker_hosts(os.environ.get("TPU_WORKER_HOSTNAMES"))) < 2:
             return
         jax.distributed.initialize()
         return
-    if str(jax.config.jax_platforms or "").startswith("cpu"):
-        # Cross-process collectives on the CPU backend need the gloo
-        # transport; the default implementation aborts every multi-process
-        # computation with "Multiprocess computations aren't implemented on
-        # the CPU backend". Must be set before backend initialization.
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -256,10 +276,6 @@ def active_mesh() -> Mesh | None:
     except ValueError:
         # get_mesh() refuses to run under an active jit trace; inside a trace
         # only the explicit activate_mesh registry (checked above) applies.
-        return None
-    except AttributeError:
-        # jax.sharding.get_mesh() is not present in every supported JAX
-        # version; without it the set_mesh idiom can't be in effect.
         return None
     return None if getattr(m, "empty", True) else m
 

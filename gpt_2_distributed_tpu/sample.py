@@ -71,6 +71,9 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> None:
     args = build_argparser().parse_args(argv)
+    from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if args.device:
         os.environ["JAX_PLATFORMS"] = args.device
 
@@ -123,6 +126,9 @@ def main(argv: list[str] | None = None) -> None:
     if bad:
         sys.exit(f"prompt ids out of vocab range: {bad[:5]}")
 
+    from gpt_2_distributed_tpu.utils.device_info import device_banner
+
+    print(device_banner(), file=sys.stderr)
     template = jax.eval_shape(lambda: gpt2.init_params(config))
     # Explicit single-device shardings: without them orbax re-applies the
     # shardings recorded in the checkpoint files — exactly the path it warns

@@ -595,6 +595,9 @@ def main(argv: list[str] | None = None) -> None:
     from gpt_2_distributed_tpu.config import validate_worker_flags
 
     validate_worker_flags(p, args)
+    from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if args.device:
         os.environ["JAX_PLATFORMS"] = args.device
 

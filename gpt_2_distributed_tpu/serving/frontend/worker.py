@@ -629,6 +629,40 @@ def worker_argv(args: argparse.Namespace, serve: ServeConfig) -> list[str]:
     return argv
 
 
+def refuse_unpinned_workers(max_workers: int) -> None:
+    """Exit with a message where ``--placement subprocess`` would hang.
+
+    A chip belongs to one process at a time, and off the CPU there is no
+    slice pinning yet: every worker sees, and takes, ALL chips of the host.
+    So one worker is the most an accelerator host can run, and only from a
+    parent that has not taken the chips itself. Found at start, not as a
+    hang in the second worker's backend init."""
+    if max_workers > 1:
+        sys.exit(
+            f"--placement subprocess: {max_workers} workers asked for, but "
+            f"off the CPU a worker cannot be pinned to a slice of the chips "
+            f"— each takes every chip of the host, so the second would hang "
+            f"waiting for the first's. Run one worker, keep replicas "
+            f"in-process (--placement inprocess: one process drives all "
+            f"chips), or pass --device cpu"
+        )
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return
+    # jax._src internal, checked under jax 0.9.0: the only way to ask
+    # whether a backend is live without bringing one up.
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized() and jax.default_backend() != "cpu":
+        sys.exit(
+            f"--placement subprocess: this process already holds the "
+            f"{jax.default_backend()} backend, and a chip belongs to one "
+            f"process at a time — a worker spawned now would hang waiting "
+            f"for it. Spawn workers from a parent that has not touched the "
+            f"device, or keep replicas in-process"
+        )
+
+
 def spawner_from_args(
     args: argparse.Namespace,
     serve: ServeConfig,
@@ -638,7 +672,8 @@ def spawner_from_args(
     """The one constructor all three CLIs share for subprocess placement.
     On CPU hosts (``--device cpu`` or JAX_PLATFORMS=cpu) each worker env
     is pinned to exactly ``serve.mesh_devices`` virtual devices — its
-    device slice — via the hoisted conftest recipe."""
+    device slice — via the hoisted conftest recipe. Anywhere else workers
+    cannot be pinned, and what would hang is refused here."""
     env = None
     device = (getattr(args, "device", None)
               or os.environ.get("JAX_PLATFORMS") or "")
@@ -646,6 +681,10 @@ def spawner_from_args(
         env = forced_host_device_env(serve.mesh_devices)
         if getattr(args, "device", None):
             env["JAX_PLATFORMS"] = args.device
+    else:
+        refuse_unpinned_workers(
+            max(initial_replicas, getattr(args, "max_replicas", None) or 0)
+        )
     token_file = getattr(args, "worker_auth_token_file", None)
     return WorkerSpawner(
         worker_argv(args, serve), serve,
@@ -1079,6 +1118,9 @@ def main(argv: list[str] | None = None) -> None:
     args = p.parse_args(argv)
     if (args.ckpt is None) == (not args.init_random):
         p.error("exactly one of --ckpt / --init_random is required")
+    from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if args.device:
         os.environ["JAX_PLATFORMS"] = args.device
 

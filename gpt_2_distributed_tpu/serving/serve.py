@@ -303,8 +303,13 @@ def load_model(args: argparse.Namespace):
 
     from gpt_2_distributed_tpu.checkpoint import latest_checkpoint, restore_params
     from gpt_2_distributed_tpu.models import gpt2
+    from gpt_2_distributed_tpu.utils.device_info import device_banner
 
     config = model_config_from_args(args)
+    # Every process that loads weights says what it loads them onto (the
+    # JSONL CLI, the front door in-process, each worker): rc 0 alone does
+    # not tell a chip from the CPU JAX falls back to.
+    print(device_banner(), file=sys.stderr, flush=True)
 
     if args.init_random:
         params = gpt2.init_params(config)
@@ -423,6 +428,9 @@ def main(argv: list[str] | None = None) -> None:
     from gpt_2_distributed_tpu.config import validate_worker_flags
 
     validate_worker_flags(p, args)
+    from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     if args.device:
         os.environ["JAX_PLATFORMS"] = args.device
 

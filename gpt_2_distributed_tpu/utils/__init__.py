@@ -1,5 +1,7 @@
 from gpt_2_distributed_tpu.utils.device_info import (
+    device_banner,
     device_info_lines,
+    device_memory_lines,
     get_memory_info,
     print_device_info,
 )
@@ -10,7 +12,9 @@ from gpt_2_distributed_tpu.utils.flops import (
 )
 
 __all__ = [
+    "device_banner",
     "device_info_lines",
+    "device_memory_lines",
     "device_peak_flops",
     "flops_per_token",
     "get_memory_info",

@@ -54,16 +54,25 @@ _TPU_PEAK_FLOPS: dict[str, float] = {
 
 
 def device_peak_flops(device=None) -> float | None:
-    """Peak bf16 FLOP/s of one device, or None if unknown (e.g. CPU)."""
+    """Peak bf16 FLOP/s of one device; None off the TPU (a CPU has no peak
+    this framework measures against).
+
+    The kind is matched exactly. A TPU kind the table does not list raises:
+    a prefix match would hand "TPU v5x" the figure of "TPU v5", and every
+    MFU computed from it would be wrong without a word."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
-    if kind in _TPU_PEAK_FLOPS:
-        return _TPU_PEAK_FLOPS[kind]
-    for name, flops in _TPU_PEAK_FLOPS.items():
-        if kind.startswith(name):
-            return flops
-    return None
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind
+    if kind not in _TPU_PEAK_FLOPS:
+        raise ValueError(
+            f"no peak FLOP/s on record for TPU device kind {kind!r}; MFU "
+            f"cannot be computed against a guessed peak — add the kind to "
+            f"utils/flops.py::_TPU_PEAK_FLOPS with its published figure "
+            f"(listed: {', '.join(sorted(_TPU_PEAK_FLOPS))})"
+        )
+    return _TPU_PEAK_FLOPS[kind]
 
 
 def mfu(

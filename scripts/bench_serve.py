@@ -1753,14 +1753,22 @@ def main(argv=None) -> None:
 
         _dp, _tp = parse_serve_mesh(args.serve_mesh)
         need = _dp * _tp
+        if (jax.device_count() < need and not
+                (os.environ.get("JAX_PLATFORMS") or "").startswith("cpu")):
+            # Never by itself: a run meant for chips that finds too few
+            # must not come back with CPU numbers under device names.
+            p.error(f"--serve_mesh {args.serve_mesh!r} needs {need} "
+                    f"devices, found {jax.device_count()} "
+                    f"({jax.devices()[0].platform}); set JAX_PLATFORMS=cpu "
+                    f"to rehearse on forced virtual CPU devices")
         if (jax.device_count() < need
                 and os.environ.get("_BENCH_SERVE_FORCED") != "1"):
-            # Too few real devices: re-exec against the forced virtual
-            # CPU platform (the test suite's conftest pattern) so the
-            # sharded and single-device engines run in ONE process and
-            # the stream comparison is apples-to-apples. highest matmul
-            # precision pins both engines to the same fp32 reductions the
-            # parity tests use.
+            # Too few CPU devices, and the CPU was asked for: re-exec
+            # against the forced virtual CPU platform (the test suite's
+            # conftest pattern) so the sharded and single-device engines
+            # run in ONE process and the stream comparison is
+            # apples-to-apples. highest matmul precision pins both engines
+            # to the same fp32 reductions the parity tests use.
             import re
             import subprocess
 

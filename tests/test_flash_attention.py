@@ -270,3 +270,21 @@ def test_sharded_dropout_streams_differ_per_shard():
         np.allclose(out[0], out[b]) for b in range(1, B)
     )
     assert same == 0, f"{same}/7 shards reused the shard-0 dropout mask"
+
+
+@pytest.mark.parametrize("impl,seq_len,line", [
+    ("auto", 128, "[kernels] attention: dense (xla)"),     # flash only on a TPU
+    ("dense", 128, "[kernels] attention: dense (xla)"),
+    ("flash", 128, "[kernels] attention: flash (interpret)"),
+])
+def test_resolved_attention_impl_is_said_once(monkeypatch, capsys, impl, seq_len, line):
+    """The trace-time choice (platform, shape, mesh) is printed once per
+    process, so a run can be held to the implementation it was meant to take."""
+    from gpt_2_distributed_tpu.ops import spmd
+    from gpt_2_distributed_tpu.ops.attention import select_attention_impl
+
+    monkeypatch.setattr(spmd, "_RESOLVED_IMPLS", set())
+    q = jnp.zeros((1, seq_len, 2, 64), jnp.float32)
+    for _ in range(2):
+        select_attention_impl(impl, seq_len)(q, q, q)
+    assert capsys.readouterr().err.splitlines() == [line]

@@ -681,3 +681,61 @@ def test_frontend_process_sigterm_exits_zero(tiny_config, tmp_path):
         if proc.poll() is None:
             proc.kill()
         proc.stderr.close()
+
+
+# --------------------------------- subprocess placement off the CPU refuses
+
+
+def _subprocess_args(*extra):
+    from gpt_2_distributed_tpu.serving.frontend.server import build_argparser
+
+    return build_argparser().parse_args([
+        "--init_random", "--n_layer", "2", "--n_embd", "32", "--n_head", "2",
+        "--vocab_size", "257", "--seq_len", "64", "--placement", "subprocess",
+        *extra,
+    ])
+
+
+@pytest.mark.parametrize("flags,platform_env,refused", [
+    (["--replicas", "2"], None, "cannot be pinned"),
+    (["--replicas", "1", "--max_replicas", "3"], None, "cannot be pinned"),
+    (["--replicas", "2"], "tpu", "cannot be pinned"),
+    (["--replicas", "2", "--device", "tpu"], "cpu", "cannot be pinned"),
+    (["--replicas", "2"], "cpu", None),              # CPU: slices are pinned
+    (["--replicas", "2", "--device", "cpu"], None, None),
+    (["--replicas", "1"], None, None),               # one worker may take the chips
+], ids=["two-workers", "autoscale-headroom", "env-tpu", "flag-tpu-beats-env",
+        "env-cpu", "flag-cpu", "one-worker"])
+def test_unpinnable_subprocess_placement_refused_at_start(
+        monkeypatch, flags, platform_env, refused):
+    """Off the CPU every worker would take all chips and the second would
+    hang in backend init; the spawner refuses before spawning anything."""
+    from gpt_2_distributed_tpu.serving.frontend.worker import spawner_from_args
+    from gpt_2_distributed_tpu.serving.serve import (
+        build_serve_config,
+        model_config_from_args,
+    )
+
+    if platform_env is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platform_env)
+    args = _subprocess_args(*flags)
+    serve = build_serve_config(args, model_config_from_args(args))
+    if refused is None:
+        spawner_from_args(args, serve, initial_replicas=args.replicas)
+    else:
+        with pytest.raises(SystemExit, match=refused):
+            spawner_from_args(args, serve, initial_replicas=args.replicas)
+
+
+def test_workers_refused_from_a_parent_that_holds_the_chip(monkeypatch):
+    from gpt_2_distributed_tpu.serving.frontend.worker import (
+        refuse_unpinned_workers,
+    )
+
+    jax.devices()                       # this process's (CPU) backend is live
+    refuse_unpinned_workers(1)          # a CPU parent holds no chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="already holds the tpu backend"):
+        refuse_unpinned_workers(1)

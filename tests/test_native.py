@@ -7,6 +7,8 @@ in CI (the build image ships gcc) so the native path never silently rots
 into the fallback.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -19,6 +21,22 @@ def test_native_builds_on_this_host():
         "native window gather failed to build — CI hosts ship a C compiler, "
         "so this signals a build regression, not a missing toolchain"
     )
+
+
+def test_shared_object_is_named_after_its_source(tmp_path, monkeypatch):
+    """The cached build carries a hash of the C source in its name, so a
+    stale object lying in a copied tree (file times do not survive every
+    copy) can never be loaded for a source it was not built from."""
+    current = native.so_path()
+    with open(native._SRC, "rb") as f:
+        source = f.read()
+    assert os.path.exists(current)          # built by the test above
+    edited = tmp_path / "window_gather.c"
+    edited.write_bytes(source + b"\n/* edited */\n")
+    monkeypatch.setattr(native, "_SRC", str(edited))
+    assert os.path.basename(native.so_path()) != os.path.basename(current)
+    monkeypatch.setattr(native, "_SRC", str(tmp_path / "missing.c"))
+    assert native.so_path() is None
 
 
 def test_gather_matches_numpy():

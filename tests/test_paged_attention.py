@@ -201,3 +201,20 @@ def test_rejects_bad_impl_and_shapes(rng_np):
         paged_attention(q[:, :, None], kp, vp, table, lengths)
     with pytest.raises(ValueError, match="matching"):
         paged_attention(q, kp, vp[:-1], table, lengths)
+
+
+@pytest.mark.parametrize("impl,line", [
+    ("auto", "[kernels] paged_attention: xla (gather)"),      # the CPU's choice
+    ("xla", "[kernels] paged_attention: xla (gather)"),
+    ("pallas", "[kernels] paged_attention: pallas (interpret)"),
+])
+def test_resolved_impl_is_said_once(rng_np, monkeypatch, capsys, impl, line):
+    """Which way the dispatch went is printed once per process: a chip run
+    that landed on the gather or the interpreter must not look healthy."""
+    from gpt_2_distributed_tpu.ops import spmd
+
+    monkeypatch.setattr(spmd, "_RESOLVED_IMPLS", set())
+    q, kp, vp, bt, ln, _, _ = _paged_case(rng_np)
+    for _ in range(2):
+        paged_attention(q, kp, vp, bt, ln, impl=impl)
+    assert capsys.readouterr().err.splitlines() == [line]
