@@ -267,6 +267,15 @@ for _name, _dist in (
     ("spec_rollbacks", "sum"),         # cumulative verify passes with a rejection
     ("draft_ms", "sum"),               # cumulative draft-pass wall time
     ("verify_ms", "sum"),              # cumulative target-verify wall time
+    # serving/step_clocks.py: the engine's own clocks and counters, per step
+    ("engine_host_ms", "mean"),        # step time outside prefill and decode
+    ("admit_ms", "mean"),              # of it: deadline evictions + admission
+    ("grow_ms", "mean"),               # of it: watermark block-table growth
+    ("emit_ms", "mean"),               # of it: after the read-back to the end
+    ("decode_dispatch_ms", "mean"),    # decode program's call to its return
+    ("decode_wait_ms", "mean"),        # from there to the token read-back
+    ("decode_rows", "mean"),           # rows one decode step advances
+    ("decode_attended", "mean"),       # keys those rows attend
 ):
     METRIC_REGISTRY.metric(
         _name, reduction=ReductionStrategy.CURRENT, tb_prefix="serve/",

@@ -7,12 +7,15 @@ serving waterfalls.
 
 Design constraints, in order:
 
-1. **Disabled must be free.** The default-constructed tracer is disabled:
+1. **Nothing attached must be free.** The default-constructed tracer is
+   disabled: with no trace directory and no profiler capture running,
    ``span()`` returns a shared singleton no-op context manager without
-   allocating a span object or touching the clock, ``event()``/``counter()``
-   return immediately, and no file is ever opened. Instrumentation in the
-   training step loop and the serving decode loop therefore costs one
-   attribute load + one branch per call site when tracing is off.
+   allocating a span object, taking a lock or touching the clock,
+   ``event()`` returns immediately, and no file is ever opened.
+   Instrumentation in the training step loop and the serving decode loop
+   therefore costs one attribute load, one call into the profiler's
+   "is anyone recording" flag and two branches per call site (budget: 1 us
+   per span; PERF.md has the measurement).
 2. **Spans nest per thread.** Each thread owns a stack (``threading.local``);
    a span's parent is whatever span that same thread had open at entry.
    Cross-thread work (the checkpoint commit thread, the hang watchdog) gets
@@ -31,23 +34,47 @@ from trace events matches the engine's own accounting). Each file opens with
 a ``meta`` record pairing one ``perf_counter`` reading with ``time.time()``
 so the report tool can align processes on the wall clock.
 
-Host spans optionally bridge into the XLA device timeline: while an
-on-demand profiler capture is active (``--xla_profile_at``), every open span
-also enters ``jax.profiler.TraceAnnotation(name)``, so the TensorBoard trace
-viewer shows ``step_dispatch`` / ``device_sync`` bars above the device ops
-they enqueue.
+Host spans bridge into the XLA device timeline: a span entered while a
+``jax.profiler`` capture is running is also a
+``jax.profiler.TraceAnnotation`` named ``gpt2/<span name>`` - whoever started
+the capture (``--xla_profile_at``, ``--profile``, ``jax.profiler.start_server``,
+the benchmark's profiler window) and whether or not a trace directory is
+configured - so the trace viewer shows ``gpt2/step_dispatch`` /
+``gpt2/engine_step`` bars above the device ops they enqueue, on the
+profiler's own clock.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any
 
 TRACE_FILE_TEMPLATE = "trace-p{rank}.jsonl"
 DEFAULT_MAX_FILE_BYTES = 64 * 1024 * 1024
+ANNOTATION_PREFIX = "gpt2/"
+
+# jax.profiler.TraceAnnotation, once jax is in the process. This module stays
+# importable without jax (the front-door parents import it and never touch
+# jax); a process that has not imported jax cannot be under its profiler.
+_trace_annotation = None
+
+
+def _recording():
+    """``TraceAnnotation`` while a ``jax.profiler`` capture records, however
+    it was started (the profiler's own flag: one atomic load), else None."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        cls = _trace_annotation = TraceAnnotation
+    return cls if cls.is_enabled() else None
 
 
 class _NullSpan:
@@ -68,8 +95,31 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _Annotation:
+    """A span with no trace directory behind it, entered while a profiler
+    capture runs: the ``TraceAnnotation`` alone, no record."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, cls, name: str, attrs: dict[str, Any]):
+        self._ann = cls(ANNOTATION_PREFIX + name, **attrs)
+
+    def __enter__(self) -> "_Annotation":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc: object) -> bool:
+        self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs: Any) -> "_Annotation":
+        self._ann.set_metadata(**attrs)
+        return self
+
+
 class _Span:
-    """A live span. Created by ``Tracer.span`` only when tracing is enabled."""
+    """A live span with a JSONL record. Created by ``Tracer.span`` only
+    when a trace directory is configured."""
 
     __slots__ = ("_tracer", "name", "attrs", "sid", "parent", "t0", "_ann")
 
@@ -93,24 +143,16 @@ class _Span:
         self.parent = stack[-1].sid if stack else None
         self.sid = tr._next_sid()
         stack.append(self)
-        if tr._annotate:
-            try:
-                import jax
-
-                self._ann = jax.profiler.TraceAnnotation(self.name)
-                self._ann.__enter__()
-            except Exception:
-                self._ann = None
+        cls = _recording()
+        if cls is not None:
+            self._ann = _Annotation(cls, self.name, self.attrs).__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: object) -> bool:
         dur = time.perf_counter() - self.t0
         if self._ann is not None:
-            try:
-                self._ann.__exit__(*exc)
-            except Exception:
-                pass
+            self._ann.__exit__(*exc)
             self._ann = None
         tr = self._tracer
         stack = tr._stack()
@@ -137,12 +179,13 @@ class _Span:
 
 
 class Tracer:
-    """Per-process span/event/counter recorder with JSONL emission.
+    """Per-process span/event recorder with JSONL emission.
 
     A process normally has exactly one, reachable through ``get_tracer()``
     and configured once at startup by ``configure_tracing()``. Library code
     never constructs tracers; it calls ``get_tracer().span(...)`` and relies
-    on the disabled fast path when the run didn't ask for traces.
+    on the fast path when nothing is attached (no trace directory, no
+    profiler capture).
     """
 
     def __init__(
@@ -157,7 +200,6 @@ class Tracer:
         self.trace_dir = trace_dir
         self.process_index = process_index
         self.max_file_bytes = max_file_bytes
-        self._annotate = False
         self._sid = 0
         self._sid_lock = threading.Lock()
         self._write_lock = threading.Lock()
@@ -195,18 +237,18 @@ class Tracer:
             self.trace_dir, TRACE_FILE_TEMPLATE.format(rank=self.process_index)
         )
 
-    def set_annotate(self, on: bool) -> None:
-        """Bridge host spans into the device timeline while a profiler
-        capture is active (``jax.profiler.TraceAnnotation``)."""
-        self._annotate = bool(on and self.enabled)
-
     # -- recording -----------------------------------------------------------
 
     def span(self, name: str, **attrs: Any):
-        """Context manager timing a phase. Nesting derives parent links."""
-        if not self.enabled:
+        """Context manager timing a phase. Nesting derives parent links.
+        Without a trace directory the span is the profiler annotation alone
+        while a capture runs, and the shared no-op otherwise."""
+        if self.enabled:
+            return _Span(self, name, attrs)
+        cls = _recording()
+        if cls is None:
             return _NULL_SPAN
-        return _Span(self, name, attrs)
+        return _Annotation(cls, name, attrs)
 
     def event(self, name: str, ts: float | None = None, **attrs: Any) -> None:
         """Instant event. ``ts`` (perf_counter/monotonic domain) may be
@@ -221,20 +263,6 @@ class Tracer:
             "pid": self.process_index,
             "tid": threading.get_ident(),
             "ts": time.perf_counter() if ts is None else ts,
-        }
-        if attrs:
-            rec["attrs"] = attrs
-        self._emit(rec)
-
-    def counter(self, name: str, value: float, **attrs: Any) -> None:
-        if not self.enabled:
-            return
-        rec = {
-            "ph": "counter",
-            "name": name,
-            "pid": self.process_index,
-            "ts": time.perf_counter(),
-            "value": value,
         }
         if attrs:
             rec["attrs"] = attrs
@@ -371,12 +399,12 @@ def parse_profile_at(spec: str | None) -> tuple[int, int] | None:
 
 
 class XlaCapture:
-    """On-demand ``jax.profiler`` window: arms at ``start_step``, captures
-    ``n_steps`` optimizer (or engine) steps into ``<out_dir>/xla_profile``,
-    and flips the tracer's TraceAnnotation bridge on for the window so host
-    spans land in the device timeline. Drive it with ``maybe_start(step)`` /
-    ``maybe_stop(step)`` around each step; both are no-ops outside the
-    window (and when ``spec`` is None the instance is inert).
+    """On-demand ``jax.profiler`` window: arms at ``start_step`` and
+    captures ``n_steps`` optimizer (or engine) steps into
+    ``<out_dir>/xla_profile``; the tracer's spans land in it like in any
+    capture. Drive it with ``maybe_start(step)`` / ``maybe_stop(step)``
+    around each step; both are no-ops outside the window (and when ``spec``
+    is None the instance is inert).
     """
 
     def __init__(self, spec: tuple[int, int] | None, out_dir: str | None):
@@ -401,7 +429,6 @@ class XlaCapture:
 
         os.makedirs(self.profile_dir, exist_ok=True)
         jax.profiler.start_trace(self.profile_dir)
-        get_tracer().set_annotate(True)
         get_tracer().event("xla_profile_start", step=step)
         self.active = True
         return True
@@ -416,7 +443,6 @@ class XlaCapture:
         import jax
 
         jax.profiler.stop_trace()
-        get_tracer().set_annotate(False)
         get_tracer().event("xla_profile_stop", step=step)
         self.active = False
         self.done = True
@@ -431,6 +457,5 @@ class XlaCapture:
                 jax.profiler.stop_trace()
             except Exception:
                 pass
-            get_tracer().set_annotate(False)
             self.active = False
             self.done = True

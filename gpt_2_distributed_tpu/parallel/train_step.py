@@ -44,6 +44,10 @@ import optax
 
 from gpt_2_distributed_tpu.config import GPT2Config
 from gpt_2_distributed_tpu.models import gpt2
+from gpt_2_distributed_tpu.obs import compile_watch
+
+# Whoever builds a training program has the compile watch first.
+compile_watch.install()
 
 # Reference AdamW hyperparameters, /root/reference/train_gpt2_distributed.py:356-362.
 DEFAULT_WEIGHT_DECAY = 0.1

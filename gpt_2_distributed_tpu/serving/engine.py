@@ -102,6 +102,7 @@ import numpy as np
 
 from gpt_2_distributed_tpu.config import GPT2Config, ServeConfig
 from gpt_2_distributed_tpu.models import decode, gpt2
+from gpt_2_distributed_tpu.obs import compile_watch
 from gpt_2_distributed_tpu.obs.trace import get_tracer
 from gpt_2_distributed_tpu.models.generate import (
     check_generation_args,
@@ -123,6 +124,45 @@ from gpt_2_distributed_tpu.serving.paged_cache import (
     pool_bytes,
     scatter_prefill,
 )
+from gpt_2_distributed_tpu.serving.step_clocks import step_clocks
+
+
+# Whoever builds the engine's programs has the compile watch first.
+compile_watch.install()
+
+
+def _program(name: str, impl: Callable, **static) -> Callable:
+    """``impl`` with its static arguments bound, under a name: a bare
+    ``functools.partial`` has none, and its program shows as
+    ``jit__unknown`` in profiles and in the compile watch's log lines."""
+    fn = functools.partial(impl, **static)
+    fn.__name__ = name
+    return fn
+
+
+class _Phase:
+    """One boundary of the step, timed once: the tracer's span around it
+    and the host clock whose milliseconds go to each of ``keys`` in
+    ``ServingEngine.stats`` are entered and left together."""
+
+    __slots__ = ("_stats", "_keys", "_span", "_t0")
+
+    def __init__(self, stats: dict, keys: tuple[str, ...], span):
+        self._stats = stats
+        self._keys = keys
+        self._span = span
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Phase":
+        self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc: object) -> bool:
+        ms = (time.monotonic() - self._t0) * 1e3
+        for key in self._keys:
+            self._stats[key] += ms
+        return self._span.__exit__(*exc)
 
 
 # Version tag of the serialized request form (`RequestHandle.to_wire`).
@@ -949,6 +989,20 @@ class ServingEngine:
             "prefill_ms": 0.0, "decode_ms": 0.0, "queue_wait_ms": 0.0,
             "spec_draft_tokens": 0, "spec_accepted_tokens": 0,
             "spec_rollbacks": 0, "draft_ms": 0.0, "verify_ms": 0.0,
+            # The step's own clocks (`_phase`), host time.monotonic in ms;
+            # `metrics_snapshot` shows each per step. steps/step_ms: calls
+            # of step() that found work, whole wall time. admit_ms: deadline
+            # evictions + admission less the prefill dispatches admission
+            # made. decode_dispatch_ms: the part of decode_ms from the
+            # decode program's call to its return (argument transfer and
+            # enqueue; the verify pass's in a speculative round); the rest
+            # of decode_ms is the wait for the token read-back, and
+            # draft_ms. emit_ms: after the read-back to the step's end.
+            # decode_rows/decode_attended: per decode step, active rows and
+            # the keys they attend, sum of pos + 1.
+            "steps": 0, "step_ms": 0.0, "admit_ms": 0.0, "grow_ms": 0.0,
+            "decode_dispatch_ms": 0.0, "emit_ms": 0.0,
+            "decode_rows": 0, "decode_attended": 0,
         }
 
         # Per-engine jits so tests can count THIS engine's compilations:
@@ -956,8 +1010,8 @@ class ServingEngine:
         # arbitrary admission/eviction churn, and `_chunk_fn._cache_size()
         # == 1` in chunked mode (the chunk width is fixed).
         self._decode_fn = jax.jit(
-            functools.partial(
-                _decode_step_impl, config=config,
+            _program(
+                "decode_step", _decode_step_impl, config=config,
                 temperature=self.temperature, top_k=top_k,
                 attn_impl=serve.attn_impl,
             ),
@@ -965,8 +1019,8 @@ class ServingEngine:
             **decode_kw,
         )
         self._prefill_fn = jax.jit(
-            functools.partial(
-                _prefill_impl, config=config,
+            _program(
+                "prefill", _prefill_impl, config=config,
                 temperature=self.temperature, top_k=top_k,
                 compute_dtype=compute_dtype,
             ),
@@ -974,8 +1028,8 @@ class ServingEngine:
             **prefill_kw,
         )
         self._chunk_fn = jax.jit(
-            functools.partial(
-                _chunk_prefill_impl, config=config,
+            _program(
+                "chunk_prefill", _chunk_prefill_impl, config=config,
                 temperature=self.temperature, top_k=top_k,
             ),
             donate_argnames=("k_pool", "v_pool"),
@@ -987,24 +1041,25 @@ class ServingEngine:
             # verify at T = spec_k + 1 — one compile each, preserving the
             # engine's compile-once discipline.
             self._draft_fn = jax.jit(
-                functools.partial(
-                    _draft_step_impl, config=draft_config,
+                _program(
+                    "draft_step", _draft_step_impl, config=draft_config,
                     attn_impl=serve.attn_impl,
                 ),
                 donate_argnames=("k_pool", "v_pool"),
                 **spec_draft_kw,
             )
             self._draft_prefill_fn = jax.jit(
-                functools.partial(
-                    _spec_verify_impl, config=draft_config,
+                _program(
+                    "draft_catch_up", _spec_verify_impl, config=draft_config,
                     return_logits=False,
                 ),
                 donate_argnames=("k_pool", "v_pool"),
                 **spec_catchup_kw,
             )
             self._verify_fn = jax.jit(
-                functools.partial(
-                    _spec_verify_impl, config=config, return_logits=True,
+                _program(
+                    "spec_verify", _spec_verify_impl, config=config,
+                    return_logits=True,
                 ),
                 donate_argnames=("k_pool", "v_pool"),
                 **spec_verify_kw,
@@ -1024,6 +1079,7 @@ class ServingEngine:
                         return key, jax.random.uniform(sub, (3 * spec_k + 1,))
                     return jax.vmap(one)(keys)
 
+                _round_entropy.__name__ = "spec_round_entropy"
                 self._spec_keys_fn = jax.jit(_round_entropy)
         get_tracer().event(
             "engine_mesh", mesh=serve.mesh or "single",
@@ -1691,22 +1747,31 @@ class ServingEngine:
         (chunked mode), grow/preempt block tables (watermark mode), then
         one compiled decode step for every active row. Returns tokens
         emitted this step (prefill first-tokens + decode samples)."""
+        if not self.has_work():
+            return 0
         tracer = get_tracer()
-        if not tracer.enabled:
-            return self._step_impl(tracer)
-        with tracer.span("engine_step", n=int(self.stats["decode_steps"])):
+        self.stats["steps"] += 1
+        with self._phase(tracer, "engine_step", "step_ms",
+                         n=self.stats["decode_steps"]):
             return self._step_impl(tracer)
 
+    def _phase(self, tracer, name: str, *keys: str, **attrs) -> _Phase:
+        """Span ``name`` and the clock of the counters ``keys``, as one."""
+        return _Phase(self.stats, keys, tracer.span(name, **attrs))
+
     def _step_impl(self, tracer) -> int:
-        self._evict_overdue()
-        with tracer.span("admit"):
+        prefill_ms = self.stats["prefill_ms"]
+        with self._phase(tracer, "admit", "admit_ms"):
+            self._evict_overdue()
             self._try_admit()
+        # Whole-prompt mode prefills inside admission: that is prefill_ms.
+        self.stats["admit_ms"] -= self.stats["prefill_ms"] - prefill_ms
         with tracer.span("prefill"):
             emitted = self._prefill_tick()
         if not bool(self.active.any()):
             return emitted
         if self.serve.admission == "watermark":
-            with tracer.span("grow"):
+            with self._phase(tracer, "grow", "grow_ms"):
                 self._grow_tables()
             if not bool(self.active.any()):
                 return emitted
@@ -1718,49 +1783,53 @@ class ServingEngine:
             return emitted + self._spec_round(tracer)
 
         was_active = self.active.copy()
-        decode_span = tracer.span(
-            "decode", rows=int(was_active.sum())
-        ).__enter__()
-        t0 = time.monotonic()
-        with self._mesh_scope():
-            next_tokens, new_keys, self.k_pool, self.v_pool = self._decode_fn(
-                self.params, self.k_pool, self.v_pool, self.block_table,
-                self.tokens, self.pos, self.active, self.keys,
-            )
-        if self.mesh is not None:
-            # Sharded engine: the dispatch returns async; fetching the
-            # row-sharded sampled tokens is the cross-shard all-gather the
-            # scheduler blocks on. Give it its own span (sibling of
-            # "decode") so step breakdowns show gather vs compute.
-            decode_span.__exit__(None, None, None)
-            with tracer.span("token_allgather", rows=int(was_active.sum())):
+        rows = self._count_decode(was_active)
+        # `decode` is decode_ms: the dispatch, which returns async, and the
+        # token read-back the scheduler blocks on, each a child span. In the
+        # sharded engine the read-back is the cross-shard all-gather of the
+        # row-sharded sampled tokens, and is named for it.
+        with self._phase(tracer, "decode", "decode_ms", rows=rows):
+            with self._phase(tracer, "dispatch", "decode_dispatch_ms"), \
+                    self._mesh_scope():
+                next_tokens, new_keys, self.k_pool, self.v_pool = \
+                    self._decode_fn(
+                        self.params, self.k_pool, self.v_pool,
+                        self.block_table, self.tokens, self.pos, self.active,
+                        self.keys,
+                    )
+            with tracer.span(
+                "readback" if self.mesh is None else "token_allgather",
+                rows=rows,
+            ):
                 toks_host = np.asarray(next_tokens)
-            self.stats["decode_ms"] += (time.monotonic() - t0) * 1e3
-            self.stats["decode_steps"] += 1
-        else:
-            toks_host = np.asarray(next_tokens)
-            self.stats["decode_ms"] += (time.monotonic() - t0) * 1e3
-            self.stats["decode_steps"] += 1
-            decode_span.__exit__(None, None, None)
-        self.keys = np.array(new_keys)  # writable copy: admission writes rows
-        # Advance every row that decoded this step; evictions below then
-        # reset their rows. Prefilling rows (occupied, inactive) hold still.
-        self.tokens = np.where(was_active, toks_host, self.tokens)
-        self.pos = np.where(was_active, self.pos + 1, self.pos)
-        decoded = 0
-        for slot, req in enumerate(self._slots):
-            if req is None or not was_active[slot]:
-                continue
-            t = int(toks_host[slot])
-            req.generated.append(t)
-            decoded += 1
-            req._emit(t)
-            if self.serve.eos_id is not None and t == self.serve.eos_id:
-                self._evict(slot, "eos")
-            elif len(req.generated) >= req.max_new_tokens:
-                self._evict(slot, "length")
-        self.stats["tokens_out"] += decoded  # prefill firsts counted at emit
+        self.stats["decode_steps"] += 1
+        with self._phase(tracer, "emit", "emit_ms"):
+            self.keys = np.array(new_keys)  # writable copy: admission writes rows
+            # Advance every row that decoded this step; evictions below then
+            # reset their rows. Prefilling rows (occupied, inactive) hold still.
+            self.tokens = np.where(was_active, toks_host, self.tokens)
+            self.pos = np.where(was_active, self.pos + 1, self.pos)
+            decoded = 0
+            for slot, req in enumerate(self._slots):
+                if req is None or not was_active[slot]:
+                    continue
+                t = int(toks_host[slot])
+                req.generated.append(t)
+                decoded += 1
+                req._emit(t)
+                if self.serve.eos_id is not None and t == self.serve.eos_id:
+                    self._evict(slot, "eos")
+                elif len(req.generated) >= req.max_new_tokens:
+                    self._evict(slot, "length")
+            self.stats["tokens_out"] += decoded  # prefill firsts counted at emit
         return emitted + decoded
+
+    def _count_decode(self, was_active: np.ndarray) -> int:
+        """Rows of this decode step, counted with the keys they attend."""
+        rows = int(was_active.sum())
+        self.stats["decode_rows"] += rows
+        self.stats["decode_attended"] += int(self.pos[was_active].sum()) + rows
+        return rows
 
     def _spec_round(self, tracer) -> int:
         """One speculative two-model step for every active row.
@@ -1798,7 +1867,7 @@ class ServingEngine:
         K = self._spec_k
         B = self.serve.max_batch
         was_active = self.active.copy()
-        rows = int(was_active.sum())
+        rows = self._count_decode(was_active)
 
         # Lazy draft-block grant: full per-slot capacity (draft_serve_view)
         # means this can never fail, so there is no draft preemption path.
@@ -1818,9 +1887,8 @@ class ServingEngine:
                 was_active[:, None], np.array(new_keys), self.keys
             )
 
-        t0 = time.monotonic()
-        draft_span = tracer.span("draft", rows=rows, k=K).__enter__()
-        with self._mesh_scope():
+        with self._phase(tracer, "draft", "draft_ms", "decode_ms",
+                         rows=rows, k=K), self._mesh_scope():
             clen_cu = np.where(
                 was_active, self.pos - self._draft_pos, 0
             ).astype(np.int32)
@@ -1864,70 +1932,68 @@ class ServingEngine:
                 d_toks[:, i] = d
                 cur_tok = d
                 cur_pos = cur_pos + 1
-        draft_span.__exit__(None, None, None)
-        t1 = time.monotonic()
-        self.stats["draft_ms"] += (t1 - t0) * 1e3
         self.stats["spec_draft_tokens"] += K * rows
 
-        verify_span = tracer.span("verify", rows=rows, k=K).__enter__()
-        vtoks = np.zeros((B, K + 1), np.int32)
-        vtoks[:, 0] = self.tokens
-        vtoks[:, 1:] = d_toks
-        vclen = np.where(was_active, K + 1, 0).astype(np.int32)
-        with self._mesh_scope():
-            vlogits, self.k_pool, self.v_pool = self._verify_fn(
-                self.params, self.k_pool, self.v_pool, self.block_table,
-                vtoks, self.pos.astype(np.int32), vclen,
-            )
-        vlogits = np.asarray(vlogits)    # [B, K+1, V] — the device sync
-        verify_span.__exit__(None, None, None)
-        t2 = time.monotonic()
-        self.stats["verify_ms"] += (t2 - t1) * 1e3
-        self.stats["decode_ms"] += (t2 - t0) * 1e3
+        with self._phase(tracer, "verify", "verify_ms", "decode_ms",
+                         rows=rows, k=K):
+            with self._phase(tracer, "dispatch", "decode_dispatch_ms"):
+                vtoks = np.zeros((B, K + 1), np.int32)
+                vtoks[:, 0] = self.tokens
+                vtoks[:, 1:] = d_toks
+                vclen = np.where(was_active, K + 1, 0).astype(np.int32)
+                with self._mesh_scope():
+                    vlogits, self.k_pool, self.v_pool = self._verify_fn(
+                        self.params, self.k_pool, self.v_pool,
+                        self.block_table, vtoks, self.pos.astype(np.int32),
+                        vclen,
+                    )
+            with tracer.span("readback"):
+                vlogits = np.asarray(vlogits)    # [B, K+1, V] — the device sync
         self.stats["decode_steps"] += 1
 
-        decoded = 0
-        now = time.monotonic()
-        for slot in range(B):
-            req = self._slots[slot]
-            if req is None or not was_active[slot]:
-                continue
-            emit, accepted = _spec_accept(
-                vlogits[slot], d_toks[slot],
-                [q[slot] for q in q_list] if sampled else None,
-                unis[slot] if sampled else None,
-                self.temperature, self.top_k,
-            )
-            self.stats["spec_accepted_tokens"] += accepted
-            if accepted < K:
-                self.stats["spec_rollbacks"] += 1
-            tracer.event(
-                "spec_accept", ts=now, rid=req.id,
-                drafted=K, accepted=accepted,
-            )
-            done = None
-            n_emitted = 0
-            for t in emit:
-                req.generated.append(t)
-                decoded += 1
-                n_emitted += 1
-                req._emit(t)
-                if self.serve.eos_id is not None and t == self.serve.eos_id:
-                    done = "eos"     # later emissions are dropped whole —
-                    break            # sequential decode never produces them
-                if len(req.generated) >= req.max_new_tokens:
-                    done = "length"
-                    break
-            if done is not None:
-                self._evict(slot, done)
-                continue
-            self.pos[slot] += n_emitted
-            self.tokens[slot] = emit[n_emitted - 1]
-            # Round invariant: the K+1 draft steps covered positions
-            # pos .. pos+K with tokens matching every committed prefix
-            # outcome, so the draft frontier lands exactly on the new pos.
-            self._draft_pos[slot] = self.pos[slot]
-        self.stats["tokens_out"] += decoded
+        with self._phase(tracer, "emit", "emit_ms"):
+            decoded = 0
+            now = time.monotonic()
+            for slot in range(B):
+                req = self._slots[slot]
+                if req is None or not was_active[slot]:
+                    continue
+                emit, accepted = _spec_accept(
+                    vlogits[slot], d_toks[slot],
+                    [q[slot] for q in q_list] if sampled else None,
+                    unis[slot] if sampled else None,
+                    self.temperature, self.top_k,
+                )
+                self.stats["spec_accepted_tokens"] += accepted
+                if accepted < K:
+                    self.stats["spec_rollbacks"] += 1
+                tracer.event(
+                    "spec_accept", ts=now, rid=req.id,
+                    drafted=K, accepted=accepted,
+                )
+                done = None
+                n_emitted = 0
+                for t in emit:
+                    req.generated.append(t)
+                    decoded += 1
+                    n_emitted += 1
+                    req._emit(t)
+                    if self.serve.eos_id is not None and t == self.serve.eos_id:
+                        done = "eos"     # later emissions are dropped whole —
+                        break            # sequential decode never produces them
+                    if len(req.generated) >= req.max_new_tokens:
+                        done = "length"
+                        break
+                if done is not None:
+                    self._evict(slot, done)
+                    continue
+                self.pos[slot] += n_emitted
+                self.tokens[slot] = emit[n_emitted - 1]
+                # Round invariant: the K+1 draft steps covered positions
+                # pos .. pos+K with tokens matching every committed prefix
+                # outcome, so the draft frontier lands exactly on the new pos.
+                self._draft_pos[slot] = self.pos[slot]
+            self.stats["tokens_out"] += decoded
         return decoded
 
     def run_until_idle(self, max_steps: int | None = None) -> int:
@@ -1973,6 +2039,7 @@ class ServingEngine:
             "spec_rollbacks": float(self.stats["spec_rollbacks"]),
             "draft_ms": float(self.stats["draft_ms"]),
             "verify_ms": float(self.stats["verify_ms"]),
+            **step_clocks([self.stats]),
         }
 
     def clear_prefix_cache(self) -> None:

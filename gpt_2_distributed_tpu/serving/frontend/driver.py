@@ -40,6 +40,7 @@ import threading
 import time
 from typing import TYPE_CHECKING, Callable, Sequence
 
+from gpt_2_distributed_tpu.obs import compile_watch
 from gpt_2_distributed_tpu.obs.trace import get_tracer
 
 if TYPE_CHECKING:   # annotation-only: keeps this module importable
@@ -182,6 +183,7 @@ class EngineDriver:
         # each replica's step; None in production.
         self.injector = injector
         self.steps = 0
+        self._compile_log = compile_watch.CompileLog(compile_watch.get_watch())
         self.draining = False
         self.watchdog_trips = 0
         self._last_host_poll = 0.0
@@ -439,12 +441,32 @@ class EngineDriver:
                 else:
                     still.append((handle, on_finish))
             self._watch = still
+        self._log_compiles()
         tracker = self.tracker
         if tracker is not None and self.steps % self.metrics_every == 0:
             tracker.update(self.steps, count_tokens=False,
                            watchdog_trips=float(self.watchdog_trips),
                            **self.router.metrics_snapshot())
         return emitted
+
+    def _log_compiles(self) -> None:
+        """Set-up's compile summary once a decode step has run (the
+        prefill and decode programs are built by then); after that, every
+        program that compiles late, with the step it held up: a warning,
+        but for the whole-prompt path's prefill programs, which compile
+        once per block-multiple bucket of prompt length by design. Silent where
+        no engine lives in this process (the watch is never installed)."""
+        log = self._compile_log
+        if not log.watch.installed:
+            return
+        engines = self.router.engines
+        if not log.ready and not any(
+                e.stats.get("decode_steps") for e in engines):
+            return
+        bucketed = () if all(e.serve.prefill_chunk for e in engines) else (
+            "jit(prefill)", "jit(chunk_prefill)")
+        for line in log.lines(self.steps, per_shape=bucketed):
+            print(f"[serve] {line}", file=sys.stderr, flush=True)
 
     def drain(self) -> int:
         """Run until the fleet is idle (the JSONL path's main loop and the

@@ -50,6 +50,7 @@ import time
 from typing import TYPE_CHECKING, Callable, Sequence
 
 from gpt_2_distributed_tpu.obs.trace import get_tracer
+from gpt_2_distributed_tpu.serving.step_clocks import step_clocks
 
 if TYPE_CHECKING:   # annotation-only: keeps this module importable
     from gpt_2_distributed_tpu.serving.engine import (  # pragma: no cover
@@ -511,6 +512,7 @@ class ReplicaRouter:
             "verify_ms": float(
                 sum(e.stats["verify_ms"] for e in self.engines)
             ),
+            **step_clocks(e.stats for e in self.engines),
             # Subprocess placement: replacement workers spawned after a
             # failure (the spawner counts them); always 0 in-process.
             "worker_restarts": float(

@@ -1,0 +1,41 @@
+"""What ``ServingEngine.step``'s own clocks and counters say per step: the
+part of ``metrics_snapshot()`` that the engine and the router's fleet
+aggregate share. Imports nothing, so the router stays importable without
+jax, and takes ``stats`` dicts, so a worker's proxy serves as well as an
+engine."""
+
+from __future__ import annotations
+
+from typing import Iterable, Mapping
+
+
+def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
+    """Means over every engine's steps. ``engine_host_ms``: a step's time
+    that is neither a prefill dispatch nor the decode step, of which
+    ``admit_ms``, ``grow_ms`` and ``emit_ms`` are the clocked parts.
+    ``decode_dispatch_ms`` + ``decode_wait_ms``: a decode step less its
+    draft pass, split where the decode program's call returns.
+    ``decode_rows``, ``decode_attended``: the rows a decode step advances
+    and the keys they attend."""
+    all_stats = list(all_stats)
+
+    def total(key: str) -> float:
+        return sum(stats[key] for stats in all_stats)
+
+    steps = max(total("steps"), 1)
+    decodes = max(total("decode_steps"), 1)
+    return {
+        "engine_host_ms": (
+            total("step_ms") - total("prefill_ms") - total("decode_ms")
+        ) / steps,
+        "admit_ms": total("admit_ms") / steps,
+        "grow_ms": total("grow_ms") / steps,
+        "emit_ms": total("emit_ms") / steps,
+        "decode_dispatch_ms": total("decode_dispatch_ms") / decodes,
+        "decode_wait_ms": (
+            total("decode_ms") - total("draft_ms")
+            - total("decode_dispatch_ms")
+        ) / decodes,
+        "decode_rows": total("decode_rows") / decodes,
+        "decode_attended": total("decode_attended") / decodes,
+    }

@@ -630,6 +630,7 @@ def main(argv: list[str] | None = None) -> None:
         make_train_step,
     )
     from gpt_2_distributed_tpu.utils.flops import device_peak_flops, flops_per_token
+    from gpt_2_distributed_tpu.obs import compile_watch
     from gpt_2_distributed_tpu.obs.trace import (
         XlaCapture,
         configure_tracing,
@@ -1232,6 +1233,7 @@ def main(argv: list[str] | None = None) -> None:
         # step later, which the rollback policy absorbs (its data cursor
         # already sits past the offending batches).
         pending: tuple[int, int, int, Any] | None = None
+        compile_log = compile_watch.CompileLog(compile_watch.get_watch())
         rollback_requested = False
         last_skip_reason_host = 0
 
@@ -1331,6 +1333,11 @@ def main(argv: list[str] | None = None) -> None:
             _sync_span.__exit__(None, None, None)
             with tracer.span("collector", step=p_step):
                 tracker.update(p_step, **values, **extra)
+            # The first step is done: set-up's compile summary; from then
+            # on, every program that compiles names itself and the step.
+            for line in compile_log.lines(p_step):
+                if is_primary():
+                    print(f"[compile] {line}", flush=True)
 
         def emergency_preempt_exit() -> None:
             """Preemption endgame (single-host: SIGTERM/poller flag at the
@@ -1410,9 +1417,8 @@ def main(argv: list[str] | None = None) -> None:
         def begin_step_span() -> None:
             nonlocal step_span
             end_step_span()
-            if tracer.enabled:
-                step_span = tracer.span("step", n=global_step + 1)
-                step_span.__enter__()
+            step_span = tracer.span("step", n=global_step + 1)
+            step_span.__enter__()
 
         def end_step_span() -> None:
             nonlocal step_span

@@ -2,8 +2,8 @@
 
 Each timed call is CHAINED on its predecessor's output (y = f(y, ...)), so the
 host enqueues far ahead of the device and per-dispatch latency does not
-floor the measurement (scripts/profile_breakdown.py's single-shot numbers are
-dispatch-bound — this script replaces them for component work).
+floor the measurement (single-shot timings of a component are
+dispatch-bound).
 
 Two conventions encoded here:
 * big arrays are passed as jit ARGUMENTS, never closures — closed-over arrays

@@ -19,7 +19,9 @@ trace directory written by ``gpt_2_distributed_tpu.obs.trace`` and prints:
   timestamps, so this agrees with the engine's accounting to the
   microsecond.
 * **Engine-step breakdown** — same treatment for ``engine_step`` spans
-  (admit / prefill / decode phases of the continuous-batching loop).
+  (admit / prefill / grow / decode / emit phases of the continuous-batching
+  loop; ``decode`` is the engine's ``decode_ms``, with its dispatch and
+  its token read-back listed under it).
 
 ``--json`` emits the same content as one JSON object for dashboards.
 
@@ -91,7 +93,9 @@ def step_breakdown(
 
     Only *direct* children are summed — a nested span (e.g. a barrier
     inside consensus_exchange) is already inside its parent's duration, so
-    counting it again would overstate attribution.
+    counting it again would overstate attribution. A phase's own children
+    (``dispatch`` and ``readback`` inside the engine's ``decode``) are
+    listed under it as its ``parts``, and added to nothing.
     """
     spans = [r for r in records if r.get("ph") == "span"]
     by_key = {(r["pid"], r["sid"]): r for r in spans}
@@ -106,31 +110,44 @@ def step_breakdown(
                 children[(r["pid"], r["parent"])].append(r)
 
     phase_durs: dict[str, list[float]] = defaultdict(list)
+    part_durs: dict[str, dict[str, list[float]]] = defaultdict(
+        lambda: defaultdict(list))
     step_durs: list[float] = []
     residuals: list[float] = []
     for st in steps:
         kids = children.get((st["pid"], st["sid"]), [])
         attributed = 0.0
         per_phase: dict[str, float] = defaultdict(float)
+        per_part: dict[tuple[str, str], float] = defaultdict(float)
         for k in kids:
             per_phase[k["name"]] += k["dur"]
             attributed += k["dur"]
+            for part in children.get((k["pid"], k["sid"]), []):
+                per_part[k["name"], part["name"]] += part["dur"]
         for name, d in per_phase.items():
             phase_durs[name].append(d)
+        for (name, part), d in per_part.items():
+            part_durs[name][part].append(d)
         step_durs.append(st["dur"])
         residuals.append(max(0.0, st["dur"] - attributed))
 
     total_step = sum(step_durs)
     total_attr = total_step - sum(residuals)
+    def share(durs: list[float]) -> float:
+        return 100.0 * sum(durs) / total_step if total_step else 0.0
+
+    def by_total(named: dict[str, list[float]]):
+        return sorted(named.items(), key=lambda kv: -sum(kv[1]))
+
     phases = {
-        name: {
-            **_stats_ms(durs),
-            "share_pct": 100.0 * sum(durs) / total_step if total_step else 0.0,
-        }
-        for name, durs in sorted(
-            phase_durs.items(), key=lambda kv: -sum(kv[1])
-        )
+        name: {**_stats_ms(durs), "share_pct": share(durs)}
+        for name, durs in by_total(phase_durs)
     }
+    for name, parts in part_durs.items():
+        phases[name]["parts"] = {
+            part: {**_stats_ms(durs), "share_pct": share(durs)}
+            for part, durs in by_total(parts)
+        }
     return {
         "span": step_name,
         "n_steps": len(step_durs),
@@ -398,8 +415,11 @@ def _print_breakdown(b: dict[str, Any], title: str) -> None:
     print(f"  {'phase':<20} {'mean_ms':>9} {'p50_ms':>9} {'p99_ms':>9} "
           f"{'share':>7} {'n':>5}")
     for name, ph in b["phases"].items():
-        print(f"  {name:<20} {ph['mean_ms']:>9.2f} {ph['p50_ms']:>9.2f} "
-              f"{ph['p99_ms']:>9.2f} {ph['share_pct']:>6.1f}% {ph['n']:>5}")
+        rows = [(name, ph)] + [
+            ("  " + part, p) for part, p in ph.get("parts", {}).items()]
+        for label, row in rows:
+            print(f"  {label:<20} {row['mean_ms']:>9.2f} {row['p50_ms']:>9.2f} "
+                  f"{row['p99_ms']:>9.2f} {row['share_pct']:>6.1f}% {row['n']:>5}")
     res = b["residual"]
     print(f"  {'(unattributed)':<20} {res['mean_ms']:>9.2f} {res['p50_ms']:>9.2f} "
           f"{res['p99_ms']:>9.2f} {res['share_pct']:>6.1f}%")

@@ -208,13 +208,12 @@ def test_fused_backward(chip, name):
 # --- the whole train step ----------------------------------------------------
 
 
-def test_train_step_124m(chip, topo, monkeypatch):
-    """``make_train_step`` as the trainer builds it for ``--model 124M``
-    (batch 4, seq 1024, dropout on, guarded, attention left to choose),
-    compiled for one chip. The attention policy asks ``jax.devices()`` for
-    the platform at trace time and would see this sandbox's CPU, so the
-    probe is steered here, in the test: it must then pick the flash kernel
-    by itself, and the step must fit the chip's memory."""
+def _compiled_train_step(chip, topo, monkeypatch, config, accum, batch):
+    """``make_train_step`` as the trainer builds it (guarded, attention left
+    to choose), compiled for one chip. The attention policy asks
+    ``jax.devices()`` for the platform at trace time and would see this
+    sandbox's CPU, so the probe is steered here, in the test: it must then
+    pick the flash kernel by itself."""
     from gpt_2_distributed_tpu.models import gpt2
     from gpt_2_distributed_tpu.parallel.train_step import (
         make_optimizer,
@@ -223,8 +222,6 @@ def test_train_step_124m(chip, topo, monkeypatch):
     from gpt_2_distributed_tpu.resilience import init_guard_state
 
     monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
-    accum, batch, seq = 2, 4, 1024
-    config = MODEL_PRESETS["124M"].replace(n_positions=seq, scan_layers=True)
     optimizer = make_optimizer(3e-3)
     step = make_train_step(config, optimizer, guard=True)
 
@@ -236,13 +233,21 @@ def test_train_step_124m(chip, topo, monkeypatch):
 
     params = jax.eval_shape(lambda: gpt2.init_params(config))
     opt_state = jax.eval_shape(optimizer.init, params)
-    tokens = jax.ShapeDtypeStruct((accum, batch, seq), I32, sharding=chip)
-    compiled = step.lower(
+    tokens = jax.ShapeDtypeStruct(
+        (accum, batch, config.n_positions), I32, sharding=chip)
+    return step.lower(
         on_chip(params), on_chip(opt_state),
         on_chip(jax.eval_shape(init_guard_state)),
         tokens, tokens, jax.ShapeDtypeStruct(*KEY, sharding=chip), 0,
         jax.ShapeDtypeStruct((accum,), F32, sharding=chip),
     ).compile()
+
+
+def test_train_step_124m(chip, topo, monkeypatch):
+    """The step for ``--model 124M`` (batch 4, seq 1024, dropout on) must
+    pick the flash kernel and fit the chip's memory."""
+    config = MODEL_PRESETS["124M"].replace(n_positions=1024, scan_layers=True)
+    compiled = _compiled_train_step(chip, topo, monkeypatch, config, 2, 4)
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # Donated state aliases its outputs; what is not aliased is extra.
@@ -251,3 +256,87 @@ def test_train_step_124m(chip, topo, monkeypatch):
         + mem.output_size_in_bytes - mem.alias_size_in_bytes
     )
     assert need < HBM_BYTES, f"124M step needs {need / 2**30:.2f} GiB"
+
+
+# --- the kernel names the benchmark's readers match --------------------------
+#
+# benchmark/metrics/flash_attn_roofline.py and paged_attn_roofline.py find
+# their kernels in a trace by HLO instruction name (the pallas_calls carry no
+# name=), and raise where they find none: a traced benchmark run then fails,
+# on the chip. JAX derives those names from the name stack, so a
+# jax.named_scope on the path, a name= on a pallas_call or a renamed wrapper
+# function changes them. The profiler names a device event by its
+# instruction's whole text, which is a line of the compiled module's text, so
+# the needles can be held to the compiled programs here. When this fails, the
+# readers have to learn the new name (a `benchmark` PR) before the program
+# may take it.
+
+
+def _reader_constants(metric: str) -> dict:
+    from benchmark import harness
+
+    return harness.load_reader(metric).__globals__
+
+
+def _instructions_matching(hlo_text: str, needles) -> list[str]:
+    """Instructions a reader would match: ``Trace.kernel_seconds``'s rule
+    (starts with the first needle, contains the rest)."""
+    head, rest = needles[0], needles[1:]
+    lines = (line.strip().removeprefix("ROOT ") for line in hlo_text.splitlines())
+    return [l for l in lines if l.startswith(head) and all(n in l for n in rest)]
+
+
+def test_train_step_flash_kernel_names(chip, topo, monkeypatch):
+    """The train step with its layers unrolled, as ``train-124m-1k`` runs
+    124M (2 layers here: the names come from the name stack, not the depth):
+    one forward and one backward flash kernel a layer, under the names
+    ``flash_attn_roofline`` matches. (Under ``scan_layers`` both are named
+    ``closed_call``; the benchmark has no such training cell yet.)"""
+    config = MODEL_PRESETS["124M"].replace(
+        n_positions=1024, n_layer=2, scan_layers=False)
+    text = _compiled_train_step(chip, topo, monkeypatch, config, 2, 4).as_text()
+    names = _reader_constants("flash_attn_roofline")
+    forward = _instructions_matching(text, names["FORWARD"])
+    backward = _instructions_matching(text, names["BACKWARD"])
+    assert len(forward) == len(backward) == config.n_layer
+    assert not set(forward) & set(backward)
+    assert text.count("tpu_custom_call") == 2 * config.n_layer
+
+
+def test_engine_decode_step_kernel_name(chip, topo, monkeypatch):
+    """The engine's decode step - ``_decode_step_impl`` bound and named as
+    ``ServingEngine`` binds and names it - at the 1.5B widths, 2 layers:
+    its one Mosaic kernel sits in the layer scan under the name
+    ``paged_attn_roofline`` matches. The jit's own name (``decode_step``)
+    names the module, not the instruction. (The kernel compiled alone, as
+    ``test_paged_decode`` compiles it, does not get this name.)"""
+    from gpt_2_distributed_tpu.config import ServeConfig
+    from gpt_2_distributed_tpu.models import gpt2
+    from gpt_2_distributed_tpu.serving import engine as eng
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    config = MODEL_PRESETS["1.5B"].replace(n_layer=2)
+    serve = ServeConfig(max_batch=4, block_size=16, num_blocks=4 * 64 + 1,
+                        attn_impl="pallas")
+
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: arr(a.shape, a.dtype),
+        jax.eval_shape(lambda: gpt2.init_params(config)))
+    pool = arr((config.n_layer, serve.num_blocks, config.n_head,
+                serve.block_size, config.head_dim), BF16)
+    b, m = serve.max_batch, serve.max_blocks_per_seq(config.n_positions)
+    decode = jax.jit(
+        eng._program("decode_step", eng._decode_step_impl, config=config,
+                     temperature=0.0, top_k=None, attn_impl=serve.attn_impl),
+        donate_argnames=("k_pool", "v_pool"),
+    )
+    text = decode.lower(
+        params, pool, pool, arr((b, m), I32), arr((b,), I32), arr((b,), I32),
+        arr((b,), jnp.bool_), arr((b, 2), jnp.uint32)).compile().as_text()
+    assert "jit_decode_step" in text.splitlines()[0]
+    kernel = _instructions_matching(
+        text, _reader_constants("paged_attn_roofline")["KERNEL"])
+    assert len(kernel) == 1 and text.count("tpu_custom_call") == 1

@@ -47,6 +47,25 @@ def read_records(path):
         return [json.loads(line) for line in f if line.strip()]
 
 
+def host_annotations(profile_dir):
+    """``{name: [(thread line, start_ns, end_ns), ...]}`` of the tracer's
+    and the benchmark's annotations on the host plane of the capture under
+    ``profile_dir``."""
+    from benchmark.reduce_trace import HOST_PLANE, find_xplane
+
+    data = jax.profiler.ProfileData.from_file(find_xplane(profile_dir))
+    found: dict[str, list] = {}
+    for plane in data.planes:
+        if plane.name != HOST_PLANE:
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("gpt2/", "bench/")):
+                    found.setdefault(ev.name, []).append(
+                        (line.name, ev.start_ns, ev.start_ns + ev.duration_ns))
+    return found
+
+
 # --- span runtime -----------------------------------------------------------
 
 
@@ -60,7 +79,6 @@ class TestTracerCore:
         with s1 as s:
             s.set(more=2)  # no-op, no raise
         tr.event("ev", x=1)
-        tr.counter("c", 3)
         assert tr.open_spans() == {}
         # and the disabled tracer never touched the filesystem
         assert list(tmp_path.iterdir()) == []
@@ -93,15 +111,12 @@ class TestTracerCore:
             sp.set(batch=4)
         tr.event("boom", reason="test")
         tr.event("stamped", ts=123.456, rid=7)
-        tr.counter("queue_depth", 3)
         tr.close()
         recs = read_records(tr.trace_path)
         by_name = {r["name"]: r for r in recs if r["ph"] != "meta"}
         assert by_name["phase"]["attrs"] == {"batch": 4}
         assert by_name["boom"]["ph"] == "event"
         assert by_name["stamped"]["ts"] == 123.456  # explicit ts honored
-        assert by_name["queue_depth"]["ph"] == "counter"
-        assert by_name["queue_depth"]["value"] == 3
 
     def test_sibling_spans_share_parent(self, tmp_path):
         tr = Tracer(str(tmp_path), enabled=True)
@@ -200,16 +215,26 @@ class TestXlaCapture:
         tr = get_tracer().configure(str(tmp_path))
         xc = XlaCapture((3, 2), str(tmp_path))
         assert not xc.maybe_start(2)
+        with tr.span("before_window"):
+            pass
         assert xc.maybe_start(3)        # window opens at step 3
-        assert tr._annotate            # host->device bridge armed
+        with tr.span("in_window", step=3):
+            time.sleep(0.001)
         assert not xc.maybe_stop(3)     # covers steps 3-4
         assert xc.maybe_stop(4)
-        assert not tr._annotate
+        with tr.span("after_window"):
+            pass
         assert xc.done and not xc.maybe_start(5)  # one-shot
         tr.close()
         assert os.path.isdir(xc.profile_dir)
         names = [r.get("name") for r in read_records(tr.trace_path)]
         assert "xla_profile_start" in names and "xla_profile_stop" in names
+        # every span has its JSONL record; the capture holds the one that
+        # was entered inside the window, and neither of the others
+        assert {"before_window", "in_window", "after_window"} <= set(names)
+        captured = host_annotations(xc.profile_dir)
+        assert "gpt2/in_window" in captured
+        assert not {"gpt2/before_window", "gpt2/after_window"} & set(captured)
 
 
 # --- watchdog integration ---------------------------------------------------
