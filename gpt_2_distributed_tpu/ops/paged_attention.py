@@ -2,15 +2,23 @@
 
 The serving subsystem (``gpt_2_distributed_tpu/serving/``) keeps every
 in-flight sequence's K/V in fixed-size blocks carved out of ONE preallocated
-device buffer (``[num_blocks, H, block_size, D]`` per layer), addressed
-through a per-sequence block table — so sequences of wildly different
-lengths share the buffer with no per-shape recompiles and no per-request
-contiguous allocation. This module is the attention op over that layout:
+device buffer (``[L, num_blocks, H, block_size, D]``, all layers in one
+array), addressed through a per-sequence block table — so sequences of
+wildly different lengths share the buffer with no per-shape recompiles and
+no per-request contiguous allocation. This module is the attention op over
+that layout:
 
     o[b] = softmax(q[b] · K[b]^T / sqrt(D)) · V[b]
 
 where K[b]/V[b] are the first ``lengths[b]`` positions of sequence ``b``,
-scattered across pool blocks ``block_table[b, :]``.
+scattered across pool blocks ``block_table[b, :]`` of layer ``layer``.
+
+Every op here takes the WHOLE pool and a layer index, never a layer's
+slice: the step programs carry the pool through their layer loop in the one
+device layout the kernel reads (row-major, ``paged_cache.pool_shape``), and
+a slice would be a copy of ``N·H·bs·D`` elements per layer. A 4-D
+``[N, H, bs, D]`` pool is the one-layer case (``L = 1``, layer 0) of the
+same functions.
 
 Two implementations, one contract:
 
@@ -25,10 +33,11 @@ Two implementations, one contract:
   machinery of ``ops/flash_block.py`` (exp2-folded online softmax, m/l/acc
   VMEM scratch carried over the column grid): the grid's block axis indexes
   the POOL through the prefetched block table (``index_map`` reads
-  ``block_table[b, j]``), so each K/V block is DMA'd straight from its pool
-  slot — no gathered copy ever exists. Decode is forward-only, so unlike
-  flash_block there is no VJP; numerics differ from the XLA path by
-  online-softmax ulps (same contract as flash vs dense attention).
+  ``block_table[b, j]``, offset to the layer's run of blocks), so each K/V
+  block is DMA'd straight from its pool slot — no gathered copy and no
+  layer slice ever exists. Decode is forward-only, so unlike flash_block
+  there is no VJP; numerics differ from the XLA path by online-softmax
+  ulps (same contract as flash vs dense attention).
 
 Per-sequence lengths do the masking: position ``s`` of sequence ``b`` is
 attendable iff ``s < lengths[b]``. ``lengths[b] == 0`` marks an idle slot
@@ -54,31 +63,41 @@ from gpt_2_distributed_tpu.ops.spmd import pallas_mode, record_resolved_impl
 _DIMS = ("parallel", "parallel", "arbitrary")  # j carries the m/l/acc scratch
 
 
+def _contiguous_view(pool: jnp.ndarray, layer, block_table: jnp.ndarray):
+    """One gather of ``pool[layer, block_table]``, ``[B, M, H, bs, D]``, as
+    the contiguous per-sequence view ``[B, H, M*bs, D]``."""
+    if pool.ndim == 4:       # one layer's [N, H, bs, D]: the L = 1 case
+        pool = pool[None]
+    b, m = block_table.shape
+    _, _, h, bs, d = pool.shape
+    blocks = pool[layer, block_table]
+    return blocks.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+
+
 def paged_attention_xla(
     q: jnp.ndarray,            # [B, H, D] compute dtype
-    k_pool: jnp.ndarray,       # [N, H, bs, D]
-    v_pool: jnp.ndarray,       # [N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D] (or [N, H, bs, D], layer 0)
+    v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32 pool indices
     lengths: jnp.ndarray,      # [B] int32 attendable positions (0 = idle)
+    layer=0,                   # scalar int32 (traced in the layer loop)
 ) -> jnp.ndarray:
     """Gather-based reference path. Mirrors ``decode.decode_step``'s
     attention bit-for-bit on the attendable prefix: identical einsum forms,
     fp32 scores, ``MASK_VALUE`` fill (which underflows to exactly 0 after
     the softmax max-subtract), probs cast back to the compute dtype."""
-    b, h, d = q.shape
-    m = block_table.shape[1]
-    bs = k_pool.shape[2]
+    b, _, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
 
-    # [B, M, H, bs, D] -> [B, H, M*bs, D]: the contiguous per-sequence view.
-    kc = k_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    vc = v_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    kc = _contiguous_view(k_pool, layer, block_table)  # [B, H, M*bs, D]
+    vc = _contiguous_view(v_pool, layer, block_table)
+    s = kc.shape[2]
 
     qh = q[:, :, None]                               # [B, H, 1, D]
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk", qh, kc, preferred_element_type=jnp.float32
     ) * scale                                        # [B, H, 1, M*bs] fp32
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, m * bs), 3)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, s), 3)
     mask = kpos < lengths[:, None, None, None]
     scores = jnp.where(mask, scores, MASK_VALUE)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -91,10 +110,11 @@ def paged_attention_xla(
 
 def paged_prefill_attention(
     q: jnp.ndarray,            # [B, T, H, D] chunk queries, compute dtype
-    k_pool: jnp.ndarray,       # [N, H, bs, D]
-    v_pool: jnp.ndarray,       # [N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D] (or [N, H, bs, D], layer 0)
+    v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32 pool indices
     start: jnp.ndarray,        # [B] int32 absolute position of q[:, 0]
+    layer=0,                   # scalar int32 (traced in the layer loop)
 ) -> jnp.ndarray:
     """Chunked-prefill attention over a partially-built block table.
 
@@ -120,14 +140,12 @@ def paged_prefill_attention(
     treatment that pays off for single-row decode is left to the on-chip
     campaign.
     """
-    b, t, h, d = q.shape
-    m = block_table.shape[1]
-    bs = k_pool.shape[2]
+    b, t, _, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
 
-    # [B, M, H, bs, D] -> [B, H, M*bs, D]: contiguous per-sequence view.
-    kc = k_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    vc = v_pool[block_table].transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
+    kc = _contiguous_view(k_pool, layer, block_table)  # [B, H, M*bs, D]
+    vc = _contiguous_view(v_pool, layer, block_table)
+    s = kc.shape[2]
 
     qh = q.transpose(0, 2, 1, 3)                     # [B, H, T, D]
     scores = jnp.einsum(
@@ -136,7 +154,7 @@ def paged_prefill_attention(
     qpos = start[:, None, None, None] + jax.lax.broadcasted_iota(
         jnp.int32, (b, 1, t, 1), 2
     )
-    kpos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, m * bs), 3)
+    kpos = jax.lax.broadcasted_iota(jnp.int32, (b, 1, 1, s), 3)
     scores = jnp.where(kpos <= qpos, scores, MASK_VALUE)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     o = jnp.einsum("bhqk,bhkd->bhqd", probs, vc)     # [B, H, T, D]
@@ -145,10 +163,11 @@ def paged_prefill_attention(
 
 def spec_verify_attention(
     q: jnp.ndarray,            # [B, T, H, D] verify-window queries
-    k_pool: jnp.ndarray,       # [N, H, bs, D]
-    v_pool: jnp.ndarray,       # [N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D] (or [N, H, bs, D], layer 0)
+    v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32 pool indices
     start: jnp.ndarray,        # [B] int32 absolute position of q[:, 0]
+    layer=0,                   # scalar int32 (traced in the layer loop)
 ) -> jnp.ndarray:
     """Speculative-decoding verify pass: the target model re-scores a
     draft run of T tokens (the committed decode input plus the drafted
@@ -166,7 +185,9 @@ def spec_verify_attention(
     exact logits sequential decode would have produced at each drafted
     position.
     """
-    return paged_prefill_attention(q, k_pool, v_pool, block_table, start)
+    return paged_prefill_attention(
+        q, k_pool, v_pool, block_table, start, layer
+    )
 
 
 def _paged_fwd_kernel(
@@ -233,18 +254,29 @@ def _paged_fwd_kernel(
 
 def paged_attention_pallas(
     q: jnp.ndarray,            # [B, H, D]
-    k_pool: jnp.ndarray,       # [N, H, bs, D]
-    v_pool: jnp.ndarray,       # [N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D] (or [N, H, bs, D], layer 0)
+    v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32
     lengths: jnp.ndarray,      # [B] int32
+    layer=0,                   # scalar int32 (traced in the layer loop)
     *,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Scalar-prefetch paged attention: K/V blocks stream from their pool
-    slots via the table-indexed ``index_map`` — the gathered contiguous
-    [B, H, S, D] view never materializes."""
+    slots via the table-indexed ``index_map`` — neither a layer's slice of
+    the pool nor the gathered contiguous [B, H, S, D] view ever
+    materializes. The call reads the pool row-major, the layout it is
+    stored in (``paged_cache.pool_shape``).
+
+    The layer is folded into the table, not into the kernel: the pool is
+    viewed as ``[L*N, H, bs, D]`` (merging the two major axes of a
+    row-major array moves nothing) and block ``n`` of layer ``l`` is row
+    ``l*N + n`` of it. A third scalar-prefetch operand and a 5-D
+    ``BlockSpec`` address the same bytes, 4 % slower: the grid's 6400 steps
+    a layer are ~0.15 us each, and the scalar core pays for every term of
+    an index map (my chip run, PR 26: 56.7 against 54.4 ms for 48 calls)."""
     b, h, d = q.shape
-    bs = k_pool.shape[2]
+    n, _, bs, _ = k_pool.shape[-4:]
     m = block_table.shape[1]
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
@@ -281,11 +313,11 @@ def paged_attention_pallas(
         compiler_params=pltpu.CompilerParams(dimension_semantics=_DIMS),
         interpret=interpret,
     )(
-        block_table.astype(jnp.int32),
+        block_table.astype(jnp.int32) + jnp.asarray(layer, jnp.int32) * n,
         lengths.astype(jnp.int32),
         q[:, :, None],               # [B, H, 1, D]
-        k_pool,
-        v_pool,
+        k_pool.reshape(-1, h, bs, d),
+        v_pool.reshape(-1, h, bs, d),
     )
     return out[:, :, 0]
 
@@ -309,10 +341,11 @@ def _serving_mesh_active() -> bool:
 
 def paged_attention(
     q: jnp.ndarray,            # [B, H, D]
-    k_pool: jnp.ndarray,       # [N, H, bs, D]
-    v_pool: jnp.ndarray,       # [N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D] (or [N, H, bs, D], layer 0)
+    v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32
     lengths: jnp.ndarray,      # [B] int32
+    layer=0,                   # scalar int32 (traced in the layer loop)
     *,
     impl: str = "auto",
     interpret: bool | None = None,
@@ -325,10 +358,10 @@ def paged_attention(
         )
     if q.ndim != 3:
         raise ValueError(f"q must be [B, H, D], got shape {q.shape}")
-    if k_pool.ndim != 4 or v_pool.shape != k_pool.shape:
+    if k_pool.ndim not in (4, 5) or v_pool.shape != k_pool.shape:
         raise ValueError(
-            f"k_pool/v_pool must be matching [N, H, bs, D], got "
-            f"{k_pool.shape} / {v_pool.shape}"
+            f"k_pool/v_pool must be matching [L, N, H, bs, D] (or one "
+            f"layer's [N, H, bs, D]), got {k_pool.shape} / {v_pool.shape}"
         )
     if impl == "auto":
         impl = "pallas" if jax.devices()[0].platform == "tpu" else "xla"
@@ -340,7 +373,10 @@ def paged_attention(
             impl = "xla"
     if impl == "pallas":
         return paged_attention_pallas(
-            q, k_pool, v_pool, block_table, lengths, interpret=interpret
+            q, k_pool, v_pool, block_table, lengths, layer,
+            interpret=interpret,
         )
     record_resolved_impl("paged_attention", "xla (gather)")
-    return paged_attention_xla(q, k_pool, v_pool, block_table, lengths)
+    return paged_attention_xla(
+        q, k_pool, v_pool, block_table, lengths, layer
+    )

@@ -19,7 +19,7 @@ is the serving-shaped alternative:
 * **Chunked prefill** (``ServeConfig.prefill_chunk > 0``): prompts advance
   one fixed-width chunk per engine step, interleaved with decode steps, so
   a long prompt no longer freezes every in-flight stream's inter-token
-  latency. The chunk scatters its K/V into the request's pool blocks at
+  latency. The chunk writes its K/V into the request's pool blocks at
   position granularity and attends over the partially-built table
   (``ops/paged_attention.py::paged_prefill_attention``); the fixed chunk
   width makes it ONE compile regardless of prompt lengths.
@@ -117,12 +117,15 @@ from gpt_2_distributed_tpu.ops.paged_attention import (
 from gpt_2_distributed_tpu.serving.paged_cache import (
     BlockAllocator,
     PrefixCache,
+    as_blocks,
     copy_block,
     draft_serve_view,
     init_pools,
     make_pool_jits,
     pool_bytes,
     scatter_prefill,
+    write_chunk,
+    write_rows,
 )
 from gpt_2_distributed_tpu.serving.step_clocks import step_clocks
 
@@ -340,9 +343,55 @@ def _prefill_impl(
     return first, key, k, v
 
 
+def _paged_layers(
+    config: GPT2Config,
+    params,
+    x: jnp.ndarray,            # [rows, T, C]
+    k_pool: jnp.ndarray,       # as stored (`paged_cache.pool_shape`)
+    v_pool: jnp.ndarray,
+    write: Callable,           # (kp, vp, layer, k, v) -> (kp, vp)
+    attend: Callable,          # (q, kp, vp, layer) -> o, rows*T*H*D elements
+    data_rows: bool = False,
+):
+    """``x`` through every block, the step programs' one layer loop: each
+    layer projects q/k/v ``[rows, T, H, D]``, ``write``s its K/V into the
+    pools, ``attend``s over them, and runs the out-projection, residual and
+    MLP — every op as ``decode.decode_step`` / the dense prefill has it.
+
+    The pools ride the scan's CARRY, whole, as ``[L, N, H, bs, D]``
+    (``write`` and ``attend`` get that view), and the layer's index rides
+    ``xs`` beside its weights: carried, a pool stays one buffer that the
+    writes update in place; as ``xs``/``ys`` each layer's slice was cut
+    out, re-laid out and copied into a second pool-sized buffer.
+
+    Returns (x, k_pool, v_pool), the pools in the shape they came in."""
+    rows, t, c = x.shape
+    stored = k_pool.shape
+
+    def body(carry, layer):
+        x, kp, vp = carry
+        bp, l = layer
+        y = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"], config.layer_norm_eps)
+        q, k, v = gpt2.qkv_proj(config, y, bp)              # [rows, T, H, D]
+        kp, vp = write(kp, vp, l, k, v)
+        o = gpt2.gather_attn_heads(attend(q, kp, vp, l), data_rows=data_rows)
+        o = o.reshape(rows, t, c)
+        o = o @ bp["attn_proj_w"].astype(x.dtype) + bp["attn_proj_b"].astype(x.dtype)
+        x = x + o
+        x = gpt2._mlp_sublayer(config, x, bp, None, True)
+        return (x, kp, vp), None
+
+    layers = jax.lax.iota(jnp.int32, config.n_layer)
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, as_blocks(k_pool), as_blocks(v_pool)),
+        (params["block"], layers),
+    )
+    return x, k_pool.reshape(stored), v_pool.reshape(stored)
+
+
 def _chunk_prefill_impl(
     params,
-    k_pool: jnp.ndarray,       # [L, N, H, bs, D] — donated
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D], or as stored — donated
     v_pool: jnp.ndarray,
     bt: jnp.ndarray,           # [R, M] int32 — one block-table row per request
     chunk: jnp.ndarray,        # [R, C] int32 tokens, right-padded per row
@@ -355,9 +404,9 @@ def _chunk_prefill_impl(
     top_k: int | None,
 ):
     """R prefill chunks straight into the pool in one dispatch: compute
-    each row's K/V for positions ``[start_r, start_r + clen_r)``, scatter
-    them into that request's blocks at position granularity, attend over
-    the partially-built tables.
+    each row's K/V for positions ``[start_r, start_r + clen_r)``, write
+    them into that request's blocks at position granularity
+    (``paged_cache.write_chunk``), attend over the partially-built tables.
 
     Compiles once per (R, C) (shape-keyed) — in chunked mode R is
     ``ServeConfig.prefill_batch`` and C is ``ServeConfig.prefill_chunk``
@@ -376,9 +425,9 @@ def _chunk_prefill_impl(
     per-row attention via ``paged_prefill_attention``'s batch axis,
     per-row PRNG chains in the vmapped sampler), so any chunk split AND
     any row batching reproduces whole-prompt prefill bit-for-bit. Padded
-    positions (``i >= clen_r``) are dropped from the scatter (out-of-range
-    destination) and causally masked out of every row we read; an all-pad
-    row (``clen_r = 0``) scatters nothing and its sampled token/advanced
+    positions (``i >= clen_r``) are never written (the pool keeps what it
+    holds there) and causally masked out of every row we read; an all-pad
+    row (``clen_r = 0``) writes nothing and its sampled token/advanced
     key are discarded by the host. Every row samples a token with its
     request key — one compiled program — and the host discards it on
     non-final chunks, leaving the PRNG chain's one split exactly where
@@ -387,10 +436,7 @@ def _chunk_prefill_impl(
     Returns ([R] sampled tokens at each row's start+clen-1, advanced
     [R, 2] keys, pools).
     """
-    r, c = chunk.shape
-    n = k_pool.shape[1]
-    bs = k_pool.shape[3]
-    m = bt.shape[1]
+    c = chunk.shape[1]
     dtype = k_pool.dtype
     start = jnp.asarray(start, jnp.int32)
     clen = jnp.asarray(clen, jnp.int32)
@@ -404,25 +450,13 @@ def _chunk_prefill_impl(
     x = tok + wpe
 
     valid = jax.lax.iota(jnp.int32, c)[None] < clen[:, None]      # [R, C]
-    blk = jnp.take_along_axis(bt, jnp.minimum(pos_ids // bs, m - 1), axis=1)
-    blk = jnp.where(valid, blk, n)   # out-of-range => scatter drops the row
-    off = pos_ids % bs
-
-    def body(x, layer):
-        bp, kp, vp = layer           # kp/vp: [N, H, bs, D]
-        y = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"], config.layer_norm_eps)
-        q, k, v = gpt2.qkv_proj(config, y, bp)                    # [R, C, H, D]
-        kp = kp.at[blk, :, off].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[blk, :, off].set(v.astype(vp.dtype), mode="drop")
-        o = paged_prefill_attention(q, kp, vp, bt, start)         # [R, C, H, D]
-        o = gpt2.gather_attn_heads(o)
-        o = o.reshape(r, c, config.n_embd)
-        o = o @ bp["attn_proj_w"].astype(x.dtype) + bp["attn_proj_b"].astype(x.dtype)
-        x = x + o
-        x = gpt2._mlp_sublayer(config, x, bp, None, True)
-        return x, (kp, vp)
-
-    x, (kps, vps) = jax.lax.scan(body, x, (params["block"], k_pool, v_pool))
+    x, k_pool, v_pool = _paged_layers(
+        config, params, x, k_pool, v_pool,
+        write=lambda kp, vp, l, k, v: write_chunk(
+            kp, vp, l, bt, start, valid, k, v),
+        attend=lambda q, kp, vp, l: paged_prefill_attention(
+            q, kp, vp, bt, start, l),
+    )
     x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], config.layer_norm_eps)
     last = jnp.maximum(clen - 1, 0)                               # [R]
     h_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
@@ -437,12 +471,12 @@ def _chunk_prefill_impl(
         return tok, key
 
     first, keys = jax.vmap(row_sample)(logits, keys)
-    return first.astype(jnp.int32), keys, kps, vps
+    return first.astype(jnp.int32), keys, k_pool, v_pool
 
 
 def _decode_step_impl(
     params,
-    k_pool: jnp.ndarray,       # [L, N, H, bs, D]
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D], or as stored
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32
     tokens: jnp.ndarray,       # [B] int32 — token to process, at `pos`
@@ -467,8 +501,7 @@ def _decode_step_impl(
     """
     bsz = tokens.shape[0]
     dtype = k_pool.dtype
-    bs = k_pool.shape[3]
-    c = config.n_embd
+    bs = k_pool.shape[-2]
 
     tok = params["wte"].astype(dtype).at[tokens].get(mode="clip")
     wpe = params["wpe"].astype(dtype).at[pos].get(mode="clip")   # [B, C]
@@ -479,23 +512,14 @@ def _decode_step_impl(
     blk = jnp.where(active, blk, 0)   # idle rows scribble on the null block
     off = pos % bs
 
-    def body(x, layer):
-        bp, kp, vp = layer            # kp/vp: [N, H, bs, D]
-        y = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"], config.layer_norm_eps)
-        q, k, v = gpt2.qkv_proj(config, y, bp)                   # [B, 1, H, D]
-        kp = kp.at[blk, :, off].set(k[:, 0])
-        vp = vp.at[blk, :, off].set(v[:, 0])
-        o = paged_attention(
-            q[:, 0], kp, vp, block_table, lengths, impl=attn_impl
-        )                                                        # [B, H, D]
-        o = gpt2.gather_attn_heads(o, data_rows=True)
-        o = o.reshape(bsz, 1, c)
-        o = o @ bp["attn_proj_w"].astype(x.dtype) + bp["attn_proj_b"].astype(x.dtype)
-        x = x + o
-        x = gpt2._mlp_sublayer(config, x, bp, None, True)
-        return x, (kp, vp)
-
-    x, (kps, vps) = jax.lax.scan(body, x, (params["block"], k_pool, v_pool))
+    x, k_pool, v_pool = _paged_layers(
+        config, params, x, k_pool, v_pool,
+        write=lambda kp, vp, l, k, v: write_rows(
+            kp, vp, l, blk, off, k[:, 0], v[:, 0]),
+        attend=lambda q, kp, vp, l: paged_attention(
+            q[:, 0], kp, vp, block_table, lengths, l, impl=attn_impl),
+        data_rows=True,
+    )
     x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], config.layer_norm_eps)
     logits = jnp.einsum(
         "btc,vc->btv", x, params["wte"].astype(x.dtype),
@@ -511,12 +535,12 @@ def _decode_step_impl(
         return tok, key
 
     next_tokens, keys = jax.vmap(row_sample)(logits, keys)
-    return next_tokens.astype(jnp.int32), keys, kps, vps
+    return next_tokens.astype(jnp.int32), keys, k_pool, v_pool
 
 
 def _draft_step_impl(
     params,
-    k_pool: jnp.ndarray,       # [L, N, H, bs, D] — DRAFT pool
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D], or as stored — DRAFT pool
     v_pool: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, M] int32 — draft block table
     tokens: jnp.ndarray,       # [B] int32 — token to process, at `pos`
@@ -537,8 +561,7 @@ def _draft_step_impl(
     each slot's chain head."""
     bsz = tokens.shape[0]
     dtype = k_pool.dtype
-    bs = k_pool.shape[3]
-    c = config.n_embd
+    bs = k_pool.shape[-2]
 
     tok = params["wte"].astype(dtype).at[tokens].get(mode="clip")
     wpe = params["wpe"].astype(dtype).at[pos].get(mode="clip")   # [B, C]
@@ -548,37 +571,27 @@ def _draft_step_impl(
     blk = block_table[jnp.arange(bsz), jnp.minimum(pos // bs,
                                                    block_table.shape[1] - 1)]
     blk = jnp.where(active, blk, 0)   # idle rows scribble on the null block
-
     off = pos % bs
 
-    def body(x, layer):
-        bp, kp, vp = layer            # kp/vp: [N, H, bs, D]
-        y = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"], config.layer_norm_eps)
-        q, k, v = gpt2.qkv_proj(config, y, bp)                   # [B, 1, H, D]
-        kp = kp.at[blk, :, off].set(k[:, 0])
-        vp = vp.at[blk, :, off].set(v[:, 0])
-        o = paged_attention(
-            q[:, 0], kp, vp, block_table, lengths, impl=attn_impl
-        )                                                        # [B, H, D]
-        o = gpt2.gather_attn_heads(o, data_rows=True)
-        o = o.reshape(bsz, 1, c)
-        o = o @ bp["attn_proj_w"].astype(x.dtype) + bp["attn_proj_b"].astype(x.dtype)
-        x = x + o
-        x = gpt2._mlp_sublayer(config, x, bp, None, True)
-        return x, (kp, vp)
-
-    x, (kps, vps) = jax.lax.scan(body, x, (params["block"], k_pool, v_pool))
+    x, k_pool, v_pool = _paged_layers(
+        config, params, x, k_pool, v_pool,
+        write=lambda kp, vp, l, k, v: write_rows(
+            kp, vp, l, blk, off, k[:, 0], v[:, 0]),
+        attend=lambda q, kp, vp, l: paged_attention(
+            q[:, 0], kp, vp, block_table, lengths, l, impl=attn_impl),
+        data_rows=True,
+    )
     x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], config.layer_norm_eps)
     logits = jnp.einsum(
         "btc,vc->btv", x, params["wte"].astype(x.dtype),
         preferred_element_type=jnp.float32,
     )[:, 0]                                                      # [B, V] fp32
-    return logits, kps, vps
+    return logits, k_pool, v_pool
 
 
 def _spec_verify_impl(
     params,
-    k_pool: jnp.ndarray,       # [L, N, H, bs, D] — donated
+    k_pool: jnp.ndarray,       # [L, N, H, bs, D], or as stored — donated
     v_pool: jnp.ndarray,
     bt: jnp.ndarray,           # [R, M] int32 block-table rows
     chunk: jnp.ndarray,        # [R, T] int32 tokens, right-padded per row
@@ -589,7 +602,7 @@ def _spec_verify_impl(
     return_logits: bool,
 ):
     """The speculative two-model engine's shared forward: a T-token window
-    through the model, K/V scattered into the pool at position
+    through the model, K/V written into the pool at position
     granularity, attention over the partially-built table via
     ``spec_verify_attention``.
 
@@ -611,14 +624,11 @@ def _spec_verify_impl(
       are never formed.
 
     Unlike ``_chunk_prefill_impl``, positions at or past
-    ``config.n_positions`` are masked out of the scatter: a verify
+    ``config.n_positions`` are masked out of the write: a verify
     window straddling the context end must not wrap into (and corrupt)
-    the last real block's valid rows — dropped writes land nowhere, and
+    the last real block's valid rows — masked writes land nowhere, and
     the host never emits past the context anyway."""
-    r, t = chunk.shape
-    n = k_pool.shape[1]
-    bs = k_pool.shape[3]
-    m = bt.shape[1]
+    t = chunk.shape[1]
     dtype = k_pool.dtype
     start = jnp.asarray(start, jnp.int32)
     clen = jnp.asarray(clen, jnp.int32)
@@ -630,33 +640,21 @@ def _spec_verify_impl(
 
     valid = jax.lax.iota(jnp.int32, t)[None] < clen[:, None]      # [R, T]
     valid = valid & (pos_ids < config.n_positions)
-    blk = jnp.take_along_axis(bt, jnp.minimum(pos_ids // bs, m - 1), axis=1)
-    blk = jnp.where(valid, blk, n)   # out-of-range => scatter drops the row
-    off = pos_ids % bs
-
-    def body(x, layer):
-        bp, kp, vp = layer           # kp/vp: [N, H, bs, D]
-        y = layer_norm(x, bp["ln1_scale"], bp["ln1_bias"], config.layer_norm_eps)
-        q, k, v = gpt2.qkv_proj(config, y, bp)                    # [R, T, H, D]
-        kp = kp.at[blk, :, off].set(k.astype(kp.dtype), mode="drop")
-        vp = vp.at[blk, :, off].set(v.astype(vp.dtype), mode="drop")
-        o = spec_verify_attention(q, kp, vp, bt, start)           # [R, T, H, D]
-        o = gpt2.gather_attn_heads(o)
-        o = o.reshape(r, t, config.n_embd)
-        o = o @ bp["attn_proj_w"].astype(x.dtype) + bp["attn_proj_b"].astype(x.dtype)
-        x = x + o
-        x = gpt2._mlp_sublayer(config, x, bp, None, True)
-        return x, (kp, vp)
-
-    x, (kps, vps) = jax.lax.scan(body, x, (params["block"], k_pool, v_pool))
+    x, k_pool, v_pool = _paged_layers(
+        config, params, x, k_pool, v_pool,
+        write=lambda kp, vp, l, k, v: write_chunk(
+            kp, vp, l, bt, start, valid, k, v),
+        attend=lambda q, kp, vp, l: spec_verify_attention(
+            q, kp, vp, bt, start, l),
+    )
     if not return_logits:
-        return kps, vps
+        return k_pool, v_pool
     x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], config.layer_norm_eps)
     logits = jnp.einsum(
         "btc,vc->btv", x, params["wte"].astype(x.dtype),
         preferred_element_type=jnp.float32,
     )                                                             # [R, T, V]
-    return logits, kps, vps
+    return logits, k_pool, v_pool
 
 
 def _spec_probs(logits, temperature: float, top_k: int | None) -> np.ndarray:
@@ -879,7 +877,7 @@ class ServingEngine:
             )
             # Chunk-prefill rows are replicated over 'data' (R is small and
             # unconstrained by the mesh; the matmuls still shard over 'tp'
-            # and the pool scatter lands data-sharded).
+            # and the pool writes land data-sharded).
             chunk_kw = dict(
                 in_shardings=(param_sh, pool_sharding, pool_sharding,
                               rep_sh, rep_sh, rep_sh, rep_sh, rep_sh),
@@ -907,7 +905,7 @@ class ServingEngine:
                 )
                 # Draft decode rows shard like target decode rows; the
                 # verify window and draft catch-up rows replicate like
-                # chunked prefill (same [R, T] row shapes, same scatter).
+                # chunked prefill (same [R, T] row shapes, same write).
                 spec_draft_kw = dict(
                     in_shardings=(draft_param_sh, pool_sharding,
                                   pool_sharding, vec_sh, row_sh, row_sh,

@@ -1,11 +1,31 @@
 """Paged KV-cache plumbing: the block pool, its allocator, and the device
-scatter that moves prefill K/V into pool blocks.
+writes that move K/V into pool blocks.
 
 Layout: one preallocated buffer per K and V, ``[L, num_blocks, H,
-block_size, D]`` — layer-stacked to mirror the parameter pytree (so the
-decode step scans layers exactly like training does), block-paged on the
-second axis so sequences of different lengths share the buffer through
-per-sequence block tables instead of per-shape contiguous allocations.
+block_size, D]`` — layer-stacked to mirror the parameter pytree,
+block-paged on the second axis so sequences of different lengths share the
+buffer through per-sequence block tables instead of per-shape contiguous
+allocations.
+
+A pool has ONE device layout, from ``ServingEngine.k_pool`` through every
+program's entry, layer loop, K/V write, attention read and exit: row-major,
+which is how the paged kernel's Mosaic call reads it. Three things keep it:
+
+* the array is STORED with its block axis split, ``[L, N1, N2, H, bs, D]``
+  (``pool_shape``; ``as_blocks`` is the free ``[L, N1*N2, H, bs, D]`` view
+  every program works on). The TPU compiler stores an array whose minor
+  dimension is D = 64 with another dimension minor-most if one longer than
+  64 exists — ``[L, 257, H, bs, D]`` gets the BLOCK axis minor-most, and a
+  program that wants it row-major re-lays out the pool on entry and on exit
+  — and row-major when none does. (An explicit ``jax.experimental.layout``
+  format would say so directly, but under jax 0.9.0 a program read back
+  from the persistent compilation cache has lost it: the executable comes
+  back compiled for the default layout. `PERF.md` §6, PR 26.)
+* the step programs carry the whole pool through their layer loop and
+  index the layer (``engine._paged_layers``), never slicing a layer out;
+* every write here is a ``dynamic_update_slice`` of whole blocks, which XLA
+  performs in place in the layout the buffer has (a ``scatter`` wants a
+  third layout and drags the pool through it).
 
 Block 0 is the null block: never allocated, it backs idle slots and the
 padded tail of every block table, so device code can index the table
@@ -22,6 +42,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import itertools
+import math
 from typing import Iterable
 
 import jax
@@ -225,24 +247,64 @@ class PrefixCache:
             pass
 
 
+# The TPU's compact layout puts a dimension of more than this many elements
+# minor-most rather than pad D = 64 to the 128 lanes.
+_MAX_MAJOR_DIM = 64
+
+
+def split_blocks(num_blocks: int) -> tuple[int, ...]:
+    """The block axis as the fewest factors of at most 64 whose product is
+    the least that holds ``num_blocks`` (257 -> (6, 43): one block of
+    padding, which no table ever names)."""
+    k = 1
+    while _MAX_MAJOR_DIM ** k < num_blocks:
+        k += 1
+    splits = (
+        (*lead, -(-num_blocks // math.prod(lead)))
+        for lead in itertools.product(
+            range(2, _MAX_MAJOR_DIM + 1), repeat=k - 1
+        )
+    )
+    return min((f for f in splits if f[-1] <= _MAX_MAJOR_DIM), key=math.prod)
+
+
+def pool_shape(
+    config: GPT2Config, serve: ServeConfig, sharded: bool = False
+) -> tuple[int, ...]:
+    """The shape a pool is stored in: ``[L, *split_blocks(N), H, bs, D]``
+    on one device, so that its default device layout is the row-major one
+    the paged kernel reads; plain ``[L, N, H, bs, D]`` under a serving
+    mesh, whose 'data' axis cuts the block axis into the allocator's
+    per-shard runs (padding would shift them) and whose programs take the
+    XLA gather path, not the kernel."""
+    blocks = (serve.num_blocks,) if sharded else split_blocks(serve.num_blocks)
+    return (
+        config.n_layer,
+        *blocks,
+        config.n_head,
+        serve.block_size,
+        config.head_dim,
+    )
+
+
+def as_blocks(pool: jnp.ndarray) -> jnp.ndarray:
+    """A stored pool as ``[L, N, H, bs, D]``: merging the split block axes
+    of a row-major array moves nothing. (N may end in padding blocks.)"""
+    return pool.reshape(pool.shape[0], -1, *pool.shape[-3:])
+
+
 def init_pools(
     config: GPT2Config,
     serve: ServeConfig,
     compute_dtype: jnp.dtype = jnp.bfloat16,
     sharding=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The preallocated K and V pools, ``[L, N, H, bs, D]`` zeros.
+    """The preallocated K and V pools, zeros of ``pool_shape``.
 
     ``sharding`` (a NamedSharding; block axis over 'data', head axis over
     'tp') places each pool directly on the serving mesh so no device ever
     materializes the full buffer."""
-    shape = (
-        config.n_layer,
-        serve.num_blocks,
-        config.n_head,
-        serve.block_size,
-        config.head_dim,
-    )
+    shape = pool_shape(config, serve, sharded=sharding is not None)
     if sharding is not None:
         zeros = jax.jit(
             lambda: jnp.zeros(shape, compute_dtype), out_shardings=sharding
@@ -283,29 +345,140 @@ def draft_serve_view(
 
 
 def pool_bytes(config: GPT2Config, serve: ServeConfig, itemsize: int = 2) -> int:
-    """Device bytes the two pools pin (the serving deployment's KV budget)."""
+    """Bytes of K/V the two pools hold (the serving deployment's KV budget).
+    On a TPU they occupy more: row-major pads D = 64 to the 128 lanes, twice
+    this (2.53 GB for 1.5B at 257 blocks, against 1.89 GB block-minor)."""
     return (
         2 * config.n_layer * serve.num_blocks * config.n_head
         * serve.block_size * config.head_dim * itemsize
     )
 
 
-def _scatter_prefill_impl(
+def _write_blocks(
     k_pool: jnp.ndarray,   # [L, N, H, bs, D]
+    v_pool: jnp.ndarray,
+    layer,                 # scalar int32
+    dst: jnp.ndarray,      # [J] int32 destination blocks
+    keep: jnp.ndarray,     # [J, bs] bool — positions of block j to take
+    k: jnp.ndarray,        # [J, H, bs, D], or [J, H, 1, D]: one row for all
+    v: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``pool[layer, dst[j]] = where(keep[j], new[j], what the pool holds)``
+    for j in order: each block is read, merged and put back with one
+    ``dynamic_update_slice``, so the pool is updated in place whatever its
+    layout, and a position that is not kept keeps its bits."""
+    h, bs, d = k_pool.shape[2:]
+
+    def one(j, pools):
+        at = (layer, dst[j], 0, 0, 0)
+        mask = jax.lax.dynamic_index_in_dim(keep, j, 0).reshape(1, 1, 1, bs, 1)
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                pool,
+                jnp.where(
+                    mask,
+                    jax.lax.dynamic_index_in_dim(new, j, 0)[None]
+                    .astype(pool.dtype),
+                    jax.lax.dynamic_slice(pool, at, (1, 1, h, bs, d)),
+                ),
+                at,
+            )
+            for pool, new in zip(pools, (k, v))
+        )
+
+    return jax.lax.fori_loop(0, dst.shape[0], one, (k_pool, v_pool))
+
+
+def write_rows(
+    k_pool: jnp.ndarray,   # [L, N, H, bs, D]
+    v_pool: jnp.ndarray,
+    layer,                 # scalar int32
+    blk: jnp.ndarray,      # [B] int32 destination block per row
+    off: jnp.ndarray,      # [B] int32 position inside the block
+    k: jnp.ndarray,        # [B, H, D] one position's K per row
+    v: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The decode step's write: ``pool[layer, blk[b], :, off[b]] = new[b]``
+    for every row, in row order (idle rows all land on the null block; the
+    last one wins there, and nothing reads it)."""
+    bs = k_pool.shape[3]
+    keep = jax.lax.iota(jnp.int32, bs)[None] == off[:, None]      # [B, bs]
+    return _write_blocks(
+        k_pool, v_pool, layer, blk, keep, k[:, :, None], v[:, :, None]
+    )
+
+
+def write_chunk(
+    k_pool: jnp.ndarray,   # [L, N, H, bs, D]
+    v_pool: jnp.ndarray,
+    layer,                 # scalar int32
+    bt: jnp.ndarray,       # [R, M] int32 block-table rows
+    start: jnp.ndarray,    # [R] int32 position of k[:, 0]
+    valid: jnp.ndarray,    # [R, C] bool — False = padding, never written
+    k: jnp.ndarray,        # [R, C, H, D] a run of consecutive positions
+    v: jnp.ndarray,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The chunk programs' write: position ``p = start[r] + i`` of row ``r``
+    goes to ``pool[layer, bt[r, p // bs], :, p % bs]`` wherever
+    ``valid[r, i]`` — the same pool contents, bit for bit, as a scatter
+    with the invalid rows dropped.
+
+    A run of C positions touches at most ``nb`` consecutive table slots,
+    so each row's K/V is re-cut at its own block boundaries into
+    ``[nb, H, bs, D]`` and written block by block: the partial first and
+    last blocks, padding, and table slots past the row's end keep what
+    the pool holds."""
+    r, c, h, d = k.shape
+    bs = k_pool.shape[3]
+    m = bt.shape[1]
+    nb = (c + 2 * bs - 2) // bs
+    first = start // bs                                           # [R]
+    # Chunk index of every position of those nb blocks.
+    i = (first * bs - start)[:, None] + jax.lax.iota(jnp.int32, nb * bs)[None]
+    ic = jnp.clip(i, 0, c - 1)
+    slot = first[:, None] + jax.lax.iota(jnp.int32, nb)[None]     # [R, nb]
+    keep = (i >= 0) & (i < c) & jnp.take_along_axis(valid, ic, axis=1)
+    keep = keep.reshape(r, nb, bs) & (slot < m)[:, :, None]
+    dst = jnp.take_along_axis(bt, jnp.minimum(slot, m - 1), axis=1)
+
+    def blocks(x):           # [R, C, H, D] -> [R * nb, H, bs, D]
+        x = jnp.take_along_axis(x, ic[:, :, None, None], axis=1)
+        x = x.reshape(r, nb, bs, h, d).transpose(0, 1, 3, 2, 4)
+        return x.reshape(r * nb, h, bs, d)
+
+    return _write_blocks(
+        k_pool, v_pool, layer, dst.reshape(r * nb),
+        keep.reshape(r * nb, bs), blocks(k), blocks(v),
+    )
+
+
+def _scatter_prefill_impl(
+    k_pool: jnp.ndarray,   # stored pool (`pool_shape`)
     v_pool: jnp.ndarray,
     k: jnp.ndarray,        # [L, H, Ppad, D] — prefill K, Ppad = nb * bs
     v: jnp.ndarray,
     block_ids: jnp.ndarray,  # [nb] int32 pool destinations
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     l, h, ppad, d = k.shape
-    bs = k_pool.shape[3]
+    bs = k_pool.shape[-2]
     nb = ppad // bs
-    kb = k.reshape(l, h, nb, bs, d).transpose(0, 2, 1, 3, 4)
-    vb = v.reshape(l, h, nb, bs, d).transpose(0, 2, 1, 3, 4)
-    return (
-        k_pool.at[:, block_ids].set(kb.astype(k_pool.dtype)),
-        v_pool.at[:, block_ids].set(vb.astype(v_pool.dtype)),
-    )
+    pools = (as_blocks(k_pool), as_blocks(v_pool))
+    news = tuple(
+        x.reshape(l, h, nb, bs, d).transpose(0, 2, 1, 3, 4).astype(pool.dtype)
+        for x, pool in zip((k, v), pools)
+    )                                                    # [L, nb, H, bs, D]
+
+    def one(j, pools):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                pool, jax.lax.dynamic_slice_in_dim(new, j, 1, axis=1),
+                block_ids[j], axis=1,
+            )
+            for pool, new in zip(pools, news)
+        )
+
+    pools = jax.lax.fori_loop(0, nb, one, pools)
+    return pools[0].reshape(k_pool.shape), pools[1].reshape(v_pool.shape)
 
 
 # Scatter one sequence's prefill K/V into its allocated pool blocks.
@@ -319,15 +492,19 @@ scatter_prefill = functools.partial(
 
 
 def _copy_block_impl(
-    k_pool: jnp.ndarray,   # [L, N, H, bs, D]
+    k_pool: jnp.ndarray,   # stored pool (`pool_shape`)
     v_pool: jnp.ndarray,
     src: jnp.ndarray,      # scalar int32 source block
     dst: jnp.ndarray,      # scalar int32 destination block
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    return (
-        k_pool.at[:, dst].set(k_pool[:, src]),
-        v_pool.at[:, dst].set(v_pool[:, src]),
-    )
+    def copy(pool):
+        blocks = as_blocks(pool)
+        return jax.lax.dynamic_update_slice_in_dim(
+            blocks, jax.lax.dynamic_slice_in_dim(blocks, src, 1, axis=1),
+            dst, axis=1,
+        ).reshape(pool.shape)
+
+    return copy(k_pool), copy(v_pool)
 
 
 # Copy-on-write: duplicate one pool block across all layers.

@@ -303,21 +303,19 @@ def test_train_step_flash_kernel_names(chip, topo, monkeypatch):
     assert text.count("tpu_custom_call") == 2 * config.n_layer
 
 
-def test_engine_decode_step_kernel_name(chip, topo, monkeypatch):
-    """The engine's decode step - ``_decode_step_impl`` bound and named as
-    ``ServingEngine`` binds and names it - at the 1.5B widths, 2 layers:
-    its one Mosaic kernel sits in the layer scan under the name
-    ``paged_attn_roofline`` matches. The jit's own name (``decode_step``)
-    names the module, not the instruction. (The kernel compiled alone, as
-    ``test_paged_decode`` compiles it, does not get this name.)"""
+def _engine_programs(chip, topo, monkeypatch, num_blocks=4 * 64 + 1):
+    """The decode step and the 256-wide chunk prefill, bound, named, donated
+    and handed their pools as ``ServingEngine`` does - stored in
+    ``paged_cache.pool_shape`` - at the 1.5B widths, 2 layers, 4 slots."""
     from gpt_2_distributed_tpu.config import ServeConfig
     from gpt_2_distributed_tpu.models import gpt2
     from gpt_2_distributed_tpu.serving import engine as eng
+    from gpt_2_distributed_tpu.serving.paged_cache import pool_shape
 
     monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
     config = MODEL_PRESETS["1.5B"].replace(n_layer=2)
-    serve = ServeConfig(max_batch=4, block_size=16, num_blocks=4 * 64 + 1,
-                        attn_impl="pallas")
+    serve = ServeConfig(max_batch=4, block_size=16, num_blocks=num_blocks,
+                        prefill_chunk=256, attn_impl="pallas")
 
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -325,18 +323,70 @@ def test_engine_decode_step_kernel_name(chip, topo, monkeypatch):
     params = jax.tree_util.tree_map(
         lambda a: arr(a.shape, a.dtype),
         jax.eval_shape(lambda: gpt2.init_params(config)))
-    pool = arr((config.n_layer, serve.num_blocks, config.n_head,
-                serve.block_size, config.head_dim), BF16)
+    pool = arr(pool_shape(config, serve), BF16)
     b, m = serve.max_batch, serve.max_blocks_per_seq(config.n_positions)
     decode = jax.jit(
         eng._program("decode_step", eng._decode_step_impl, config=config,
                      temperature=0.0, top_k=None, attn_impl=serve.attn_impl),
         donate_argnames=("k_pool", "v_pool"),
-    )
-    text = decode.lower(
+    ).lower(
         params, pool, pool, arr((b, m), I32), arr((b,), I32), arr((b,), I32),
-        arr((b,), jnp.bool_), arr((b, 2), jnp.uint32)).compile().as_text()
+        arr((b,), jnp.bool_), arr((b, 2), jnp.uint32)).compile()
+    chunk = jax.jit(
+        eng._program("chunk_prefill", eng._chunk_prefill_impl, config=config,
+                     temperature=0.0, top_k=None),
+        donate_argnames=("k_pool", "v_pool"),
+    ).lower(
+        params, pool, pool, arr((1, m), I32),
+        arr((1, serve.prefill_chunk), I32), arr((1,), I32), arr((1,), I32),
+        arr((1, 2), jnp.uint32)).compile()
+    return decode, chunk, pool.shape
+
+
+def test_engine_decode_step_kernel_name(chip, topo, monkeypatch):
+    """The engine's decode step - ``_decode_step_impl`` bound and named as
+    ``ServingEngine`` binds and names it - at the 1.5B widths, 2 layers:
+    its one Mosaic kernel sits in the layer scan under the name
+    ``paged_attn_roofline`` matches. The jit's own name (``decode_step``)
+    names the module, not the instruction. (The kernel compiled alone, as
+    ``test_paged_decode`` compiles it, does not get this name.)"""
+    text = _engine_programs(chip, topo, monkeypatch)[0].as_text()
     assert "jit_decode_step" in text.splitlines()[0]
     kernel = _instructions_matching(
         text, _reader_constants("paged_attn_roofline")["KERNEL"])
     assert len(kernel) == 1 and text.count("tpu_custom_call") == 1
+
+
+def test_engine_programs_never_copy_their_pools(chip, topo, monkeypatch):
+    """One layout for a pool, end to end (PR 26). As the engine builds
+    them, the decode step and the chunk prefill take the pools row-major at
+    entry - the compiler's own default for the stored shape, so a program
+    read back from the compile cache has it too - alias them to their
+    outputs, and hold no ``copy`` of anything pool-shaped: neither a whole
+    pool nor a layer's slice, whichever way the block axis is spelt. And
+    their temporaries do not grow with the pool: twice the blocks, the same
+    bytes. (They cannot be "below the pools' bytes": the hoisted bf16 copy
+    of the weights is larger than 4 slots' pools at any depth.)"""
+    import re
+
+    small = _engine_programs(chip, topo, monkeypatch)
+    large = _engine_programs(chip, topo, monkeypatch, num_blocks=8 * 64 + 1)
+    shape = small[2]
+    assert shape == (2, 6, 43, 25, 16, 64)
+    stored = "bf16[" + ",".join(map(str, shape)) + "]{5,4,3,2,1,0:"
+    blocks = {"6,43", "257", "258"}     # split, asked for, merged
+    for name, compiled, bigger in zip(("decode", "chunk"), small, large):
+        text = compiled.as_text()
+        head = text.splitlines()[0]
+        assert head.count(stored) == 4, (name, head)    # 2 pools in, 2 out
+        assert head.count("may-alias") == 2, (name, head)
+        for line in text.splitlines():
+            made = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) copy(?:-start)?\(", line)
+            if made:
+                dims = re.search(r"\[([\d,]*)\]", made.group(1)).group(1)
+                assert not any(
+                    re.search(rf"(^|,){b}(,|$)", dims) for b in blocks
+                ), (name, line.strip()[:160])
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        grown = bigger.memory_analysis().temp_size_in_bytes
+        assert abs(grown - temp) < 2**20, (name, temp, grown)

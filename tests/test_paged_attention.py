@@ -16,6 +16,7 @@ from gpt_2_distributed_tpu.ops.paged_attention import (
     paged_attention_xla,
     paged_prefill_attention,
 )
+from gpt_2_distributed_tpu.serving.paged_cache import write_chunk, write_rows
 
 
 def _paged_case(rng, b=3, h=2, d=8, bs=4, m=4, n_blocks=32, scramble=True):
@@ -193,6 +194,105 @@ def test_prefill_future_positions_are_bitwise_invisible(rng_np):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
+@pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("heads", [25, 12], ids=["H25", "H12"])
+def test_layer_of_the_whole_pool_matches_its_slice(rng_np, heads, layer):
+    """Every op takes the whole ``[L, N, H, bs, D]`` pool and a layer: the
+    kernel (interpret mode; the layer folded into its block table) and the
+    one-gather XLA paths give what the XLA path gives on ``pool[layer]``,
+    for ragged lengths with an idle row and a full table."""
+    l, n, bs, d, m = 5, 9, 16, 64, 2
+    q = jnp.asarray(rng_np.normal(size=(4, heads, d)), jnp.float32)
+    kp = jnp.asarray(rng_np.normal(size=(l, n, heads, bs, d)), jnp.float32)
+    vp = jnp.asarray(rng_np.normal(size=(l, n, heads, bs, d)), jnp.float32)
+    table = jnp.asarray(
+        rng_np.permutation(np.arange(1, n)).reshape(4, m), jnp.int32)
+    lengths = jnp.asarray([0, m * bs, 17, 1], jnp.int32)
+    want = paged_attention_xla(q, kp[layer], vp[layer], table, lengths)
+    got = paged_attention_xla(q, kp, vp, table, lengths, jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = paged_attention_pallas(q, kp, vp, table, lengths, jnp.int32(layer))
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got[0]), 0.0)      # the idle row
+    qc = jnp.asarray(rng_np.normal(size=(4, 3, heads, d)), jnp.float32)
+    start = jnp.asarray([0, m * bs - 3, 14, 5], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(paged_prefill_attention(
+            qc, kp, vp, table, start, jnp.int32(layer))),
+        np.asarray(paged_prefill_attention(
+            qc, kp[layer], vp[layer], table, start)))
+
+
+def _scattered(pool, layer, blk, off, new):
+    """The write as the step programs made it before PR 26: one scatter,
+    rows whose block is out of range dropped."""
+    return pool.at[layer, blk, :, off].set(new.astype(pool.dtype), mode="drop")
+
+
+WRITES = {
+    # rows: (start, clen) of a C-wide chunk; bs = 4, M = 4 (16 positions)
+    "mid-block to mid-block, a padding row, a short row":
+        (6, [(2, 5), (0, 0), (5, 2)]),
+    "aligned full blocks, a one-token row, a row to the table's end":
+        (8, [(4, 8), (7, 1), (8, 8)]),
+    "a window that straddles the table's end (the verify pass masks it)":
+        (5, [(13, 3), (0, 5), (11, 5)]),
+}
+
+
+@pytest.mark.parametrize("case", WRITES)
+def test_chunk_write_leaves_the_pool_a_scatter_would(rng_np, case):
+    """``write_chunk`` (whole blocks read, merged and put back) against the
+    position-granular scatter it replaces: bit-equal pools, in every layer -
+    padding rows dropped, the rest of each partial block untouched."""
+    c, rows = WRITES[case]
+    l, n, h, bs, d, m = 3, 16, 2, 4, 8, 4
+    r = len(rows)
+    kp = jnp.asarray(rng_np.normal(size=(l, n, h, bs, d)), jnp.bfloat16)
+    vp = jnp.asarray(rng_np.normal(size=(l, n, h, bs, d)), jnp.bfloat16)
+    k = jnp.asarray(rng_np.normal(size=(r, c, h, d)), jnp.float32)
+    v = jnp.asarray(rng_np.normal(size=(r, c, h, d)), jnp.float32)
+    bt = jnp.asarray(
+        rng_np.permutation(np.arange(1, n))[: r * m].reshape(r, m), jnp.int32)
+    start = jnp.asarray([s for s, _ in rows], jnp.int32)
+    clen = jnp.asarray([n_ for _, n_ in rows], jnp.int32)
+    pos = start[:, None] + jnp.arange(c)[None]
+    valid = (jnp.arange(c)[None] < clen[:, None]) & (pos < m * bs)
+    blk = jnp.take_along_axis(bt, jnp.minimum(pos // bs, m - 1), axis=1)
+    blk = jnp.where(valid, blk, n)
+    layer = 1
+    got_k, got_v = jax.jit(write_chunk)(
+        kp, vp, jnp.int32(layer), bt, start, valid, k, v)
+    np.testing.assert_array_equal(
+        np.asarray(got_k, np.float32),
+        np.asarray(_scattered(kp, layer, blk, pos % bs, k), np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(got_v, np.float32),
+        np.asarray(_scattered(vp, layer, blk, pos % bs, v), np.float32))
+
+
+def test_row_write_leaves_the_pool_a_scatter_would(rng_np):
+    """``write_rows``, the decode step's write: active rows each to their
+    own block, two rows into one block, idle rows onto the null block at
+    their own offsets - bit-equal with the scatter it replaces."""
+    l, n, h, bs, d = 3, 8, 2, 4, 8
+    kp = jnp.asarray(rng_np.normal(size=(l, n, h, bs, d)), jnp.bfloat16)
+    vp = jnp.asarray(rng_np.normal(size=(l, n, h, bs, d)), jnp.bfloat16)
+    k = jnp.asarray(rng_np.normal(size=(6, h, d)), jnp.bfloat16)
+    v = jnp.asarray(rng_np.normal(size=(6, h, d)), jnp.bfloat16)
+    blk = jnp.asarray([5, 0, 2, 2, 0, 7], jnp.int32)   # rows 1 and 4 idle
+    off = jnp.asarray([3, 1, 0, 2, 2, 1], jnp.int32)
+    layer = 2
+    got_k, got_v = jax.jit(write_rows)(kp, vp, jnp.int32(layer), blk, off, k, v)
+    np.testing.assert_array_equal(
+        np.asarray(got_k, np.float32),
+        np.asarray(_scattered(kp, layer, blk, off, k), np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(got_v, np.float32),
+        np.asarray(_scattered(vp, layer, blk, off, v), np.float32))
+
+
 def test_rejects_bad_impl_and_shapes(rng_np):
     q, kp, vp, table, lengths, _, _ = _paged_case(rng_np)
     with pytest.raises(ValueError, match="impl="):
@@ -201,6 +301,8 @@ def test_rejects_bad_impl_and_shapes(rng_np):
         paged_attention(q[:, :, None], kp, vp, table, lengths)
     with pytest.raises(ValueError, match="matching"):
         paged_attention(q, kp, vp[:-1], table, lengths)
+    with pytest.raises(ValueError, match="matching"):      # a stored 6-D pool
+        paged_attention(q, kp[None, None], vp[None, None], table, lengths)
 
 
 @pytest.mark.parametrize("impl,line", [

@@ -495,6 +495,41 @@ def test_cow_on_block_aligned_cached_prompt(tiny_params, tiny_config):
     assert h3.generated == ref3
 
 
+@pytest.mark.parametrize("chunk", [0, 5], ids=["whole-prompt", "chunked"])
+def test_split_block_axis_pool_bit_parity(tiny_params, tiny_config, chunk):
+    """Past 64 blocks a pool is stored with its block axis split (70 ->
+    [L, 2, 35, H, bs, D]; on the TPU that keeps it row-major, PR 26) and
+    every program works on the merged view: block ids on both sides of the
+    seam - whole-prompt scatter or chunk writes, a copy-on-write, decode
+    writes - and every stream still equals ``generate_cached``."""
+    from gpt_2_distributed_tpu.serving.paged_cache import pool_shape, split_blocks
+
+    assert split_blocks(64) == (64,) and split_blocks(257) == (6, 43)
+    three = split_blocks(8193)          # 128 slots x 64 blocks + the null one
+    assert len(three) == 3 and max(three) <= 64
+    assert 8193 <= three[0] * three[1] * three[2] <= 8200
+    serve = _serve(num_blocks=70, prefill_chunk=chunk, prefix_cache=True)
+    eng = ServingEngine(tiny_params, tiny_config, serve, temperature=0.0)
+    assert eng.k_pool.shape == pool_shape(tiny_config, serve)
+    assert eng.k_pool.shape[1:3] == (2, 35)
+    shared = list(range(100, 116))                  # two full blocks of 8
+    prompts = [shared] * 2 + [[30 + i] * (14 + i) for i in range(10)]
+    keys = [jax.random.PRNGKey(70 + i) for i in range(len(prompts))]
+    seen = set()
+    hs = [eng.submit(prompts[0], 12, rng=keys[0])]
+    eng.run_until_idle(max_steps=100)   # cached before its twin arrives
+    hs += [eng.submit(p, 12, rng=k) for p, k in zip(prompts[1:], keys[1:])]
+    while not all(h.done for h in hs):
+        eng.step()
+        seen.update(int(b) for b in eng.block_table.ravel())
+    assert eng.stats["cow_copies"] >= 1
+    assert min(seen - {0}) < 35 < max(seen), sorted(seen)
+    for h, p, k in zip(hs, prompts, keys):
+        assert h.generated == _oneshot(
+            tiny_params, tiny_config, p, k, 12, temperature=0.0), h.id
+    assert eng.k_pool.shape == pool_shape(tiny_config, serve)
+
+
 # ------------------------------------------- watermark admission / preempt
 
 
