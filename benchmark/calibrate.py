@@ -6,9 +6,10 @@ seeds in one process (one compile, one machine):
 
 ``program``: the timed path against the reference, as a run compares them
 (the lower reading is the largest over a dozen seeds or more). ``control``:
-the reference put in the program's place, computed with fp8 matmul
-operands - the nearest precision below the configurations' bfloat16 (the
-upper reading is the smallest it gives). ``fault`` (training): half of the
+the reference put in the program's place, computed with the family's
+``control_matmul`` - the nearest precision below the one the configuration
+states, fp8 operands under GPT-2's bfloat16 (the upper reading is the
+smallest it gives). ``fault`` (training): half of the
 batch left out and the mean taken over the rest, planted in the reference
 put in the program's place. A step that returns its state unchanged reads 1
 on ``moved_norm_gap`` by that number's measure and needs no run.
@@ -26,10 +27,11 @@ import sys
 import time
 
 
-def _training(cell, seed, what):
+def training(cell, seed, what):
+    """One seed's readings of a training cell."""
     from benchmark import check, harness, train_cell
-    from benchmark.reference import gpt2 as ref
 
+    leaf_norms = cell["reference"].leaf_norms
     spans = harness.Spans()
     trainer = train_cell.Trainer(cell, seed, spans)
     try:
@@ -46,31 +48,33 @@ def _training(cell, seed, what):
     out = {"reference_s": time.monotonic() - t0,
            "losses": observed["losses"], "reference_losses": reference["losses"]}
     if "program" in what:
-        got = check.training_numbers(observed, reference)
+        got = check.training_numbers(observed, reference, leaf_norms)
         out["program"] = got["numbers"]
         out["program_leaves"] = got["leaves"]
     if "control" in what:
         control = train_cell.reference_numbers(
-            cell, seed, observed, lr, wd, matmul=ref.fp8_matmul)
-        out["control"] = check.training_numbers(control, reference)["numbers"]
+            cell, seed, observed, lr, wd, control=True)
+        out["control"] = check.training_numbers(
+            control, reference, leaf_norms)["numbers"]
     if "fault" in what:
         halved = dict(observed, batches=[
             (x[: len(x) // 2], y[: len(y) // 2]) for x, y in observed["batches"]])
         half = train_cell.reference_numbers(cell, seed, halved, lr, wd)
-        out["fault_half_batch"] = check.training_numbers(half, reference)["numbers"]
+        out["fault_half_batch"] = check.training_numbers(
+            half, reference, leaf_norms)["numbers"]
     return out
 
 
-def _serving(cell, seed, what, seconds):
+def serving(cell, seed, what, seconds):
+    """One seed's readings of a serving cell, over a window of ``seconds``."""
     from benchmark import harness, serve_cell
-    from benchmark.reference import gpt2 as ref
 
     spans = harness.Spans()
     profiler = harness.ProfilerWindow(cell["name"], spans, False)
     engine, driver = serve_cell.build_engine(cell, seed)
     try:
-        serve_cell.warm_up(cell, engine, driver, cell["config_file"]["vocab_size"])
-        seen, _, _, _ = serve_cell.run_window(
+        serve_cell.warm_up(cell, engine, driver)
+        seen, *_ = serve_cell.run_window(
             cell, seed, seconds, engine, driver, spans, profiler)
     finally:
         driver.close()
@@ -78,10 +82,11 @@ def _serving(cell, seed, what, seconds):
     sample = serve_cell.sample_for_check(cell, finished, seed)
     del engine, driver
     gc.collect()
-    weights = ref.make_weights(ref.sizes_of(cell["config_file"]), seed)
+    # one reference for both readings: its weights are made once
+    logits = cell["reference"].serving_reference(cell["sizes"], seed)
     out = {"finished": len(finished), "sampled": len(sample)}
     t0 = time.monotonic()
-    gaps = serve_cell.logit_gaps(cell, seed, sample, weights=weights)
+    gaps = serve_cell.logit_gaps(cell, seed, sample, logits=logits)
     out["reference_s"] = time.monotonic() - t0
     out["checked_tokens"] = int(len(gaps))
     if "program" in what:
@@ -89,7 +94,7 @@ def _serving(cell, seed, what, seconds):
                           "tokens_off_best": int((gaps > 0).sum())}
     if "control" in what:
         rough = serve_cell.logit_gaps(
-            cell, seed, sample, matmul=ref.fp8_matmul, weights=weights)
+            cell, seed, sample, control=True, logits=logits)
         out["control"] = {"token_logit_gap": float(rough.max()),
                           "tokens_off_best": int((rough > 0).sum())}
     return out
@@ -118,10 +123,10 @@ def main(argv=None) -> int:
         sink = open(args.out, "a")
     try:
         for seed in (int(s) for s in args.seeds.split(",")):
-            if cell["mix"]["kind"] == "train":
-                row = _training(cell, seed, what)
+            if harness.runner_name(cell) == "train_cell":
+                row = training(cell, seed, what)
             else:
-                row = _serving(cell, seed, what, args.seconds)
+                row = serving(cell, seed, what, args.seconds)
             row = {"workload": args.workload, "seed": seed, **row}
             line = json.dumps(row)
             print(line, flush=True)

@@ -33,15 +33,15 @@ def worst_leaf_gap(program: dict, reference: dict, skip=()) -> tuple[float, str]
     return worst, where
 
 
-def worst_leaf_difference(program_tree, reference_tree, reference: dict):
+def worst_leaf_difference(program_tree, reference_tree, reference: dict,
+                          leaf_norms):
     """The worst leaf's norm of the difference between the two trees - what
     rounding noise shows in, where a gap of norms averages it away -
-    against the reference's norm of that leaf or of the median leaf."""
+    against the reference's norm of that leaf or of the median leaf.
+    ``leaf_norms`` is the family's: it says what a leaf is."""
     import jax
 
-    from benchmark.reference import gpt2 as ref
-
-    diff = ref.leaf_norms(jax.tree_util.tree_map(
+    diff = leaf_norms(jax.tree_util.tree_map(
         lambda a, b: a - b, program_tree, reference_tree))
     median = statistics.median(reference.values())
     name = max(diff, key=lambda n: diff[n] / max(reference[n], median))
@@ -54,11 +54,12 @@ def nought_gradient_leaves(reference_grad: dict) -> set[str]:
             if v < NOUGHT_GRADIENT_SHARE * median}
 
 
-def training_numbers(program: dict, reference: dict) -> dict:
+def training_numbers(program: dict, reference: dict, leaf_norms) -> dict:
     """``program`` and ``reference`` each hold ``losses`` (one a step),
     ``grad`` (leaf norms of the first gradient as the optimizer got it) and
     ``moved`` (leaf norms of the parameters' change over those steps); the
-    reference also counts the rows that the program was fed wrong."""
+    reference also counts the rows that the program was fed wrong.
+    ``leaf_norms`` is the family's view of a parameter tree."""
     numbers = {}
     if "data_rows_wrong" in reference:
         numbers["data_rows_wrong"] = reference["data_rows_wrong"]
@@ -72,7 +73,8 @@ def training_numbers(program: dict, reference: dict) -> dict:
     leaves = {"grad_norm_gap": grad_leaf, "moved_norm_gap": moved_leaf}
     if "grad_tree" in program and "grad_tree" in reference:
         numbers["grad_diff"], leaves["grad_diff"] = worst_leaf_difference(
-            program["grad_tree"], reference["grad_tree"], reference["grad"])
+            program["grad_tree"], reference["grad_tree"], reference["grad"],
+            leaf_norms)
     return {"numbers": numbers, "leaves": leaves}
 
 

@@ -2,15 +2,22 @@
 data files, the device check, the compile counter, host spans, the profiler
 window, the per-layer readers and the result line.
 
-Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is a file of its own, found by the name in
-``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<traffic>.json``,
-``limits/<workload>.json``, ``metrics/<metric>.py``.
+Everything that belongs to one configuration, one traffic mix, one
+per-layer metric or one family of models is a file of its own, found by the
+name in ``BENCHMARK.json``: ``configs/<config>.json``,
+``traffic/<traffic>.json``, ``limits/<workload>.json``,
+``metrics/<metric>.py`` - and, by the ``family`` that the configuration file
+names, ``reference/<family>.py`` (the plain reference and the family's shape
+arithmetic; imports nothing of the program) and ``program/<family>.py`` (the
+one file that names the package's model schema for that family). Nothing
+else under ``benchmark/`` knows a model's keys.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import importlib
 import importlib.util
 import json
 import os
@@ -33,9 +40,83 @@ def load_json(*parts: str):
         return json.load(f)
 
 
+# The one place that says which runner drives a kind of traffic, and what the
+# two modules of a family export for that runner, whatever the architecture.
+# A family that does not train leaves ``train_cell``'s names out; a mix of a
+# kind that no runner drives is refused when its cell is loaded.
+RUNNER_OF_KIND = {"train": "train_cell", "backlog": "serve_cell"}
+FAMILY_CONTRACT = {
+    "reference": {
+        "always": ("sizes_of", "make_weights", "control_matmul", "attention_shapes"),
+        "serve_cell": ("serving_reference", "forward_flops_per_token"),
+        "train_cell": ("train_steps", "leaf_norms", "train_flops_per_token", "ADAM_B1"),
+    },
+    "program": {
+        "always": (),
+        "serve_cell": ("model_config", "serve_config"),
+        "train_cell": ("trainer_flags", "train_model_config"),
+    },
+}
+
+
+def runner_name(cell: dict) -> str:
+    """``serve_cell`` or ``train_cell``: the module of the benchmark whose
+    ``run`` drives the cell's kind of traffic."""
+    kind = cell["mix"].get("kind")
+    if kind not in RUNNER_OF_KIND:
+        raise RunFailed(f"traffic mix {cell['traffic']!r}: kind {kind!r} is none "
+                        f"of {sorted(RUNNER_OF_KIND)}")
+    return RUNNER_OF_KIND[kind]
+
+
+def runner_of(cell: dict):
+    return importlib.import_module("benchmark." + runner_name(cell))
+
+
+@functools.cache
+def _module_at(path: str):
+    if not os.path.exists(path):
+        raise RunFailed(f"no {os.path.relpath(path, ROOT)}")
+    stem = os.path.relpath(path, BENCH_DIR)[:-len(".py")]
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_" + "".join(c if c.isalnum() else "_" for c in stem), path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_module(kind: str, name: str):
+    """``<kind>/<name>.py`` of the benchmark, loaded by path, once (a
+    metric's name may hold dots, and a later PR adds files, not ``import``
+    lines)."""
+    return _module_at(os.path.join(BENCH_DIR, kind, name + ".py"))
+
+
+def attach_family(cell: dict) -> dict:
+    """Put the configuration's ``family`` on the cell: its two modules as
+    ``cell["reference"]`` and ``cell["program"]``, held to the contract's
+    names for the cell's runner, and ``cell["sizes"]``, the model's sizes as
+    the family's reference reads them from the configuration file. Whatever
+    else the sizes hold, they hold ``vocab_size``: the traffic draws its
+    token ids from it."""
+    family = cell["config_file"].get("family")
+    if not family:
+        raise RunFailed(f"configuration {cell['config']!r} names no \"family\"")
+    runner = runner_name(cell)
+    for kind, names in FAMILY_CONTRACT.items():
+        module = load_module(kind, family)
+        missing = [n for n in names["always"] + names[runner]
+                   if not hasattr(module, n)]
+        if missing:
+            raise RunFailed(f"benchmark/{kind}/{family}.py lacks {missing}")
+        cell[kind] = module
+    cell["sizes"] = cell["reference"].sizes_of(cell["config_file"])
+    return cell
+
+
 def load_cell(workload: str) -> dict:
-    """The cell's entry with its configuration, traffic mix, limits and
-    the names of the metrics it reports."""
+    """The cell's entry with its configuration, its family's two modules and
+    sizes, its traffic mix, limits and the names of the metrics it reports."""
     manifest = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
@@ -46,7 +127,8 @@ def load_cell(workload: str) -> dict:
     cell["config_file"] = load_json(ROOT, entry["file"])
     from benchmark import traffic
 
-    cell["mix"] = traffic.load_mix(cell["traffic"])
+    cell["mix"] = traffic.load_mix(cell["traffic"], os.path.join(BENCH_DIR, "traffic"))
+    attach_family(cell)
     limits_path = os.path.join(BENCH_DIR, "limits", f"{workload}.json")
     cell["limits"] = load_json(limits_path) if os.path.exists(limits_path) else {}
 
@@ -171,12 +253,14 @@ class ProfilerWindow:
         self._window.__enter__()
         self.started_at = time.monotonic()
 
-    def maybe_stop(self, force: bool = False) -> None:
-        """Call between steps, with the device drained."""
+    def maybe_stop(self, force: bool = False) -> bool:
+        """Call between steps, with the device drained. True when this call
+        stopped the recording: the moment to read the program's counters
+        for the traced part of the window."""
         if not self.active:
-            return
+            return False
         if not force and time.monotonic() - self.started_at < TRACE_SECONDS:
-            return
+            return False
         import jax
 
         self._window.__exit__(None, None, None)
@@ -184,6 +268,7 @@ class ProfilerWindow:
         jax.profiler.stop_trace()
         self.stopped_at = time.monotonic()
         self.active = False
+        return True
 
     def read(self):
         from benchmark.reduce_trace import Trace
@@ -203,14 +288,8 @@ class ProfilerWindow:
 
 
 def load_reader(name: str):
-    """The ``read(ctx)`` of ``metrics/<name>.py`` (a name may hold dots, so
-    the file is loaded by path)."""
-    path = os.path.join(BENCH_DIR, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_metric_" + name.replace(".", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    """The ``read(ctx)`` of ``metrics/<name>.py``."""
+    return load_module("metrics", name).read
 
 
 def read_layer_metrics(cell: dict, ctx: dict) -> dict:
@@ -231,22 +310,22 @@ def metrics_of(cell: dict, values: dict, device: dict, profiler: "ProfilerWindow
     """(metrics, breakdown) of a run. Untraced: the cell's end-to-end
     metrics out of ``values``. Traced: the trace is reduced, ``busy_s`` and
     ``window_s`` go into ``device``, and the cell's per-layer readers get
-    ``ctx`` with the trace, the spans, the peaks and the sizes added."""
+    ``ctx`` with the cell (its family's reference, for the shape arithmetic,
+    is ``ctx["cell"]["reference"]``), the trace, the spans, the peaks and the
+    sizes added."""
     if not profiler.enabled:
         missing = [m["name"] for m in cell["end_to_end"] if m["name"] not in values]
         if missing:
             raise RunFailed(f"the window gave no {missing}")
         return {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                 for m in cell["end_to_end"]}, None
-    from benchmark.reference.gpt2 import sizes_of
-
     trace = profiler.read()
     lo, hi = trace.window_ns()
     device["busy_s"] = trace.busy_seconds(lo, hi)
     device["window_s"] = (hi - lo) / 1e9
     breakdown = {"device_ops": trace.top_ops(lo, hi),
                  "idle_gaps": trace.idle_gaps(lo, hi)}
-    ctx = dict(ctx, cell=cell, sizes=sizes_of(cell["config_file"]),
+    ctx = dict(ctx, cell=cell, sizes=cell["sizes"],
                peaks=load_peaks(device["kind"]), spans=spans.records,
                values=values, trace=trace, trace_window_ns=(lo, hi), device=device)
     return read_layer_metrics(cell, ctx), breakdown

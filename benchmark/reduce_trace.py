@@ -8,7 +8,9 @@ per chip whose line ``XLA Ops`` carries one event per executed HLO
 operation, named by the instruction's whole text, a line ``XLA Modules``
 with one event per program run, and a plane ``/host:CPU`` with one line per
 host thread, where the harness's own ``jax.profiler.TraceAnnotation`` spans
-(named ``bench/<span>``) land. All planes share one clock, in nanoseconds.
+(named ``bench/<span>``) and the program's (``gpt2/<span>``: every span of
+its tracer entered while a capture records) land. All planes share one
+clock, in nanoseconds.
 Two things a reader has to know. Operations nest: a ``while`` or a
 conditional is an event that spans the events of its body, so time by
 operation is self time. And a Pallas kernel shows as ``%<hlo name> = ...
@@ -31,7 +33,7 @@ import sys
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
-SPAN_PREFIX = "bench/"
+SPAN_PREFIXES = ("bench/", "gpt2/")   # the harness's spans, the program's
 
 
 class NoKernelEvent(LookupError):
@@ -63,7 +65,8 @@ def union_seconds(intervals) -> float:
 
 
 class Trace:
-    """Device operations per chip and the harness's host spans."""
+    """Device operations per chip, and the host spans of the harness and of
+    the program."""
 
     def __init__(self, device_ops: dict[str, list], host_spans: list):
         # plane name -> [(name, start_ns, end_ns)], sorted by start
@@ -71,8 +74,9 @@ class Trace:
             # an enclosing operation before those nested in it
             k: sorted(v, key=lambda e: (e[1], -e[2])) for k, v in device_ops.items()
         }
-        # [(span name without the prefix, start_ns, end_ns)]
-        self.host_spans = sorted(host_spans, key=lambda e: e[1])
+        # [(span name without its prefix, start_ns, end_ns)], an enclosing
+        # span before those nested in it
+        self.host_spans = sorted(host_spans, key=lambda e: (e[1], -e[2]))
 
     @classmethod
     def from_file(cls, path: str) -> "Trace":
@@ -93,14 +97,13 @@ class Trace:
             elif plane.name == HOST_PLANE:
                 for line in plane.lines:
                     for ev in line.events:
-                        if ev.name.startswith(SPAN_PREFIX):
+                        name = span_name(ev.name)
+                        if name is not None:
                             host_spans.append((
-                                ev.name[len(SPAN_PREFIX):], ev.start_ns,
-                                ev.start_ns + ev.duration_ns,
-                            ))
+                                name, ev.start_ns, ev.start_ns + ev.duration_ns))
         return cls(device_ops, host_spans)
 
-    # -- the traced window: the outermost harness span, or all device ops --
+    # -- the traced window: the harness's outermost span, or all device ops --
 
     def window_ns(self, span: str = "window") -> tuple[float, float]:
         spans = [(s, e) for n, s, e in self.host_spans if n == span]
@@ -171,9 +174,11 @@ class Trace:
         return [[name, sec / chips] for name, sec in ranked]
 
     def idle_gaps(self, lo: float, hi: float, n: int = 10):
-        """Idle time of the first chip by the innermost harness span open
-        when each gap began (``(none)`` where no span was open), the ``n``
-        largest sums."""
+        """Idle time of the first chip by the innermost span open when each
+        gap began - the program's own where it has one there (``admit``,
+        ``prefill``, ``dispatch``, ``readback``, ``emit``...), else the
+        harness's (``submit``, ``step``...), ``(none)`` where no span was
+        open - the ``n`` largest sums."""
         if not self.device_ops:
             return []
         plane = sorted(self.device_ops)[0]
@@ -205,6 +210,15 @@ class Trace:
             by_span[name] = by_span.get(name, 0.0) + (g1 - g0) / 1e9
         ranked = sorted(by_span.items(), key=lambda kv: -kv[1])[:n]
         return [[name, sec] for name, sec in ranked]
+
+
+def span_name(event_name: str) -> str | None:
+    """``bench/step`` -> ``step``, ``gpt2/admit`` -> ``admit``; None for
+    what is no span of the harness or the program."""
+    for prefix in SPAN_PREFIXES:
+        if event_name.startswith(prefix):
+            return event_name[len(prefix):]
+    return None
 
 
 def short_name(hlo_text: str) -> str:

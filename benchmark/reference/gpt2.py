@@ -12,11 +12,19 @@ tree feeds both; the values come from ``make_weights`` (below), which the
 benchmark calls once for the program and again, after the program's state is
 freed, for the reference.
 
-``matmul`` is the hook the control uses: ``fp8_matmul`` computes every
-weight matmul (and the head) on 8-bit floating-point operands, the nearest
-precision below the bfloat16 the configurations state. (Per-row-scaled
-int8, the other 8-bit choice, turned out as exact as the program's bfloat16
-and cannot serve as a control: PERF.md, Findings, PR 24.)
+``matmul`` is the hook the control uses: ``control_matmul`` (``fp8_matmul``)
+computes every weight matmul (and the head) on 8-bit floating-point
+operands, the nearest precision below the bfloat16 the configurations state.
+(Per-row-scaled int8, the other 8-bit choice, turned out as exact as the
+program's bfloat16 and cannot serve as a control: PERF.md, Findings, PR 24.)
+
+This is the family ``gpt2``: a configuration file that says ``"family":
+"gpt2"`` gets this module as its reference (``harness.attach_family``). What
+every family's reference exports, under these names (``FAMILY_CONTRACT`` in
+``benchmark/harness.py``): ``sizes_of``, ``make_weights``, ``control_matmul``
+and ``attention_shapes``; for serving ``serving_reference`` and
+``forward_flops_per_token``; for training ``train_steps``, ``leaf_norms``,
+``train_flops_per_token`` and ``ADAM_B1``.
 """
 
 from __future__ import annotations
@@ -43,6 +51,41 @@ def sizes_of(config: dict) -> dict:
 
 def _frozen(sizes: dict) -> tuple:
     return tuple(sorted(sizes.items()))
+
+
+# --- operations from shapes: what the algorithm needs, never what an
+# implementation happens to do; recomputed operations do not count ----------
+
+
+def matmul_params(sizes: dict) -> int:
+    """Parameters that take part in matmuls: per block qkv (3C^2), attention
+    projection (C^2) and MLP (8C^2), plus the tied head's [C, V] projection.
+    Embedding lookups are gathers, not operations."""
+    c, l, v = sizes["n_embd"], sizes["n_layer"], sizes["vocab_size"]
+    return l * 12 * c * c + c * v
+
+
+def train_flops_per_token(sizes: dict, seq_len: int) -> float:
+    """Forward and backward: 6 per matmul parameter, and the attention
+    score and value matmuls (2 * 2*C*T forward, twice that backward) in
+    each layer, counted over the full square as the usual convention does."""
+    c, l = sizes["n_embd"], sizes["n_layer"]
+    return 6.0 * matmul_params(sizes) + 12.0 * l * c * seq_len
+
+
+def forward_flops_per_token(sizes: dict, context: float) -> float:
+    """One forward pass of one token that attends over ``context`` keys."""
+    c, l = sizes["n_embd"], sizes["n_layer"]
+    return 2.0 * matmul_params(sizes) + 4.0 * l * c * context
+
+
+def attention_shapes(sizes: dict) -> dict:
+    """What a kernel's roofline needs of the model: the layers that hold a
+    KV cache, the query heads, the KV heads and the head width. Every layer
+    of GPT-2 is full multi-head attention."""
+    heads = sizes["n_head"]
+    return {"kv_layers": sizes["n_layer"], "heads": heads, "kv_heads": heads,
+            "head_dim": sizes["n_embd"] // heads}
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -131,6 +174,8 @@ def _fp8_matmul_bwd(saved, dy):
 
 fp8_matmul.defvjp(_fp8_matmul_fwd, _fp8_matmul_bwd)
 
+control_matmul = fp8_matmul   # the nearest precision below the stated bfloat16
+
 
 def _layer_norm(x, scale, bias, eps):
     mean = jnp.mean(x, axis=-1, keepdims=True)
@@ -195,6 +240,16 @@ def _logits_jit(frozen_sizes, w, idx, matmul):
 
 def logits(w, sizes: dict, idx, matmul=plain_matmul):
     return _logits_jit(_frozen(sizes), w, jnp.asarray(idx, jnp.int32), matmul)
+
+
+def serving_reference(sizes: dict, seed: int):
+    """The serving check's reference: ``logits(ids, matmul=plain_matmul)``
+    over [B, T] token ids, with the seed's weights. This family holds one
+    whole weight tree, made once here and kept for as long as the returned
+    function lives; a family too large for that makes them layer by layer
+    inside the call."""
+    w = make_weights(sizes, seed)
+    return functools.partial(logits, w, sizes)
 
 
 # --- training: loss, gradient, AdamW ----------------------------------------

@@ -45,12 +45,8 @@ def main(argv=None) -> int:
         print(f"device: platform={device['platform']} kind={device['kind']} "
               f"count={device['count']} | compile cache: {cache_dir}", flush=True)
         compiles = harness.CompileCounter()
-        if cell["mix"]["kind"] == "train":
-            from benchmark import train_cell as runner
-        else:
-            from benchmark import serve_cell as runner
-        result = runner.run(cell, args.seed, args.seconds, bool(args.trace),
-                            device, STARTED, compiles)
+        result = harness.runner_of(cell).run(
+            cell, args.seed, args.seconds, bool(args.trace), device, STARTED, compiles)
     except (harness.RunFailed, LookupError, ImportError, FileNotFoundError) as exc:
         print(f"benchmark run failed: {type(exc).__name__}: {exc}", file=sys.stderr)
         return 1

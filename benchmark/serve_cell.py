@@ -12,6 +12,13 @@ and the engine is dropped, the reference runs once over each sampled
 request's prompt with its served tokens (the longest finished request
 always among them), and the widest gap by which a served token's logit lies
 below the reference's best is held to the cell's limit.
+
+The model is found by name: the configuration's ``family`` gives
+``cell["reference"]`` (weights from the seed, the serving reference, the
+control's matmul) and ``cell["program"]`` (the package's model and serving
+configurations). The engine is read only through what it publishes: its
+``stats`` counters, ``occupancy`` and ``queue_depth``, and - in a traced run
+- its own ``gpt2/...`` spans in the profiler's trace.
 """
 
 from __future__ import annotations
@@ -23,7 +30,6 @@ import time
 import numpy as np
 
 from benchmark import check, harness, traffic
-from benchmark.reference import gpt2 as ref
 
 PROGRESS_SECONDS = 5.0   # the log's tokens-per-slice line, for a run that reads far off
 
@@ -47,75 +53,27 @@ class Served:
         return self.handle is not None and self.handle.done
 
 
-def serve_config(cell: dict):
-    from gpt_2_distributed_tpu.config import ServeConfig
-
-    s = cell["config_file"]["serve"]
-    n_positions = cell["config_file"]["n_positions"]
-    # The program's own worst-case rule: every slot can hold a full
-    # context, and block 0 is the null block.
-    blocks = s["max_batch"] * (-(-n_positions // s["block_size"])) + 1
-    return ServeConfig(
-        max_batch=s["max_batch"], block_size=s["block_size"], num_blocks=blocks,
-        prefill_chunk=s["prefill_chunk"], prefix_cache=s["prefix_cache"],
-        admission=s["admission"], attn_impl=s.get("attn_impl", "auto"))
-
-
 def build_engine(cell: dict, seed: int):
     """(engine, driver): weights from the seed, placed as
     ``serve.load_model --init_random`` places them (fp32, default device)."""
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
     from gpt_2_distributed_tpu.serving.engine import ServingEngine
     from gpt_2_distributed_tpu.serving.frontend.driver import EngineDriver
     from gpt_2_distributed_tpu.serving.frontend.router import ReplicaRouter
 
-    cfg = cell["config_file"]
-    config = MODEL_PRESETS[cfg["program"]["preset"]].replace(
-        n_layer=cfg["n_layer"], n_embd=cfg["n_embd"], n_head=cfg["n_head"],
-        vocab_size=cfg["vocab_size"], n_positions=cfg["n_positions"])
-    params = ref.make_weights(ref.sizes_of(cfg), seed)
-    serve = serve_config(cell)
-    engine = ServingEngine(params, config, serve,
-                           temperature=float(cfg["serve"]["temperature"]))
+    cfg, program = cell["config_file"], cell["program"]
+    params = cell["reference"].make_weights(cell["sizes"], seed)
+    engine = ServingEngine(
+        params, program.model_config(cfg), program.serve_config(cfg, cell["mix"]),
+        temperature=float(cfg["serve"]["temperature"]))
     router = ReplicaRouter(lambda: engine, replicas=1)
     return engine, EngineDriver(router)
 
 
-def annotate_engine(engine, spans: harness.Spans) -> list:
-    """Traced runs only: wrap the engine's scheduling, prefill and decode
-    calls in spans, from here, so that idle gaps get a name, and keep for
-    each decode dispatch ``(time, rows, keys attended)`` as its arguments
-    say. A method that a refactor has renamed is left alone."""
-    decodes: list[tuple[float, int, int]] = []
-
-    def wrap(name, span, note=None):
-        inner = getattr(engine, name, None)
-        if inner is None:
-            return
-
-        def wrapped(*args, **kwargs):
-            if note is not None:
-                note(*args, **kwargs)
-            with spans(span):
-                return inner(*args, **kwargs)
-
-        setattr(engine, name, wrapped)
-
-    def note_decode(params, k_pool, v_pool, block_table, tokens, pos, active, keys):
-        active = np.asarray(active)
-        decodes.append((time.monotonic(), int(active.sum()),
-                        int((np.asarray(pos)[active] + 1).sum())))
-
-    wrap("_try_admit", "schedule")
-    wrap("_prefill_tick", "prefill")
-    wrap("_decode_fn", "decode", note_decode)
-    return decodes
-
-
-def warm_up(cell: dict, engine, driver, vocab_size: int) -> None:
+def warm_up(cell: dict, engine, driver) -> None:
     """Run every program the window will: the chunked prefill, the decode
     step and the host-side helpers, on requests of the mix's own shapes."""
     rng = np.random.default_rng(0)
+    vocab_size = cell["sizes"]["vocab_size"]
     pool = traffic.length_pool(cell["mix"])
     longest = max(p for p, _ in pool)
     for p in (longest, 1 + longest // 2, min(p for p, _ in pool)):
@@ -126,13 +84,14 @@ def warm_up(cell: dict, engine, driver, vocab_size: int) -> None:
 def run_window(cell: dict, seed: int, seconds: float, engine, driver,
                spans: harness.Spans, profiler: harness.ProfilerWindow):
     """Drive the mix through the driver for ``seconds``. Returns the
-    requests seen, the per-step occupancy samples and the window's ends."""
+    requests seen, the per-step occupancy samples, the window's ends and -
+    in a traced run - the engine's counters as the profiler stopped."""
     mix = cell["mix"]
-    vocab = cell["config_file"]["vocab_size"]
-    source = traffic.requests(mix, vocab, seed)
+    source = traffic.requests(mix, cell["sizes"]["vocab_size"], seed)
     min_queue = math.ceil(engine.serve.max_batch * float(mix.get("min_queue_slots", 0)))
     seen: list[Served] = []
     occupancy: list[int] = []
+    traced_stats = None
     upcoming = next(source)
 
     def submit(request):
@@ -156,10 +115,12 @@ def run_window(cell: dict, seed: int, seconds: float, engine, driver,
         with spans("step"):
             driver.step()
         occupancy.append(engine.occupancy)
-        profiler.maybe_stop()
+        if profiler.maybe_stop():
+            traced_stats = dict(engine.stats)
     t1 = time.monotonic()
-    profiler.maybe_stop(force=True)
-    return seen, occupancy, t0, t1
+    if profiler.maybe_stop(force=True):
+        traced_stats = dict(engine.stats)
+    return seen, occupancy, t0, t1, traced_stats
 
 
 def sample_for_check(cell: dict, finished: list[Served], seed: int) -> list[Served]:
@@ -181,23 +142,26 @@ def sample_for_check(cell: dict, finished: list[Served], seed: int) -> list[Serv
 
 
 def logit_gaps(cell: dict, seed: int, sample: list[Served],
-               matmul=ref.plain_matmul, weights=None) -> np.ndarray:
+               control: bool = False, logits=None) -> np.ndarray:
     """Per served token of the sample, the gap below the reference's best
-    logit (``matmul`` plain), or - for the control - the gap of the token
-    that ``matmul``'s precision puts first at each of the same positions."""
-    cfg = cell["config_file"]
-    sizes = ref.sizes_of(cfg)
-    w = ref.make_weights(sizes, seed) if weights is None else weights
-    width = sizes["n_positions"]
+    logit, or - for the control - the gap of the token that the family's
+    ``control_matmul`` precision puts first at each of the same positions.
+    Every request is padded to the mix's ``max_total`` (one compiled
+    reference, as wide as the traffic and no wider); ``logits`` is the
+    family's ``serving_reference`` for the seed, made here unless given."""
+    family = cell["reference"]
+    if logits is None:
+        logits = family.serving_reference(cell["sizes"], seed)
+    width = int(cell["mix"]["max_total"])
     gaps = []
     for served in sample:
         prompt, tokens = served.request.prompt, list(served.handle.generated)
         ids = np.zeros((1, width), np.int32)
         seq = (prompt + tokens)[:width]
         ids[0, :len(seq)] = seq
-        exact = np.asarray(ref.logits(w, sizes, ids))[0]
-        if matmul is not ref.plain_matmul:
-            rough = np.asarray(ref.logits(w, sizes, ids, matmul))[0]
+        exact = np.asarray(logits(ids))[0]
+        if control:
+            rough = np.asarray(logits(ids, family.control_matmul))[0]
             pos = np.arange(len(prompt) - 1, len(prompt) - 1 + len(tokens))
             tokens = rough[pos].argmax(axis=-1).tolist()
         gaps.append(check.token_logit_gaps(exact, len(prompt), tokens))
@@ -233,14 +197,19 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
     profiler = harness.ProfilerWindow(cell["name"], spans, trace)
     engine, driver = build_engine(cell, seed)
     try:
-        decodes = annotate_engine(engine, spans) if trace else []
-        warm_up(cell, engine, driver, cell["config_file"]["vocab_size"])
+        warm_up(cell, engine, driver)
         stats_before = dict(engine.stats)
         compiled_before = compiles.count
         setup_s = time.monotonic() - started
-        seen, occupancy, t0, t1 = run_window(
+        seen, occupancy, t0, t1, traced_stats = run_window(
             cell, seed, seconds, engine, driver, spans, profiler)
-        stats = {k: engine.stats[k] - stats_before[k] for k in stats_before}
+
+        def since_warm_up(now):
+            return {k: now[k] - stats_before[k] for k in stats_before}
+
+        stats = since_warm_up(engine.stats)
+        if traced_stats is not None:
+            traced_stats = since_warm_up(traced_stats)
         compiled_in_window = compiles.count - compiled_before
         memory_peak = harness.memory_peak_bytes(cell["chips"])
     finally:
@@ -275,9 +244,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
 
     device = dict(device, memory_peak_bytes=memory_peak)
     metrics, breakdown = harness.metrics_of(cell, values, device, profiler, spans, {
-        "window": (t0, t1), "stats": stats, "occupancy": occupancy,
-        "decodes": [d for d in decodes
-                    if profiler.started_at <= d[0] <= profiler.stopped_at],
+        "window": (t0, t1), "stats": stats, "traced_stats": traced_stats,
+        "occupancy": occupancy,
     })
     return {"correct": correct, "attempted": attempted, "failed": failed,
             "metrics": metrics, "device": device, "compared": compared,

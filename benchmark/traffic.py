@@ -22,17 +22,12 @@ import statistics
 
 import numpy as np
 
-TRAFFIC_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "traffic")
 
-KINDS = ("train", "backlog")
-
-
-def load_mix(name: str) -> dict:
-    with open(os.path.join(TRAFFIC_DIR, f"{name}.json")) as f:
-        mix = json.load(f)
-    if mix.get("kind") not in KINDS:
-        raise ValueError(f"traffic mix {name!r}: kind must be one of {KINDS}")
-    return mix
+def load_mix(name: str, traffic_dir: str) -> dict:
+    """The mix's parameters. Its ``kind`` says which runner drives it
+    (``harness.RUNNER_OF_KIND``, checked when the cell is loaded)."""
+    with open(os.path.join(traffic_dir, f"{name}.json")) as f:
+        return json.load(f)
 
 
 # --- training: token shards --------------------------------------------------

@@ -4,10 +4,13 @@ step, driven for ``--seconds`` from the benchmark's loop.
 ``train.main`` is one function and cannot be driven for N seconds, so the
 loop here repeats its order (fetch a step's micro-batches, place them,
 dispatch, prefetch the next step's while the device computes, wait for the
-previous step's metrics) around the program's own pieces. The flags go
-through ``train.build_parser``; ``model_config_from_flags`` repeats the
-trainer's inline flags-to-config lines, and a test pins both to
-``train.main`` step by step.
+previous step's metrics) around the program's own pieces. The flags and the
+model configuration come from the family's program module
+(``cell["program"].trainer_flags`` through ``train.build_parser``, then
+``train_model_config``, which repeats the trainer's inline flags-to-config
+lines); a test pins both to ``train.main`` step by step. The weights, the
+reference's steps and its view of a parameter tree are the family's
+reference module's (``cell["reference"]``).
 
 Set-up builds ONE object - the compiled step with its state - drives it
 from the seed through the first ``reference_steps`` steps through the
@@ -25,48 +28,6 @@ import time
 import numpy as np
 
 from benchmark import check, harness, traffic
-from benchmark.reference import gpt2 as ref
-
-
-def trainer_flags(cell: dict, data_dir: str, seed: int) -> list[str]:
-    """The trainer's command line for this cell."""
-    cfg, mix = cell["config_file"], cell["mix"]
-    train = cfg["train"]
-    argv = [
-        "--data_dir", data_dir, "--model", cfg["program"]["preset"],
-        "--n_layer", str(cfg["n_layer"]), "--n_embd", str(cfg["n_embd"]),
-        "--n_head", str(cfg["n_head"]), "--vocab_size", str(cfg["vocab_size"]),
-        "--seq_len", str(mix["seq_len"]), "--batch", str(train["micro_batch"]),
-        "--grad_accum_steps", str(train["grad_accum"]), "--seed", str(seed),
-    ]
-    for flag, value in train["flags"].items():
-        argv += [f"--{flag}", str(value)]
-    return argv
-
-
-def model_config_from_flags(args):
-    """``train.main``'s flags-to-config lines (it has them inline)."""
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
-
-    overrides = {
-        k: getattr(args, k)
-        for k in ("n_layer", "n_embd", "n_head", "vocab_size")
-        if getattr(args, k) is not None
-    }
-    if args.scan_layers == "auto":
-        scan_layers = args.model not in ("124M", "345M")
-    else:
-        scan_layers = args.scan_layers == "on"
-    config = MODEL_PRESETS[args.model].replace(
-        n_positions=args.seq_len, remat=args.remat, scan_layers=scan_layers,
-        loss_impl=args.loss_impl, **overrides)
-    if args.attention_impl:
-        config = config.replace(attention_impl=args.attention_impl)
-    if args.dropout is not None:
-        config = config.replace(embd_dropout=args.dropout,
-                                attn_dropout=args.dropout,
-                                resid_dropout=args.dropout)
-    return config
 
 
 class Trainer:
@@ -89,15 +50,17 @@ class Trainer:
         from gpt_2_distributed_tpu.resilience import init_guard_state
 
         self.spans = spans
-        self.sizes = ref.sizes_of(cell["config_file"])
+        self.reference = cell["reference"]
+        self.sizes = cell["sizes"]
         self.data_dir = os.path.join(harness.WORK_DIR, cell["name"], "shards")
         self.shard_paths = traffic.write_shards(
             self.data_dir, cell["mix"], self.sizes["vocab_size"], seed)
         self.args = args = trainer.build_parser().parse_args(
-            trainer_flags(cell, self.data_dir, seed))
+            cell["program"].trainer_flags(
+                cell["config_file"], cell["mix"], self.data_dir, seed))
         if args.step_guard != "on" or args.training_mode != "local":
             raise harness.RunFailed("the loop here drives the guarded one-chip step")
-        self.config = model_config_from_flags(args)
+        self.config = cell["program"].train_model_config(args)
         self.mesh = create_mesh(MeshSpec.for_mode(args.training_mode))
         self.accum = args.grad_accum_steps
         self.tokens_per_step = self.accum * args.batch * args.seq_len
@@ -114,7 +77,7 @@ class Trainer:
         self.optimizer = make_optimizer(self.lr, weight_decay=args.weight_decay)
         self._feed = self._batches()
 
-        params = ref.make_weights(self.sizes, seed)
+        params = self.reference.make_weights(self.sizes, seed)
         self._activate = activate_mesh(self.mesh)
         self._activate.__enter__()
         self.params, self.opt_state, _, _ = shard_params_and_opt_state(
@@ -194,6 +157,7 @@ def first_steps(trainer: Trainer, n_steps: int) -> dict:
     reference is compared with."""
     import jax
 
+    ref = trainer.reference
     moments = jax.jit(lambda mu: jax.tree_util.tree_map(
         lambda m: m / (1.0 - ref.ADAM_B1), mu))
     observed = {"losses": [], "skipped": 0}
@@ -229,18 +193,22 @@ def first_steps(trainer: Trainer, n_steps: int) -> dict:
 
 
 def reference_numbers(cell: dict, seed: int, observed: dict, lr: float,
-                      weight_decay: float, matmul=ref.plain_matmul) -> dict:
-    """The reference's first steps. It is fed from the shard files, not by
-    the program's loader: each row the loader fed is looked up there, and a
-    row that the files do not hold as fed counts in ``data_rows_wrong``."""
-    sizes = ref.sizes_of(cell["config_file"])
+                      weight_decay: float, control: bool = False) -> dict:
+    """The reference's first steps (``control``: computed with the family's
+    ``control_matmul``). It is fed from the shard files, not by the
+    program's loader: each row the loader fed is looked up there, and a row
+    that the files do not hold as fed counts in ``data_rows_wrong``."""
+    ref = cell["reference"]
+    sizes = cell["sizes"]
     rows = cell["config_file"]["reference"]["rows_per_block"]
-    if matmul is not ref.plain_matmul:
+    precision = {}
+    if control:
+        precision["matmul"] = ref.control_matmul
         rows = max(1, rows // 2)    # the control's backward keeps more alive
     batches, wrong = traffic.rows_in_shards(*observed["shards"], observed["batches"])
     losses, grad, moved = ref.train_steps(
         sizes, seed, batches, lr=lr, weight_decay=weight_decay,
-        rows_per_block=rows, matmul=matmul)
+        rows_per_block=rows, **precision)
     return {"losses": losses, "grad": ref.leaf_norms(grad),
             "moved": ref.leaf_norms(moved), "grad_tree": grad,
             "data_rows_wrong": wrong}
@@ -298,7 +266,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, device: dict,
 
     # --- correct: the first steps against the reference ------------------
     reference = reference_numbers(cell, seed, observed, lr, weight_decay)
-    numbers = check.training_numbers(observed, reference)["numbers"]
+    numbers = check.training_numbers(
+        observed, reference, cell["reference"].leaf_norms)["numbers"]
     correct, compared = check.judge(numbers, cell["limits"])
     finite = all(np.isfinite(v) for v in window_losses)
     correct = correct and finite and skipped == 0 and observed["skipped"] == 0

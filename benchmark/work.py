@@ -1,33 +1,11 @@
-"""Operations and bytes from shapes: what the algorithm needs, never what an
-implementation happens to do. Recomputed operations do not count.
-
-``sizes`` is a configuration file's object (``n_embd``, ``n_layer``,
-``n_head``, ``vocab_size``).
+"""Operations and bytes of a kernel from its shapes: what the algorithm
+needs, never what an implementation happens to do. Recomputed operations do
+not count. What is a kernel's and no model's lives here; a model's own
+arithmetic (operations per token, which layers hold a KV cache, heads and
+head width) is its family's, in ``reference/<family>.py``.
 """
 
 from __future__ import annotations
-
-
-def matmul_params(sizes: dict) -> int:
-    """Parameters that take part in matmuls: per block qkv (3C^2), attention
-    projection (C^2) and MLP (8C^2), plus the tied head's [C, V] projection.
-    Embedding lookups are gathers, not operations."""
-    c, l, v = sizes["n_embd"], sizes["n_layer"], sizes["vocab_size"]
-    return l * 12 * c * c + c * v
-
-
-def train_flops_per_token(sizes: dict, seq_len: int) -> float:
-    """Forward and backward: 6 per matmul parameter, and the attention
-    score and value matmuls (2 * 2*C*T forward, twice that backward) in
-    each layer, counted over the full square as the usual convention does."""
-    c, l = sizes["n_embd"], sizes["n_layer"]
-    return 6.0 * matmul_params(sizes) + 12.0 * l * c * seq_len
-
-
-def forward_flops_per_token(sizes: dict, context: float) -> float:
-    """One forward pass of one token that attends over ``context`` keys."""
-    c, l = sizes["n_embd"], sizes["n_layer"]
-    return 2.0 * matmul_params(sizes) + 4.0 * l * c * context
 
 
 def flash_attention_work(batch: int, heads: int, seq: int, head_dim: int,
@@ -45,12 +23,15 @@ def flash_attention_work(batch: int, heads: int, seq: int, head_dim: int,
 
 
 def paged_attention_work(attended_tokens: float, rows: float, heads: int,
-                         head_dim: int, bytes_per_el: int = 2):
+                         head_dim: int, kv_heads: int, bytes_per_el: int = 2):
     """(flops, bytes) of one layer's decode attention: ``attended_tokens``
-    is the sum over rows of the keys each row attends to. K and V of every
-    attended position are read once; q in and o out per row."""
-    kv = attended_tokens * heads * head_dim
-    return 4.0 * kv, (2.0 * kv + 2.0 * rows * heads * head_dim) * bytes_per_el
+    is the sum over rows of the keys each row attends to. Every query head
+    takes its two products over them; K and V of every attended position
+    are read once, in ``kv_heads`` heads (``heads`` of them unless the
+    model groups its queries); q in and o out per row."""
+    per_head = attended_tokens * head_dim
+    moved = 2.0 * per_head * kv_heads + 2.0 * rows * heads * head_dim
+    return 4.0 * per_head * heads, moved * bytes_per_el
 
 
 def roofline_seconds(flops: float, nbytes: float, peaks: dict):

@@ -4,8 +4,10 @@ configuration and mixes small enough for the sandbox. Not a benchmark."""
 import copy
 import os
 
+from benchmark import harness
+
 TINY_CONFIG = {
-    "name": "tiny", "source": "tests",
+    "name": "tiny", "family": "gpt2", "source": "tests",
     "vocab_size": 257, "n_positions": 128, "n_embd": 32, "n_layer": 2,
     "n_head": 2, "layer_norm_epsilon": 1e-05, "initializer_range": 0.02,
     "program": {"preset": "124M"},
@@ -48,16 +50,29 @@ LIMITS = {
 }
 
 
-def tiny_cell(kind: str) -> dict:
+def _merged(into: dict, changes: dict) -> dict:
+    for key, value in changes.items():
+        if isinstance(value, dict) and isinstance(into.get(key), dict):
+            _merged(into[key], value)
+        else:
+            into[key] = value
+    return into
+
+
+def tiny_cell(kind: str, **config_changes) -> dict:
+    """A cell as ``harness.load_cell`` gives one, the family's two modules
+    and the sizes on it. ``config_changes`` are merged into the tiny
+    configuration first (group by group): the sizes are read from it once,
+    here, as ``load_cell`` reads them."""
     # its own work directory (shards, traces) in each test process
-    return {
+    return harness.attach_family({
         "name": f"tiny-{kind}-{os.getpid()}", "config": "tiny", "traffic": kind, "chips": 1,
-        "config_file": copy.deepcopy(TINY_CONFIG),
+        "config_file": _merged(copy.deepcopy(TINY_CONFIG), config_changes),
         "mix": copy.deepcopy(TINY_MIXES[kind]),
         "limits": copy.deepcopy(LIMITS[kind]),
         "end_to_end": [{"name": n, "unit": "x"} for n in END_TO_END[kind]],
         "per_layer": [],
-    }
+    })
 
 
 CPU_DEVICE = {"platform": "cpu", "kind": "cpu", "count": 1}

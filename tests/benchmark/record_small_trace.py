@@ -23,18 +23,19 @@ def main():
     from bench_tiny import tiny_cell
     from benchmark import harness, reduce_trace, serve_cell, train_cell
 
+    kinds = sys.argv[1:] or ["train", "backlog"]   # which of the two to record
     harness.TRACE_SECONDS = 0.05
     manifest = harness.load_json(ROOT, "BENCHMARK.json")
     os.makedirs(OUT, exist_ok=True)
     report = {}
     for kind, runner, cell_name in (("train", train_cell, "train-124m-1k"),
                                     ("backlog", serve_cell, "serve-xl-backlog")):
-        cell = tiny_cell(kind)
-        cell["config_file"].update(n_embd=128, n_head=2, vocab_size=512,
-                                   n_positions=256)
-        cell["config_file"]["train"]["flags"]["attention_impl"] = "auto"
-        cell["config_file"]["serve"].update(block_size=16, prefill_chunk=32,
-                                            attn_impl="auto")
+        if kind not in kinds:
+            continue
+        cell = tiny_cell(
+            kind, n_embd=128, n_head=2, vocab_size=512, n_positions=256,
+            train={"flags": {"attention_impl": "auto"}},
+            serve={"block_size": 16, "prefill_chunk": 32, "attn_impl": "auto"})
         if kind == "train":
             cell["mix"].update(seq_len=256, tokens_per_shard=65536)
         cell["per_layer"] = [m for m in manifest["per_layer"]

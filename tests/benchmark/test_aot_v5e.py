@@ -66,8 +66,7 @@ def _need(compiled):
 
 def test_train_step_124m_b8(chip, topo, monkeypatch):
     """The guarded step as ``train-124m-1k`` builds it, at 12 x 8 x 1024."""
-    from benchmark import train_cell
-    from benchmark.reference import gpt2 as ref
+    from benchmark import harness
     from gpt_2_distributed_tpu import train as trainer
     from gpt_2_distributed_tpu.parallel.train_step import (
         make_optimizer, make_train_step)
@@ -75,13 +74,13 @@ def test_train_step_124m_b8(chip, topo, monkeypatch):
 
     monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
     cell = _cell("train-124m-1k")
-    args = trainer.build_parser().parse_args(
-        train_cell.trainer_flags(cell, "unused", 0))
-    config = train_cell.model_config_from_flags(args)
+    args = trainer.build_parser().parse_args(cell["program"].trainer_flags(
+        cell["config_file"], cell["mix"], "unused", 0))
+    config = cell["program"].train_model_config(args)
     optimizer = make_optimizer(args.lr, weight_decay=args.weight_decay)
     step = make_train_step(config, optimizer, guard=True)
     params = jax.eval_shape(
-        lambda: ref.make_weights(ref.sizes_of(cell["config_file"]), 0))
+        lambda: cell["reference"].make_weights(cell["sizes"], 0))
     opt_state = jax.eval_shape(optimizer.init, params)
     shape = (args.grad_accum_steps, args.batch, args.seq_len)
     assert shape == (12, 8, 1024)
@@ -97,21 +96,17 @@ def test_train_step_124m_b8(chip, topo, monkeypatch):
 
 
 def _serving_programs(workload, chip, topo, monkeypatch, extra_slots=0):
-    from benchmark import serve_cell
-    from benchmark.reference import gpt2 as ref
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
+    from benchmark import harness
     from gpt_2_distributed_tpu.serving import engine as eng
 
     monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
     cell = _cell(workload)
     cfg = cell["config_file"]
-    config = MODEL_PRESETS[cfg["program"]["preset"]]
-    assert (config.n_layer, config.n_embd, config.n_head, config.vocab_size) == (
-        cfg["n_layer"], cfg["n_embd"], cfg["n_head"], cfg["vocab_size"])
+    config = cell["program"].model_config(cfg)
     cell["config_file"]["serve"]["max_batch"] += extra_slots
-    serve = serve_cell.serve_config(cell)
+    serve = cell["program"].serve_config(cfg, cell["mix"])
     params = _on_chip(jax.eval_shape(
-        lambda: ref.make_weights(ref.sizes_of(cfg), 0)), chip)
+        lambda: cell["reference"].make_weights(cell["sizes"], 0)), chip)
     pool = jax.ShapeDtypeStruct(
         (config.n_layer, serve.num_blocks, config.n_head, serve.block_size,
          config.head_dim), BF16, sharding=chip)

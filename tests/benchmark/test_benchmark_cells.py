@@ -12,7 +12,6 @@ import pytest
 
 from bench_tiny import CPU_DEVICE, tiny_cell
 from benchmark import check, harness, serve_cell, traffic, train_cell
-from benchmark.reference import gpt2 as ref
 
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
@@ -140,14 +139,14 @@ def test_training_control_comes_out_not_correct():
         trainer.close()
     lr, wd = trainer.lr, trainer.args.weight_decay
     exact = train_cell.reference_numbers(cell, 3, observed, lr, wd)
-    rough = train_cell.reference_numbers(cell, 3, observed, lr, wd,
-                                         matmul=ref.fp8_matmul)
+    rough = train_cell.reference_numbers(cell, 3, observed, lr, wd, control=True)
+    leaf_norms = cell["reference"].leaf_norms
     correct, rows = check.judge(
-        check.training_numbers(rough, exact)["numbers"], cell["limits"])
+        check.training_numbers(rough, exact, leaf_norms)["numbers"], cell["limits"])
     assert correct is False
     assert "grad_diff" in {r["name"] for r in rows if r["ok"] is False}
     assert check.judge(
-        check.training_numbers(exact, exact)["numbers"], cell["limits"])[0]
+        check.training_numbers(exact, exact, leaf_norms)["numbers"], cell["limits"])[0]
 
 
 def test_serving_control_comes_out_not_correct():
@@ -157,11 +156,10 @@ def test_serving_control_comes_out_not_correct():
     the two-layer size a token's own embedding decides the next token and
     no precision flips it. (CPU readings at this size on 3 seeds: program
     0.0017-0.0027, control 0.044-0.082.)"""
-    cell = tiny_cell("backlog")
-    cell["config_file"].update(n_layer=12, n_embd=128, vocab_size=2048)
+    cell = tiny_cell("backlog", n_layer=12, n_embd=128, vocab_size=2048)
     cell["mix"]["check_tokens"] = 150
     cell["limits"] = {"token_logit_gap": {"limit": 0.01}}
-    vocab = cell["config_file"]["vocab_size"]
+    vocab = cell["sizes"]["vocab_size"]
     engine, driver = serve_cell.build_engine(cell, 4)
     source = traffic.requests(cell["mix"], vocab, 4)
     seen = []
@@ -180,10 +178,32 @@ def test_serving_control_comes_out_not_correct():
     sample = serve_cell.sample_for_check(cell, finished, 4)
     assert max(sample, key=lambda s: len(s.handle.generated)) is sample[0]
     exact = serve_cell.logit_gaps(cell, 4, sample)
-    rough = serve_cell.logit_gaps(cell, 4, sample, matmul=ref.fp8_matmul)
+    rough = serve_cell.logit_gaps(cell, 4, sample, control=True)
     assert len(exact) == len(rough) >= cell["mix"]["check_tokens"]
     assert check.judge({"token_logit_gap": exact.max()}, cell["limits"])[0] is True
     assert check.judge({"token_logit_gap": rough.max()}, cell["limits"])[0] is False
+
+
+@pytest.mark.parametrize("kind", ["train", "backlog"])
+def test_calibrate_takes_its_readings_through_the_family(kind):
+    """Both paths of the tool that the limits are set with, on the tiny
+    cells: the next configuration's limits come from it as committed."""
+    from benchmark import calibrate
+
+    cell = tiny_cell(kind)
+    what = {"program", "control", "fault"}
+    if kind == "train":
+        row = calibrate.training(cell, 6, what)
+        assert len(row["losses"]) == len(row["reference_losses"]) == 3
+        assert check.judge(row["program"], cell["limits"])[0] is True
+        assert check.judge(row["control"], cell["limits"])[0] is False
+        assert row["fault_half_batch"]["grad_norm_gap"] > 10 * row["program"]["grad_norm_gap"]
+    else:
+        row = calibrate.serving(cell, 6, what, 1.0)
+        assert row["sampled"] > 0 and row["checked_tokens"] >= cell["mix"]["check_tokens"]
+        assert check.judge(row["program"], cell["limits"])[0] is True
+        assert set(row["control"]) == set(row["program"])
+    json.dumps(row)                               # a line of the --out file
 
 
 def test_a_compile_inside_the_window_is_counted():

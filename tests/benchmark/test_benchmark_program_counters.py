@@ -1,8 +1,8 @@
 """The per-layer metrics that read the program's own counters: each reader
 on a hand-built ``ctx`` (what it computes, and that it reports nothing where
 the program has no such counter, as the parent of the PR that added them has
-not), the engine's decode counters against what the harness's wrapper
-intercepts on the tiny backlog cell, and the compile watch in place before a
+not), a traced tiny run that reads the engine through its counters alone and
+leaves its methods as they are, and the compile watch in place before a
 cell's first compile."""
 
 import os
@@ -12,10 +12,12 @@ import time
 
 import pytest
 
-from bench_tiny import tiny_cell
-from benchmark import harness, serve_cell
+from bench_tiny import CPU_DEVICE, tiny_cell
+from benchmark import harness, serve_cell, work
+from benchmark.reduce_trace import Trace
 
 ROOT = harness.ROOT
+HERE = os.path.dirname(os.path.abspath(__file__))
 
 # what a parent without the counters hands the readers
 OLD_STATS = {"decode_steps": 40, "decode_ms": 4000.0, "prefill_dispatches": 5,
@@ -96,35 +98,107 @@ def test_set_up_readers_read_the_real_watch():
     assert harness.load_reader("setup_programs")({"window": (0.0, 1.0)}) is None
 
 
-def test_decode_counters_equal_what_the_harness_intercepts():
-    """The pin the follow-up needs: on the tiny backlog cell, step for step,
-    ``decode_rows`` / ``decode_attended`` are the ``decodes`` that
-    ``annotate_engine`` reads off ``_decode_fn``'s arguments, so a
-    ``benchmark`` PR can point ``mfu_pct.decode`` and ``paged_attn_roofline``
-    at the counters and delete the wrapper."""
-    from benchmark import traffic
+# rows and keys attended of the decode dispatches of a traced window, one
+# pair a step, as the engine counts them into ``decode_rows`` and
+# ``decode_attended``
+DISPATCHES = [(4, 700), (4, 704), (3, 650), (4, 910), (2, 75)]
+PEAKS = {"flops_per_s_bf16": 197e12, "hbm_bytes_per_s": 819e9}
 
+
+class FakeTrace:
+    """15 ms in the paged kernel's events over the traced window."""
+
+    def kernel_seconds(self, needles, lo, hi):
+        assert needles[0] == "%closed_call"
+        return 0.015, 48 * len(DISPATCHES)
+
+
+def _decode_ctx(traced_stats):
+    ref = harness.load_module("reference", "gpt2")
+    sizes = ref.sizes_of(harness.load_json(ROOT, "benchmark/configs/gpt2-xl.json"))
+    return {"stats": STATS, "traced_stats": traced_stats,
+            "cell": {"reference": ref}, "sizes": sizes, "peaks": PEAKS, "trace": FakeTrace(),
+            "trace_window_ns": (0, 4e9)}, ref, sizes
+
+
+def _mfu_by_dispatch(ref, sizes):
+    """As the reader had it while the harness kept one record a dispatch."""
+    rows = sum(r for r, _ in DISPATCHES)
+    attended = sum(a for _, a in DISPATCHES)
+    per_step = rows * ref.forward_flops_per_token(sizes, attended / rows) / len(DISPATCHES)
+    step_s = STATS["decode_ms"] / STATS["decode_steps"] / 1e3
+    return 100.0 * per_step / step_s / PEAKS["flops_per_s_bf16"]
+
+
+def _roofline_by_dispatch(ref, sizes):
+    shapes = ref.attention_shapes(sizes)
+    least = 0.0
+    for rows, attended in DISPATCHES:
+        flops, nbytes = work.paged_attention_work(
+            attended, rows, shapes["heads"], shapes["head_dim"], shapes["kv_heads"])
+        least += shapes["kv_layers"] * work.roofline_seconds(flops, nbytes, PEAKS)[0]
+    return 100.0 * least / 0.015
+
+
+@pytest.mark.parametrize("metric, by_dispatch", [
+    ("mfu_pct.decode", _mfu_by_dispatch),
+    ("paged_attn_roofline", _roofline_by_dispatch)])
+def test_decode_reader_gives_the_per_dispatch_value_from_the_counters_alone(
+        metric, by_dispatch):
+    traced = {"decode_steps": len(DISPATCHES),
+              "decode_rows": sum(r for r, _ in DISPATCHES),
+              "decode_attended": sum(a for _, a in DISPATCHES)}
+    ctx, ref, sizes = _decode_ctx(traced)
+    read = harness.load_reader(metric)
+    assert read(ctx) == pytest.approx(by_dispatch(ref, sizes), rel=1e-12)
+    assert 0 < read(ctx) < 100
+    # a program without the counters, or a traced part that decoded nothing:
+    # the metric is left out, never 0
+    assert read(dict(ctx, traced_stats={})) is None
+    assert read(dict(ctx, traced_stats=dict(traced, decode_rows=0))) is None
+
+
+def test_traced_run_reads_the_engine_through_its_counters_and_wraps_nothing(
+        monkeypatch):
+    """A traced tiny backlog run whose engine's decode program takes one
+    argument more than today's: the harness names no method of the engine
+    and spells out no signature, so both decode metrics still read, and the
+    engine's methods are the objects they were. (The device's side of the
+    trace is the chip's recording beside this file: the CPU has no device
+    plane and no kernel event.)"""
+    manifest = harness.load_json(ROOT, "BENCHMARK.json")
     cell = tiny_cell("backlog")
-    engine, driver = serve_cell.build_engine(cell, seed=11)
-    try:
-        decodes = serve_cell.annotate_engine(engine, harness.Spans())
-        source = traffic.requests(cell["mix"], cell["config_file"]["vocab_size"], 11)
-        per_step, last = [], (0, 0)
-        for _ in range(60):
-            while engine.queue_depth < engine.serve.max_batch:
-                request = next(source)
-                driver.submit(request.prompt, request.max_new_tokens,
-                              rng=request.index)
-            driver.step()
-            now = (engine.stats["decode_rows"], engine.stats["decode_attended"])
-            if now != last:
-                per_step.append((now[0] - last[0], now[1] - last[1]))
-                last = now
-    finally:
-        driver.close()
-    assert len(decodes) == engine.stats["decode_steps"] > 30
-    assert per_step == [(rows, attended) for _, rows, attended in decodes]
-    assert max(rows for rows, _ in per_step) == engine.serve.max_batch
+    cell["per_layer"] = [m for m in manifest["per_layer"]
+                         if m["name"] in ("mfu_pct.decode", "paged_attn_roofline")]
+    built, build = {}, serve_cell.build_engine
+
+    def build_with_a_second_state(cell, seed):
+        engine, driver = build(cell, seed)
+        inner = engine._decode_fn
+
+        def decode(params, k_pool, v_pool, block_table, tokens, pos, active,
+                   keys, recurrent_state=None):
+            return inner(params, k_pool, v_pool, block_table, tokens, pos,
+                         active, keys)
+
+        engine._decode_fn = decode
+        built.update(engine=engine, methods={
+            n: getattr(engine, n) for n in ("_decode_fn", "_try_admit", "_prefill_tick")})
+        return engine, driver
+
+    monkeypatch.setattr(serve_cell, "build_engine", build_with_a_second_state)
+    monkeypatch.setattr(harness, "TRACE_SECONDS", 0.3)
+    monkeypatch.setattr(
+        harness.ProfilerWindow, "read",
+        lambda self: Trace.from_file(os.path.join(HERE, "small_serve.xplane.pb")))
+    device = dict(CPU_DEVICE, kind="TPU v5 lite")     # whose peaks the readers take
+    result = serve_cell.run(cell, 13, 1.0, True, device, time.monotonic(),
+                            harness.CompileCounter())
+    assert result["correct"] is True
+    assert set(result["metrics"]) == {"mfu_pct.decode", "paged_attn_roofline"}
+    assert all(m["value"] > 0 for m in result["metrics"].values())
+    for name, method in built["methods"].items():
+        assert getattr(built["engine"], name) == method, f"{name} was wrapped"
 
 
 BEFORE_FIRST_COMPILE = """

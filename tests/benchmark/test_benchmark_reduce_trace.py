@@ -1,6 +1,8 @@
-"""``reduce_trace`` on two small traces recorded on the chip (a v5e, PR 24;
-``record_small_trace.py`` beside this file says how): a training window and
-a serving window of 50 ms each, through the real kernels."""
+"""``reduce_trace`` on two small traces recorded on the chip (a v5e; the
+training one in PR 24, the serving one again in PR 27, with the program's own
+``gpt2/...`` spans in it; ``record_small_trace.py`` beside this file says
+how): a training window and a serving window of 50 ms each, through the real
+kernels."""
 
 import os
 
@@ -78,17 +80,50 @@ def test_top_operations_are_self_times(train):
     assert any(name.endswith("(tpu_custom_call)") for name, _ in top)
 
 
+HARNESS_SPANS = {"data_fetch", "h2d", "step_dispatch", "device_sync", "submit", "step"}
+PROGRAM_SPANS = {"engine_step", "admit", "prefill", "decode", "dispatch",
+                 "readback", "emit"}
+
+
 @pytest.mark.parametrize("which", ["train", "serve"])
-def test_idle_gaps_are_attributed_to_the_harness_spans(which, request):
+def test_idle_gaps_are_attributed_to_the_spans(which, request):
     trace = request.getfixturevalue(which)
     lo, hi = trace.window_ns()
     gaps = trace.idle_gaps(lo, hi)
     idle = (hi - lo) / 1e9 - trace.busy_seconds(lo, hi)
     assert sum(s for _, s in trace.idle_gaps(lo, hi, n=10**6)) == pytest.approx(idle)
     names = {name for name, _ in gaps}
-    assert names <= {"data_fetch", "h2d", "step_dispatch", "device_sync", "submit",
-                     "step", "schedule", "prefill", "decode", "idle_wait", "(none)"}
+    assert names <= HARNESS_SPANS | PROGRAM_SPANS | {"(none)"}
     assert names - {"(none)"}                  # the spans are in the trace
+
+
+def test_an_idle_gap_is_named_by_the_innermost_span_of_the_program(serve):
+    """The serving trace holds the engine's own ``gpt2/...`` spans inside the
+    harness's ``bench/step``: a gap that opens under one of them is that
+    phase's, and ``step`` keeps only what lies outside ``ServingEngine.step``
+    (nothing, here)."""
+    assert {"bench/", "gpt2/"} == set(reduce_trace.SPAN_PREFIXES)
+    kept = {name for name, _, _ in serve.host_spans}
+    assert PROGRAM_SPANS <= kept and {"submit", "step", "window"} <= kept
+    lo, hi = serve.window_ns()
+    by_span = dict(serve.idle_gaps(lo, hi, n=10**6))
+    assert {"prefill", "dispatch", "readback", "emit"} <= set(by_span)
+    assert "step" not in by_span
+    # every phase span lies inside an engine step, and that inside a step
+    steps = [(s, e) for n, s, e in serve.host_spans if n == "step"]
+    for name, s, e in serve.host_spans:
+        if name in PROGRAM_SPANS:
+            assert any(a <= s and e <= b for a, b in steps), name
+    # by hand on a two-span trace: the gap belongs to the inner, later span
+    toy = Trace({"/device:TPU:0": [("%op", 0, 10), ("%op", 30, 40)]},
+                [("step", 0, 40), ("emit", 8, 20)])
+    assert toy.idle_gaps(0, 40) == [["emit", 2e-8]]
+
+
+def test_span_names_lose_their_prefix_and_foreign_events_are_dropped():
+    assert reduce_trace.span_name("bench/step") == "step"
+    assert reduce_trace.span_name("gpt2/readback") == "readback"
+    assert reduce_trace.span_name("PjitFunction(decode_step)") is None
 
 
 def test_summary_lists_planes_and_lines(train):

@@ -31,6 +31,7 @@ def test_same_loss_as_train_main_at_every_step(monkeypatch):
         return update(self, step, count_tokens, **metrics)
 
     monkeypatch.setattr(StatsTracker, "update", recording)
-    train.main(train_cell.trainer_flags(cell, trainer.data_dir, seed)
+    train.main(cell["program"].trainer_flags(
+        cell["config_file"], cell["mix"], trainer.data_dir, seed)
                + ["--max_steps", "3", "--cli_every", "1"])
     assert [theirs[i] for i in (1, 2, 3)] == ours
