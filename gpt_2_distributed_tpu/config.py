@@ -358,6 +358,12 @@ class ServeConfig:
       ``prefill_chunk > 0``; the row count is padded to ``prefill_batch``
       so the batched chunk program still compiles exactly once.
 
+    * ``max_seq_len`` — the longest prompt + output a request may have
+      (0 = the model's ``n_positions``). It sets the block table's width,
+      so a deployment of a model whose position range is far beyond what
+      its pool can hold (512k positions, a 32k pool row) compiles tables
+      and selectors as wide as the traffic and no wider.
+
     Speculative decoding knob:
 
     * ``spec`` — speculative-decoding spec, ``"draft:<preset>,k:<K>"``
@@ -386,8 +392,11 @@ class ServeConfig:
     mesh: str = ""
     prefill_batch: int = 1
     spec: str = ""
+    max_seq_len: int = 0
 
     def __post_init__(self) -> None:
+        if self.max_seq_len < 0:
+            raise ValueError(f"max_seq_len={self.max_seq_len} must be >= 0")
         if self.max_batch < 1:
             raise ValueError(f"max_batch={self.max_batch} must be >= 1")
         if self.block_size < 1:
@@ -467,8 +476,12 @@ class ServeConfig:
 
     def max_blocks_per_seq(self, n_positions: int) -> int:
         """Static block-table width: enough blocks for a full-context
-        sequence."""
-        return -(-n_positions // self.block_size)
+        sequence, or for one of ``max_seq_len`` where that is set."""
+        return -(-self.seq_limit(n_positions) // self.block_size)
+
+    def seq_limit(self, n_positions: int) -> int:
+        """The longest prompt + output the engine takes."""
+        return min(n_positions, self.max_seq_len or n_positions)
 
 
 def parse_serve_mesh(mesh: str) -> tuple[int, int]:
@@ -702,6 +715,7 @@ def validate_worker_flags(p, args) -> None:
             f"--worker_pool only makes sense with --placement remote, "
             f"not {args.placement!r}"
         )
+    validate_model_flags(p, args)
 
 
 # BASELINE.json configs 1-5 require these four sizes; the standard GPT-2 family.
@@ -711,3 +725,258 @@ MODEL_PRESETS: dict[str, GPT2Config] = {
     "774M": GPT2Config(n_layer=36, n_embd=1280, n_head=20),
     "1.5B": GPT2Config(n_layer=48, n_embd=1600, n_head=25),
 }
+
+
+SPARSE_MIXER, LIGHTNING_MIXER = "minicpm4", "lightning-attn"
+
+
+@dataclass(frozen=True)
+class SparseAttentionConfig:
+    """Sizes of the InfLLM-V2 block selection (``ops/sparse_select.py``):
+    compressed keys are means over ``window`` tokens every ``stride``; a
+    query attends the first ``init_blocks`` blocks, the ``local_window /
+    block`` blocks that end with its own, and the ``topk`` best-scoring of
+    the rest; below ``dense_below`` tokens of context it attends them all."""
+
+    window: int = 32
+    stride: int = 16
+    block: int = 64
+    init_blocks: int = 1
+    local_window: int = 2048
+    topk: int = 64
+    dense_below: int = 8192
+
+    def __post_init__(self) -> None:
+        if self.window % self.stride or self.block % self.stride \
+                or self.local_window % self.block:
+            raise ValueError(
+                f"sparse sizes must nest: stride={self.stride} divides "
+                f"window={self.window} and block={self.block}, block divides "
+                f"local_window={self.local_window}"
+            )
+
+    @property
+    def local_blocks(self) -> int:
+        return self.local_window // self.block
+
+    @property
+    def list_width(self) -> int:
+        """Blocks a query attends at most: the forced and the picked ones,
+        or every block of a context that is still dense."""
+        return max(self.init_blocks + self.local_blocks + self.topk,
+                   -(-self.dense_below // self.block))
+
+    def selected_blocks(self, pos):
+        """How many blocks the query at position ``pos`` (scalar or numpy
+        array) attends, and how many it sees."""
+        import numpy as np
+
+        visible = np.asarray(pos) // self.block + 1
+        sparse = np.minimum(
+            visible, self.init_blocks + self.local_blocks + self.topk)
+        return np.where(np.asarray(pos) < self.dense_below, visible, sparse), visible
+
+
+@dataclass(frozen=True)
+class SalaConfig:
+    """MiniCPM-SALA (``models/minicpm_sala.py``): RMSNorm, no biases,
+    SwiGLU, untied head, muP-style scalings, and a PER-LAYER mixer list —
+    ``minicpm4`` layers (grouped-query attention with InfLLM-V2 block
+    selection, no rotary) among ``lightning-attn`` layers (linear attention
+    with per-head decay, rotary, output norm). Field names follow the
+    published ``config.json``. ``num_hidden_layers`` is the PUBLISHED depth:
+    it stays under the root in ``scale_depth / sqrt(num_hidden_layers)`` when
+    ``mixer_types`` holds a cut of the stack."""
+
+    vocab_size: int = 73448
+    hidden_size: int = 4096
+    intermediate_size: int = 16384
+    num_hidden_layers: int = 32
+    mixer_types: tuple[str, ...] = ()
+    num_attention_heads: int = 32
+    num_key_value_heads: int = 2
+    head_dim: int = 128
+    lightning_nh: int = 32
+    lightning_head_dim: int = 128
+    max_position_embeddings: int = 524288
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    scale_emb: float = 12.0
+    scale_depth: float = 1.4
+    dim_model_base: int = 256
+    initializer_range: float = 0.02
+    sparse: SparseAttentionConfig = SparseAttentionConfig()
+
+    def __post_init__(self) -> None:
+        object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
+        bad = set(self.mixer_types) - {SPARSE_MIXER, LIGHTNING_MIXER}
+        if bad or not self.mixer_types:
+            raise ValueError(
+                f"mixer_types must name {SPARSE_MIXER!r} or {LIGHTNING_MIXER!r} "
+                f"for every layer, got {sorted(bad) or 'none'}"
+            )
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads={self.num_attention_heads} must be a "
+                f"multiple of num_key_value_heads={self.num_key_value_heads}"
+            )
+
+    # What the engine and the generation checks read off any model config.
+    @property
+    def n_positions(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def n_layer(self) -> int:
+        return len(self.mixer_types)
+
+    @property
+    def sparse_layers(self) -> tuple[int, ...]:
+        return tuple(i for i, m in enumerate(self.mixer_types) if m == SPARSE_MIXER)
+
+    @property
+    def lightning_layers(self) -> tuple[int, ...]:
+        return tuple(i for i, m in enumerate(self.mixer_types) if m == LIGHTNING_MIXER)
+
+    @property
+    def kv_pool_view(self):
+        """What ``paged_cache.init_pools`` reads of a model: only the sparse
+        layers hold K/V, in ``num_key_value_heads`` heads."""
+        import types
+
+        return types.SimpleNamespace(
+            n_layer=len(self.sparse_layers), n_head=self.num_key_value_heads,
+            head_dim=self.head_dim)
+
+    def replace(self, **kwargs) -> "SalaConfig":
+        return dataclasses.replace(self, **kwargs)
+
+    def cut(self, n_layer: int, first: int = 0) -> "SalaConfig":
+        """``n_layer`` consecutive layers of this stack from layer ``first``
+        on (of the published 32, ``cut(16, 9)`` is the one run of 16 that
+        keeps the published ratio of 1 sparse layer to 3 linear)."""
+        total = len(self.mixer_types)
+        if n_layer < 1 or first < 0 or first + n_layer > total:
+            raise ValueError(
+                f"layers [{first}, {first + n_layer}) are not within the "
+                f"stack's {total}"
+            )
+        return self.replace(mixer_types=self.mixer_types[first:first + n_layer])
+
+    def num_params(self, include_embeddings: bool = True) -> int:
+        c, f = self.hidden_size, self.intermediate_size
+        a = self.num_attention_heads * self.head_dim
+        kv = self.num_key_value_heads * self.head_dim
+        la = self.lightning_nh * self.lightning_head_dim
+        n = c
+        for m in self.mixer_types:
+            n += 2 * c + 3 * c * f
+            if m == SPARSE_MIXER:
+                n += 2 * c * a + 2 * c * kv + a * c + 2 * self.head_dim
+            else:
+                n += 4 * c * la + la * c + 2 * self.lightning_head_dim + la
+        if include_embeddings:
+            n += 2 * self.vocab_size * c
+        return n
+
+
+_S, _L = SPARSE_MIXER, LIGHTNING_MIXER
+SALA_PRESETS: dict[str, SalaConfig] = {
+    # openbmb/MiniCPM-SALA config.json, the 32 published layers.
+    "minicpm-sala-9b": SalaConfig(mixer_types=(
+        _S, _L, _L, _L, _L, _L, _L, _L, _L, _S, _L, _L, _L, _L, _L, _L,
+        _S, _S, _L, _L, _L, _L, _S, _L, _L, _L, _L, _L, _L, _S, _S, _S)),
+    # The CPU tests' size: selection is live within 100 tokens.
+    "minicpm-sala-tiny": SalaConfig(
+        vocab_size=257, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=8, mixer_types=(_S, _L, _L, _L, _S, _L, _L, _L),
+        num_attention_heads=4, num_key_value_heads=1, head_dim=16,
+        lightning_nh=4, lightning_head_dim=16, max_position_embeddings=4096,
+        dim_model_base=32,
+        sparse=SparseAttentionConfig(
+            window=4, stride=2, block=8, init_blocks=1, local_window=16,
+            topk=2, dense_below=32)),
+}
+
+
+def refuse_for_sala(config: SalaConfig, serve: "ServeConfig",
+                    speculative: bool = False) -> str | None:
+    """What the serving engine cannot do for a :class:`SalaConfig` yet, as
+    the sentence to refuse it with (None = it can serve). jax-free: the CLIs
+    call it at parse time, the engine at construction. ``serve`` is read by
+    attribute (``prefix_cache``, ``spec``, ``prefill_chunk``, ``mesh_devices``,
+    ``prefill_batch``, ``block_size``)."""
+    if serve.prefix_cache:
+        return ("prefix_cache: a hit would need a snapshot of the "
+                "linear-attention state at the block boundary")
+    if speculative or serve.spec:
+        return "speculative decoding: the two-model round is written for GPT-2"
+    if serve.prefill_chunk == 0:
+        return ("whole-prompt prefill (prefill_chunk=0): this family "
+                "prefills in chunks through the pools and the state")
+    if serve.mesh_devices > 1:
+        return ("a serving mesh (tp or data over 1): grouped-query pools "
+                "and the per-slot state have no sharding yet")
+    if serve.prefill_batch != 1:
+        return "prefill_batch over 1: a chunk dispatch carries one slot's state"
+    if serve.block_size != config.sparse.block:
+        return (f"block_size={serve.block_size}: one pool block is one "
+                f"selection block of {config.sparse.block} keys")
+    if serve.prefill_chunk % serve.block_size:
+        return (f"prefill_chunk={serve.prefill_chunk}: a chunk covers whole "
+                f"blocks of {serve.block_size}")
+    return None
+
+
+def sala_config_from_flags(args) -> SalaConfig:
+    """``--model <SALA preset> [--n_layer N --first_layer F]``: the preset, or
+    ``N`` consecutive layers of it from layer ``F`` on (``cut``)."""
+    config = SALA_PRESETS[args.model]
+    n_layer = getattr(args, "n_layer", None)
+    first = getattr(args, "first_layer", 0) or 0
+    if n_layer is None:
+        if first:
+            raise ValueError("--first_layer needs --n_layer")
+        return config
+    return config.cut(n_layer, first)
+
+
+def validate_model_flags(p, args) -> None:
+    """Parse-time check of the model flags for a :data:`SALA_PRESETS` model,
+    jax-free like the rest of this file: sizes that are GPT-2's, a
+    checkpoint (the family has no trainer, so no checkpoint format), and
+    every engine option :func:`refuse_for_sala` names are refused before any
+    CLI pays the jax import."""
+    import types
+
+    model = getattr(args, "model", None)
+    if model not in SALA_PRESETS:
+        if getattr(args, "first_layer", 0):
+            p.error(f"--first_layer cuts a layer-pattern model's stack "
+                    f"({'|'.join(SALA_PRESETS)}), not {model}")
+        return
+    for flag in ("n_embd", "n_head", "vocab_size", "seq_len"):
+        if getattr(args, flag, None) is not None:
+            p.error(f"--{flag} is a GPT-2 size; --model {model} takes "
+                    f"--n_layer and --first_layer")
+    if getattr(args, "ckpt", None):
+        p.error(f"--ckpt: --model {model} has no checkpoint format yet; "
+                f"serve it with --init_random")
+    try:
+        config = sala_config_from_flags(args)
+    except ValueError as e:
+        p.error(str(e))
+    if not hasattr(args, "prefill_chunk"):
+        return                      # a CLI without the engine flags (sample.py)
+    try:
+        data, tp = parse_serve_mesh(getattr(args, "serve_mesh", "") or "")
+    except ValueError:
+        return                      # a malformed mesh fails where meshes are parsed
+    why = refuse_for_sala(config, types.SimpleNamespace(
+        prefix_cache=args.prefix_cache, spec="", prefill_chunk=args.prefill_chunk,
+        mesh_devices=data * tp, prefill_batch=getattr(args, "prefill_batch", 1),
+        block_size=args.block_size,
+    ), speculative=bool(getattr(args, "draft_preset", None)
+                        or getattr(args, "spec_k", None)))
+    if why is not None:
+        p.error(f"--model {model} cannot be served with {why}")

@@ -276,6 +276,12 @@ for _name, _dist in (
     ("decode_wait_ms", "mean"),        # from there to the token read-back
     ("decode_rows", "mean"),           # rows one decode step advances
     ("decode_attended", "mean"),       # keys those rows attend
+    ("prefill_tokens", "mean"),        # tokens one chunked prefill dispatch takes
+    ("prefill_attended", "mean"),      # keys those tokens attend
+    ("sparse_rows", "mean"),           # rows a step puts through block selection
+    ("sparse_selected", "mean"),       # blocks those rows attend
+    ("sparse_visible", "mean"),        # blocks those rows see
+    ("state_resets", "mean"),          # requests a step starts from a zero state
 ):
     METRIC_REGISTRY.metric(
         _name, reduction=ReductionStrategy.CURRENT, tb_prefix="serve/",

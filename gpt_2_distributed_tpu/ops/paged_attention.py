@@ -380,3 +380,102 @@ def paged_attention(
     return paged_attention_xla(
         q, k_pool, v_pool, block_table, lengths, layer
     )
+
+
+# --- grouped queries over a SELECTED list of blocks ---------------------------
+#
+# A sparse layer's row attends over a list of its blocks, not over its whole
+# table, and ``G`` query heads share each KV head. Two forms, both XLA:
+# decode rows gather exactly their listed blocks (the bytes the selection
+# exists to save); a prefill chunk, whose thousands of queries each list other
+# blocks and together list nearly all, walks the row's table in tiles under a
+# mask, which keeps the products on the MXU. ``paged_attention`` above is
+# untouched by either: at ``kv_heads == heads`` it compiles to what it did.
+
+_MASKED = -1e30   # finite, so a row with nothing selected in a tile stays NaN-free
+
+
+def paged_sparse_attention(
+    q: jnp.ndarray,            # [B, KV, G, D] one query position per row
+    k_pool: jnp.ndarray,       # [L, N, KV, bs, D]
+    v_pool: jnp.ndarray,
+    blocks: jnp.ndarray,       # [B, KV, W] int32 pool blocks, as listed
+    logical: jnp.ndarray,      # [B, KV, W] int32 their index in the sequence
+    count: jnp.ndarray,        # [B, KV] int32 entries of the list in use
+    pos: jnp.ndarray,          # [B] int32 query position (keys <= pos)
+    layer=0,
+) -> jnp.ndarray:
+    """``o[b, kv, g] = softmax(q . K^T / sqrt(D)) V`` over the keys at or
+    before ``pos[b]`` of the ``count[b, kv]`` listed blocks. A row with
+    ``count == 0`` (an idle slot) gives 0. Returns [B, KV, G, D]."""
+    b, kv, g, d = q.shape
+    bs = k_pool.shape[-2]
+    w = blocks.shape[-1]
+    head = jnp.arange(kv)[None, :, None]
+    kc = k_pool[layer, blocks, head]                       # [B, KV, W, bs, D]
+    vc = v_pool[layer, blocks, head]
+    scores = jnp.einsum("bkgd,bkwsd->bkgws", q, kc,
+                        preferred_element_type=jnp.float32) / jnp.sqrt(float(d))
+    key_pos = logical[..., None] * bs + jnp.arange(bs)     # [B, KV, W, bs]
+    keep = (jnp.arange(w)[None, None, :, None] < count[..., None, None]) \
+        & (key_pos <= pos[:, None, None, None])
+    scores = jnp.where(keep[:, :, None], scores, _MASKED).reshape(b, kv, g, w * bs)
+    probs = jax.nn.softmax(scores, axis=-1)
+    probs = jnp.where(count[:, :, None, None] > 0, probs, 0.0).astype(q.dtype)
+    return jnp.einsum("bkgs,bksd->bkgd", probs, vc.reshape(b, kv, w * bs, d))
+
+
+def paged_masked_attention(
+    q: jnp.ndarray,            # [T, KV, G, D] queries of ONE row's chunk
+    k_pool: jnp.ndarray,       # [L, N, KV, bs, D]
+    v_pool: jnp.ndarray,
+    table: jnp.ndarray,        # [M] int32 the row's block table
+    pos: jnp.ndarray,          # [T] int32 query positions, ascending
+    keep: jnp.ndarray,         # [KV, T, M] bool selected blocks per query
+    layer=0,
+    tile_blocks: int = 8,
+) -> jnp.ndarray:
+    """Attention of a chunk's queries over the row's table, ``tile_blocks``
+    blocks at a time with a running softmax: query ``t`` attends the keys at
+    or before ``pos[t]`` of the blocks ``keep[:, t]`` marks. The walk stops at
+    the tile that holds ``pos[-1]``: its cost follows the context, not the
+    table's width. Returns [T, KV, G, D]."""
+    t, kv, g, d = q.shape
+    bs = k_pool.shape[-2]
+    m = table.shape[0]
+    tile_blocks = min(tile_blocks, m)
+    span = tile_blocks * bs
+    n_tiles = -(-m // tile_blocks)
+    table = jnp.pad(table, (0, n_tiles * tile_blocks - m))
+    keep = jnp.pad(keep, ((0, 0), (0, 0), (0, n_tiles * tile_blocks - m)))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
+    head = jnp.arange(kv)[None, :]
+
+    def tile(i, carry):
+        top, total, acc = carry                            # [KV, G, T], ..., [KV, G, T, D]
+        blocks = jax.lax.dynamic_slice_in_dim(table, i * tile_blocks, tile_blocks)
+        kc = k_pool[layer, blocks[:, None], head]          # [tb, KV, bs, D]
+        vc = v_pool[layer, blocks[:, None], head]
+        kc = kc.transpose(1, 0, 2, 3).reshape(kv, span, d)
+        vc = vc.transpose(1, 0, 2, 3).reshape(kv, span, d)
+        s = jnp.einsum("tkgd,ksd->kgts", q, kc,
+                       preferred_element_type=jnp.float32) * scale
+        key_pos = i * span + jnp.arange(span)
+        ok = jax.lax.dynamic_slice_in_dim(keep, i * tile_blocks, tile_blocks, axis=2)
+        ok = jnp.repeat(ok, bs, axis=2) & (key_pos[None, None] <= pos[None, :, None])
+        s = jnp.where(ok[:, None], s, _MASKED)
+        new_top = jnp.maximum(top, s.max(axis=-1))
+        p = jnp.where(ok[:, None], jnp.exp(s - new_top[..., None]), 0.0)
+        alpha = jnp.exp(top - new_top)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "kgts,ksd->kgtd", p.astype(q.dtype), vc,
+            preferred_element_type=jnp.float32)
+        return new_top, total * alpha + p.sum(axis=-1), acc
+
+    init = (jnp.full((kv, g, t), _MASKED, jnp.float32),
+            jnp.zeros((kv, g, t), jnp.float32),
+            jnp.zeros((kv, g, t, d), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(
+        0, jnp.minimum(pos[-1] // span + 1, n_tiles), tile, init)
+    o = acc / jnp.maximum(total, 1e-37)[..., None]
+    return o.transpose(2, 0, 1, 3).astype(q.dtype)

@@ -29,13 +29,20 @@ import sys
 
 
 def build_argparser() -> argparse.ArgumentParser:
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
+    from gpt_2_distributed_tpu.config import MODEL_PRESETS, SALA_PRESETS
 
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    p.add_argument("--ckpt", required=True,
+    p.add_argument("--ckpt", default=None,
                    help="checkpoint dir (step_NNNNNNN) or save dir (uses latest)")
-    p.add_argument("--model", default="124M", choices=sorted(MODEL_PRESETS))
+    p.add_argument("--init_random", action="store_true",
+                   help="random weights from --seed instead of a checkpoint "
+                        "(the minicpm-sala-* models have no checkpoint format)")
+    p.add_argument("--model", default="124M",
+                   choices=sorted(MODEL_PRESETS) + sorted(SALA_PRESETS))
     p.add_argument("--n_layer", type=int, default=None)
+    p.add_argument("--first_layer", type=int, default=0,
+                   help="with --n_layer and a minicpm-sala-* model: that many "
+                        "consecutive layers of the published stack from this one on")
     p.add_argument("--n_embd", type=int, default=None)
     p.add_argument("--n_head", type=int, default=None)
     p.add_argument("--vocab_size", type=int, default=None)
@@ -69,8 +76,49 @@ def build_argparser() -> argparse.ArgumentParser:
     return p
 
 
+def _sample_sala(args, jax) -> None:
+    """A layer-pattern model keeps a paged cache and a recurrent state, so it
+    is sampled the way it is served: one request through a one-slot
+    ``ServingEngine`` (chunked prefill, then the decode step)."""
+    from gpt_2_distributed_tpu.config import ServeConfig, sala_config_from_flags
+    from gpt_2_distributed_tpu.models import minicpm_sala
+    from gpt_2_distributed_tpu.serving import ServingEngine
+    from gpt_2_distributed_tpu.utils.device_info import device_banner
+
+    if args.prompt_ids is None:
+        sys.exit("--model minicpm-sala-* takes --prompt_ids (no tokenizer is shipped)")
+    config = sala_config_from_flags(args)
+    ids = [int(t) for t in args.prompt_ids.split(",")]
+    bad = [t for t in ids if not 0 <= t < config.vocab_size]
+    if not ids or bad:
+        sys.exit(f"prompt ids empty or out of vocab range: {bad[:5]}")
+    print(device_banner(), file=sys.stderr)
+    block = config.sparse.block
+    total = len(ids) + args.new
+    serve = ServeConfig(
+        max_batch=1, block_size=block, num_blocks=-(-total // block) + 1,
+        prefill_chunk=block * max(1, min(2048, total) // block), max_seq_len=total)
+    eng = ServingEngine(
+        minicpm_sala.init_params(config, jax.random.PRNGKey(args.seed)), config,
+        serve, temperature=args.temperature, top_k=args.top_k)
+    print(",".join(str(t) for t in ids), end="", flush=True)
+    eng.submit(ids, args.new, rng=jax.random.PRNGKey(args.seed),
+               on_token=lambda _req, tok: print(f",{tok}", end="", flush=True))
+    eng.run_until_idle()
+    print(flush=True)
+
+
 def main(argv: list[str] | None = None) -> None:
-    args = build_argparser().parse_args(argv)
+    p = build_argparser()
+    args = p.parse_args(argv)
+    if (args.ckpt is None) == (not args.init_random):
+        p.error("exactly one of --ckpt / --init_random is required")
+    from gpt_2_distributed_tpu.config import SALA_PRESETS, validate_model_flags
+
+    validate_model_flags(p, args)
+    if args.init_random and args.model not in SALA_PRESETS:
+        p.error("--init_random samples a minicpm-sala-* model; GPT-2 presets "
+                "are sampled from a checkpoint")
     from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()
@@ -82,6 +130,8 @@ def main(argv: list[str] | None = None) -> None:
 
     if args.device:
         jax.config.update("jax_platforms", args.device)
+    if args.model in SALA_PRESETS:
+        return _sample_sala(args, jax)
 
     from gpt_2_distributed_tpu.checkpoint import latest_checkpoint, restore_params
     from gpt_2_distributed_tpu.config import MODEL_PRESETS

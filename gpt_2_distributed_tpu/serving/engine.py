@@ -100,7 +100,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from gpt_2_distributed_tpu.config import GPT2Config, ServeConfig
+from gpt_2_distributed_tpu.config import (
+    GPT2Config,
+    SalaConfig,
+    ServeConfig,
+    refuse_for_sala,
+)
 from gpt_2_distributed_tpu.models import decode, gpt2
 from gpt_2_distributed_tpu.obs import compile_watch
 from gpt_2_distributed_tpu.obs.trace import get_tracer
@@ -127,6 +132,7 @@ from gpt_2_distributed_tpu.serving.paged_cache import (
     write_chunk,
     write_rows,
 )
+from gpt_2_distributed_tpu.serving import sala_programs
 from gpt_2_distributed_tpu.serving.step_clocks import step_clocks
 
 
@@ -764,6 +770,20 @@ class ServingEngine:
         draft_config: GPT2Config | None = None,
     ):
         serve = serve if serve is not None else ServeConfig()
+        # The family seam: a model configuration picks the step programs
+        # (`_chunk_fn`, `_decode_fn`) and whatever cache it keeps beside the
+        # K/V pools (`self.state`); admission, slots, block tables, the
+        # prefill tick, growth, the emit loop, the counters and the spans
+        # below are one scheduler for every family. What a family cannot do
+        # yet is refused here, by name, not run wrong.
+        self._sala = isinstance(config, SalaConfig)
+        if self._sala:
+            why = refuse_for_sala(
+                config, serve,
+                draft_params is not None or draft_config is not None)
+            if why is not None:
+                raise ValueError(
+                    f"ServingEngine cannot serve a SalaConfig with {why}")
         # Sampling params are engine-level (static in the compiled step);
         # validate top_k once here with the shared check so a bad engine
         # config fails like a bad request would.
@@ -815,6 +835,7 @@ class ServingEngine:
         self.compute_dtype = compute_dtype
 
         self._m = serve.max_blocks_per_seq(config.n_positions)
+        self._seq_limit = serve.seq_limit(config.n_positions)
         # --- serving mesh (ServeConfig.mesh): data × tp, or None -----------
         self._dp, self._tp = serve.mesh_axes()
         self.mesh = None
@@ -925,7 +946,14 @@ class ServingEngine:
                 )
             self._scatter_fn, self._copy_fn = make_pool_jits(pool_sharding)
         self.k_pool, self.v_pool = init_pools(
-            config, serve, compute_dtype, sharding=pool_sharding
+            config.kv_pool_view if self._sala else config, serve,
+            compute_dtype, sharding=pool_sharding,
+        )
+        # The family's cache beside the pools (None: it keeps none), carried
+        # through its step programs and donated like them.
+        self.state = (
+            sala_programs.init_state(config, serve, compute_dtype)
+            if self._sala else None
         )
         self.allocator = BlockAllocator(serve.num_blocks, num_shards=self._dp)
         self._slots_per_shard = serve.max_batch // self._dp
@@ -1001,12 +1029,33 @@ class ServingEngine:
             "steps": 0, "step_ms": 0.0, "admit_ms": 0.0, "grow_ms": 0.0,
             "decode_dispatch_ms": 0.0, "emit_ms": 0.0,
             "decode_rows": 0, "decode_attended": 0,
+            # Chunked prefill's twin of the two above (tokens a dispatch
+            # takes, keys they attend), and - zero unless a layer selects
+            # its blocks - rows through the selection (prefill and decode
+            # alike), the blocks they attend and see; requests started from
+            # a zero recurrent state (`step_clocks` shows each per step).
+            "prefill_tokens": 0, "prefill_attended": 0,
+            "sparse_rows": 0, "sparse_selected": 0, "sparse_visible": 0,
+            "state_resets": 0,
         }
 
         # Per-engine jits so tests can count THIS engine's compilations:
         # the no-retrace contract is `_decode_fn._cache_size() == 1` across
         # arbitrary admission/eviction churn, and `_chunk_fn._cache_size()
         # == 1` in chunked mode (the chunk width is fixed).
+        if self._sala:
+            donate = ("k_pool", "v_pool", "state")
+            sampling = dict(config=config, temperature=self.temperature, top_k=top_k)
+            self._decode_fn = jax.jit(
+                _program("decode_step", sala_programs.decode_step_impl, **sampling),
+                donate_argnames=donate,
+            )
+            self._chunk_fn = jax.jit(
+                _program("chunk_prefill", sala_programs.chunk_prefill_impl, **sampling),
+                donate_argnames=donate,
+            )
+            get_tracer().event("engine_mesh", mesh="single", devices=1, data=1, tp=1)
+            return
         self._decode_fn = jax.jit(
             _program(
                 "decode_step", _decode_step_impl, config=config,
@@ -1103,8 +1152,13 @@ class ServingEngine:
     def kv_pool_bytes_per_device(self) -> int:
         """Per-device bytes of the two KV pools under the serving mesh
         ('data' splits the block axis, 'tp' the head axis)."""
+        itemsize = jnp.dtype(self.compute_dtype).itemsize
+        if self._sala:
+            return pool_bytes(
+                self.config.kv_pool_view, self.serve, itemsize
+            ) + sum(a.nbytes for a in jax.tree_util.tree_leaves(self.state))
         return pool_bytes(
-            self.config, self.serve, jnp.dtype(self.compute_dtype).itemsize
+            self.config, self.serve, itemsize
         ) // (self._dp * self._tp)
 
     # ------------------------------------------------------------- intake
@@ -1144,6 +1198,11 @@ class ServingEngine:
         check_generation_args(
             self.config, len(prompt), max_new_tokens, self.top_k, batch=1
         )
+        if len(prompt) + max_new_tokens > self._seq_limit:
+            raise ValueError(
+                f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
+                f"exceeds max_seq_len ({self._seq_limit})"
+            )
         need = self._blocks_needed(len(prompt), max_new_tokens)
         # A request must fit in the SMALLEST data shard (shard 0 also hosts
         # the null block) so admission can always place the queue head once
@@ -1421,12 +1480,21 @@ class ServingEngine:
             clen[i] = cl
             keys[i] = req._key
             cls.append(cl)
+        self._count_prefill(start, clen)
         t0 = time.monotonic()
         with self._mesh_scope():
-            first, out_keys, self.k_pool, self.v_pool = self._chunk_fn(
-                self.params, self.k_pool, self.v_pool,
-                bt, chunk, start, clen, keys,
-            )
+            if self.state is None:
+                first, out_keys, self.k_pool, self.v_pool = self._chunk_fn(
+                    self.params, self.k_pool, self.v_pool,
+                    bt, chunk, start, clen, keys,
+                )
+            else:
+                (first, out_keys, self.k_pool, self.v_pool,
+                 self.state) = self._chunk_fn(
+                    self.params, self.k_pool, self.v_pool, self.state,
+                    bt, chunk, start, clen, keys,
+                    np.asarray(slots, np.int32),
+                )
         first.block_until_ready()
         dur_ms = (time.monotonic() - t0) * 1e3
         first_host = np.asarray(first)
@@ -1789,9 +1857,17 @@ class ServingEngine:
         with self._phase(tracer, "decode", "decode_ms", rows=rows):
             with self._phase(tracer, "dispatch", "decode_dispatch_ms"), \
                     self._mesh_scope():
-                next_tokens, new_keys, self.k_pool, self.v_pool = \
-                    self._decode_fn(
-                        self.params, self.k_pool, self.v_pool,
+                if self.state is None:
+                    next_tokens, new_keys, self.k_pool, self.v_pool = \
+                        self._decode_fn(
+                            self.params, self.k_pool, self.v_pool,
+                            self.block_table, self.tokens, self.pos,
+                            self.active, self.keys,
+                        )
+                else:
+                    (next_tokens, new_keys, self.k_pool, self.v_pool,
+                     self.state) = self._decode_fn(
+                        self.params, self.k_pool, self.v_pool, self.state,
                         self.block_table, self.tokens, self.pos, self.active,
                         self.keys,
                     )
@@ -1826,8 +1902,34 @@ class ServingEngine:
         """Rows of this decode step, counted with the keys they attend."""
         rows = int(was_active.sum())
         self.stats["decode_rows"] += rows
-        self.stats["decode_attended"] += int(self.pos[was_active].sum()) + rows
+        self.stats["decode_attended"] += self._count_attended(
+            self.pos[was_active])
         return rows
+
+    def _count_attended(self, positions: np.ndarray) -> int:
+        """Keys that queries at ``positions`` attend in one layer: all up to
+        their own, or - where the family selects blocks - those of the
+        selected blocks, which the selection's sizes fix without a look at
+        the device (counted into the ``sparse_...`` counters too)."""
+        if not self._sala:
+            return int(positions.sum()) + len(positions)
+        bs = self.serve.block_size
+        selected, visible = self.config.sparse.selected_blocks(positions)
+        self.stats["sparse_rows"] += len(positions)
+        self.stats["sparse_selected"] += int(selected.sum())
+        self.stats["sparse_visible"] += int(visible.sum())
+        # the query's own block is attended up to the query
+        return int((selected * bs - (bs - 1 - positions % bs)).sum())
+
+    def _count_prefill(self, start: np.ndarray, clen: np.ndarray) -> None:
+        """Tokens of this chunk dispatch, counted with the keys they attend;
+        a chunk that opens a request starts its recurrent state from zero."""
+        for s, n in zip(start.tolist(), clen.tolist()):
+            self.stats["prefill_tokens"] += n
+            self.stats["prefill_attended"] += self._count_attended(
+                np.arange(s, s + n))
+            if self._sala and n and s == 0:
+                self.stats["state_resets"] += 1
 
     def _spec_round(self, tracer) -> int:
         """One speculative two-model step for every active row.

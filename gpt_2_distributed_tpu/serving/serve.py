@@ -55,14 +55,20 @@ import time
 def add_model_flags(p: argparse.ArgumentParser) -> None:
     """Model/checkpoint selection flags, shared verbatim with
     ``gpt2-tpu-frontend`` (serving/frontend/server.py)."""
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
+    from gpt_2_distributed_tpu.config import MODEL_PRESETS, SALA_PRESETS
 
     p.add_argument("--ckpt", default=None,
                    help="checkpoint dir (step_NNNNNNN) or save dir (latest)")
     p.add_argument("--init_random", action="store_true",
                    help="serve seeded-init weights instead of a checkpoint")
-    p.add_argument("--model", default="124M", choices=sorted(MODEL_PRESETS))
+    p.add_argument("--model", default="124M",
+                   choices=sorted(MODEL_PRESETS) + sorted(SALA_PRESETS))
     p.add_argument("--n_layer", type=int, default=None)
+    p.add_argument("--first_layer", type=int, default=0,
+                   help="with --n_layer and a layer-pattern model "
+                        "(minicpm-sala-*): serve --n_layer consecutive layers "
+                        "of the published stack from this one on (16 from 9 "
+                        "keeps the published ratio of kinds)")
     p.add_argument("--n_embd", type=int, default=None)
     p.add_argument("--n_head", type=int, default=None)
     p.add_argument("--vocab_size", type=int, default=None)
@@ -83,6 +89,9 @@ def add_engine_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--block_size", type=int, default=16)
     p.add_argument("--num_blocks", type=int, default=0,
                    help="KV pool blocks; 0 = max_batch worst-case sequences")
+    p.add_argument("--max_seq_len", type=int, default=0,
+                   help="longest prompt + output a request may have; sets the "
+                        "block table's width (0 = the model's positions)")
     p.add_argument("--attn_impl", default="auto",
                    choices=["auto", "xla", "pallas"])
     p.add_argument("--prefill_chunk", type=int, default=0,
@@ -284,8 +293,14 @@ def model_config_from_args(args: argparse.Namespace):
     """GPT2Config from --model + overrides, WITHOUT touching params or
     jax — subprocess placement needs the config (pool sizing, prompt
     validation) while the weights load only inside the workers."""
-    from gpt_2_distributed_tpu.config import MODEL_PRESETS
+    from gpt_2_distributed_tpu.config import (
+        MODEL_PRESETS,
+        SALA_PRESETS,
+        sala_config_from_flags,
+    )
 
+    if args.model in SALA_PRESETS:
+        return sala_config_from_flags(args)
     overrides = {
         k: getattr(args, k)
         for k in ("n_layer", "n_embd", "n_head", "vocab_size")
@@ -302,6 +317,7 @@ def load_model(args: argparse.Namespace):
     import jax
 
     from gpt_2_distributed_tpu.checkpoint import latest_checkpoint, restore_params
+    from gpt_2_distributed_tpu.config import SalaConfig
     from gpt_2_distributed_tpu.models import gpt2
     from gpt_2_distributed_tpu.utils.device_info import device_banner
 
@@ -311,6 +327,12 @@ def load_model(args: argparse.Namespace):
     # not tell a chip from the CPU JAX falls back to.
     print(device_banner(), file=sys.stderr, flush=True)
 
+    if isinstance(config, SalaConfig):
+        # --init_random, by `validate_model_flags`: bfloat16, held as such.
+        from gpt_2_distributed_tpu.models import minicpm_sala
+
+        return config, minicpm_sala.init_params(
+            config, jax.random.PRNGKey(getattr(args, "seed", 0)))
     if args.init_random:
         params = gpt2.init_params(config)
     else:
@@ -378,7 +400,9 @@ def build_serve_config(args: argparse.Namespace, config):
 
     mesh = getattr(args, "serve_mesh", "") or ""
     num_blocks = args.num_blocks
-    probe = ServeConfig(max_batch=args.max_batch, block_size=args.block_size)
+    max_seq_len = getattr(args, "max_seq_len", 0)
+    probe = ServeConfig(max_batch=args.max_batch, block_size=args.block_size,
+                        max_seq_len=max_seq_len)
     if num_blocks == 0:
         num_blocks = 1 + args.max_batch * probe.max_blocks_per_seq(
             config.n_positions
@@ -398,7 +422,7 @@ def build_serve_config(args: argparse.Namespace, config):
         prefill_chunk=args.prefill_chunk, prefix_cache=args.prefix_cache,
         admission=args.admission, watermark_blocks=args.watermark_blocks,
         mesh=mesh, prefill_batch=getattr(args, "prefill_batch", 1),
-        spec=spec,
+        spec=spec, max_seq_len=max_seq_len,
     )
 
 

@@ -16,14 +16,21 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
     ``decode_dispatch_ms`` + ``decode_wait_ms``: a decode step less its
     draft pass, split where the decode program's call returns.
     ``decode_rows``, ``decode_attended``: the rows a decode step advances
-    and the keys they attend."""
+    and the keys they attend (of the selected blocks, where a layer
+    selects). ``prefill_tokens``, ``prefill_attended``: the same of a chunked
+    prefill dispatch. ``sparse_rows``, ``sparse_selected``,
+    ``sparse_visible``: per step, the rows through a selecting attention
+    (prefill and decode alike), the blocks they attend and the blocks they
+    see. ``state_resets``: per step, requests started from a zero recurrent
+    state. A ``stats`` that predates a counter reads 0 there."""
     all_stats = list(all_stats)
 
     def total(key: str) -> float:
-        return sum(stats[key] for stats in all_stats)
+        return sum(stats.get(key, 0) for stats in all_stats)
 
     steps = max(total("steps"), 1)
     decodes = max(total("decode_steps"), 1)
+    prefills = max(total("prefill_dispatches"), 1)
     return {
         "engine_host_ms": (
             total("step_ms") - total("prefill_ms") - total("decode_ms")
@@ -38,4 +45,10 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
         ) / decodes,
         "decode_rows": total("decode_rows") / decodes,
         "decode_attended": total("decode_attended") / decodes,
+        "prefill_tokens": total("prefill_tokens") / prefills,
+        "prefill_attended": total("prefill_attended") / prefills,
+        "sparse_rows": total("sparse_rows") / steps,
+        "sparse_selected": total("sparse_selected") / steps,
+        "sparse_visible": total("sparse_visible") / steps,
+        "state_resets": total("state_resets") / steps,
     }
