@@ -276,6 +276,8 @@ for _name, _dist in (
     ("decode_wait_ms", "mean"),        # from there to the token read-back
     ("decode_rows", "mean"),           # rows one decode step advances
     ("decode_attended", "mean"),       # keys those rows attend
+    ("decode_blocks_live", "mean"),    # table slots of those rows that hold keys
+    ("decode_blocks_table", "mean"),   # all table slots of those rows
     ("prefill_tokens", "mean"),        # tokens one chunked prefill dispatch takes
     ("prefill_attended", "mean"),      # keys those tokens attend
     ("sparse_rows", "mean"),           # rows a step puts through block selection

@@ -29,22 +29,30 @@ Two implementations, one contract:
   paged path is testable bit-for-bit against the exactness reference.
   The gather materializes the per-sequence K/V (HBM traffic ~2·B·S·H·D),
   which is what the Pallas kernel exists to avoid.
-* ``impl="pallas"`` — a scalar-prefetch kernel reusing the block tiling
-  machinery of ``ops/flash_block.py`` (exp2-folded online softmax, m/l/acc
-  VMEM scratch carried over the column grid): the grid's block axis indexes
-  the POOL through the prefetched block table (``index_map`` reads
-  ``block_table[b, j]``, offset to the layer's run of blocks), so each K/V
-  block is DMA'd straight from its pool slot — no gathered copy and no
-  layer slice ever exists. Decode is forward-only, so unlike flash_block
-  there is no VJP; numerics differ from the XLA path by online-softmax
-  ulps (same contract as flash vs dense attention).
+* ``impl="pallas"`` — a scalar-prefetch kernel with the exp2-folded online
+  softmax of ``ops/flash_block.py`` (m/l/acc VMEM scratch carried over the
+  grid's block axis). The grid is ``(B, ceil(M / P))``: one step takes ``P``
+  blocks of one row with EVERY head — block ``n``'s ``[H, bs, D]`` is one
+  contiguous run of the row-major pool, so one DMA brings it — and the
+  pool is handed to the kernel ``P`` times, each operand's ``index_map``
+  reading its block from the prefetched table (offset to the layer's run
+  of blocks): no gathered copy and no layer slice ever exists. Table slots
+  past a row's last live block are clamped onto that block before the
+  call; a step whose block index repeats fetches nothing, and the
+  arithmetic is skipped too, so the table's tail costs neither.
+  ``paged_decode_grid`` gives the grid and ``P`` from the shapes. A block's
+  two products run on the VPU (a one-row product wastes the MXU): bf16
+  products summed in fp32, what the MXU gives but for the order. Decode is
+  forward-only, so unlike flash_block there is no VJP; numerics differ from
+  the XLA path by online-softmax ulps (same contract as flash vs dense
+  attention).
 
 Per-sequence lengths do the masking: position ``s`` of sequence ``b`` is
 attendable iff ``s < lengths[b]``. ``lengths[b] == 0`` marks an idle slot
 (o = 0) — pool blocks behind the table row are never read into the result.
 Block-table entries past a sequence's last block must point at a valid pool
-index (the serving layer parks them on the reserved null block 0); they are
-fetched but fully masked.
+index (the serving layer parks them on the reserved null block 0): the XLA
+gather fetches them and masks them, the kernel does not read them at all.
 """
 
 from __future__ import annotations
@@ -60,7 +68,17 @@ from gpt_2_distributed_tpu.ops.attention import MASK_VALUE
 from gpt_2_distributed_tpu.ops.flash_attention import LOG2E, NEG_INF
 from gpt_2_distributed_tpu.ops.spmd import pallas_mode, record_resolved_impl
 
-_DIMS = ("parallel", "parallel", "arbitrary")  # j carries the m/l/acc scratch
+_DIMS = ("parallel", "arbitrary")  # j carries the m/l/acc scratch
+
+# What the K and V tiles of one grid step may hold of VMEM, double-buffered
+# as the pipeline keeps them (a v5e kernel has 16 MiB by default). More buys
+# nothing: the scalar core's work is per operand and step, so per table slot
+# whatever P is, and a wider step only fetches more of the clamp's repeats
+# (my chip runs, PR 29: a decode step's 48 calls at B 4, H 25, M 64 and
+# rows of 70-480 keys take 2.66 / 2.47 / 2.53 / 2.54 / 2.66 / 2.74 ms at
+# P 1 / 2 / 3 / 4 / 5 / 8; at full rows 6.85 / 5.90 / 5.86 / 5.81 / 5.90 /
+# 5.93).
+_VMEM_BUDGET = 2**20
 
 
 def _contiguous_view(pool: jnp.ndarray, layer, block_table: jnp.ndarray):
@@ -190,24 +208,39 @@ def spec_verify_attention(
     )
 
 
+def paged_decode_grid(
+    b: int, h: int, m: int, bs: int, d: int, itemsize: int = 2
+) -> tuple[tuple[int, int], int]:
+    """The kernel's grid ``(B, ceil(M / P))`` and ``P``, the blocks one step
+    takes, from the shapes alone: as many ``[H, bs, D]`` tiles as
+    ``_VMEM_BUDGET`` holds for K and V, double-buffered — at least one, at
+    most the table's width. A tile is counted as VMEM lays it out (``bs``
+    to the dtype's sublane tile, ``D`` to 128 lanes)."""
+    sublanes = 8 * 4 // itemsize
+    tile = h * -(-bs // sublanes) * sublanes * -(-d // 128) * 128 * itemsize
+    p = max(1, min(m, _VMEM_BUDGET // (4 * tile)))
+    return (b, -(-m // p)), p
+
+
 def _paged_fwd_kernel(
-    bt_ref,       # scalar prefetch: [B, M] int32 block table
+    bt_ref,       # scalar prefetch: [B, steps * P] int32 clamped block table
     len_ref,      # scalar prefetch: [B] int32 lengths
-    q_ref,        # [1, 1, 1, D]
-    k_ref,        # [1, 1, bs, D] — pool block selected by the index_map
-    v_ref,        # [1, 1, bs, D]
-    o_ref,        # [1, 1, 1, D]
-    m_scr,        # VMEM [1, 1] f32
-    l_scr,        # VMEM [1, 1] f32
-    acc_scr,      # VMEM [1, D] f32
-    *,
+    q_ref,        # [1, H, 1, D]
+    *refs,        # P K tiles and P V tiles [1, H, bs, D], each the pool block
+                  # its index_map selected; o [1, H, 1, D]; then VMEM scratch
+                  # m [H, 1, 1] f32, l [H, 1, 1] f32, acc [H, 1, D] f32
     block_size: int,
+    per_step: int,
+    table_width: int,
 ):
-    b, j = pl.program_id(0), pl.program_id(2)
+    k_refs, v_refs = refs[:per_step], refs[per_step:2 * per_step]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * per_step:]
+    b, j = pl.program_id(0), pl.program_id(1)
     d = q_ref.shape[3]
     scale = LOG2E / (d ** 0.5)
-    length = len_ref[b]
-    base = j * block_size
+    # Slots past the table's width (P need not divide it) hold a clamped
+    # block: never attend them, whatever the length says.
+    length = jnp.minimum(len_ref[b], table_width * block_size)
 
     @pl.when(j == 0)
     def _init():
@@ -215,39 +248,45 @@ def _paged_fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Blocks wholly past the sequence contribute nothing — skip the math
-    # (the DMA already happened; table tails point at the null block).
-    @pl.when(base < length)
-    def _compute():
-        q = (q_ref[0, 0].astype(jnp.float32) * scale).astype(q_ref.dtype)
-        k = k_ref[0, 0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, bs] f32, base-2 logits
-        col = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = col < length
+    def attend(k_ref, v_ref, base):
+        """One block, every head; all layouts keep their dims, so scores
+        stay [H, bs, 1]: keys on sublanes as K and V hold them."""
+        q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+        s = jnp.sum(
+            k_ref[0].astype(jnp.float32) * q.astype(jnp.float32),
+            axis=-1, keepdims=True,
+        )  # [H, bs, 1] f32, base-2 logits
+        row = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = row < length
         s = jnp.where(valid, s, NEG_INF)
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp2(m_prev - m_new)
         # Masked lanes must be forced to 0: on a row where every lane is
         # masked m_new stays NEG_INF and exp2(s - m_new) would leak 1s
         # (the same guard flash_block documents).
         p = jnp.where(valid, jnp.exp2(s - m_new), 0.0)
         m_scr[...] = m_new
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0]
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0]
+        acc_scr[...] = acc_scr[...] * alpha + jnp.sum(
+            p.astype(v.dtype).astype(jnp.float32) * v.astype(jnp.float32),
+            axis=1, keepdims=True,
         )
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    # Blocks wholly past the sequence contribute nothing and were not
+    # fetched (their slot repeats the last live block): skip the math.
+    for i in range(per_step):
+        base = (j * per_step + i) * block_size
+        pl.when(base < length)(
+            functools.partial(attend, k_refs[i], v_refs[i], base)
+        )
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
         l = l_scr[...]
         has = l > 0.0
-        o_ref[0, 0] = jnp.where(
+        o_ref[0] = jnp.where(
             has, acc_scr[...] / jnp.maximum(l, 1e-37), 0.0
         ).astype(o_ref.dtype)
 
@@ -271,10 +310,13 @@ def paged_attention_pallas(
     The layer is folded into the table, not into the kernel: the pool is
     viewed as ``[L*N, H, bs, D]`` (merging the two major axes of a
     row-major array moves nothing) and block ``n`` of layer ``l`` is row
-    ``l*N + n`` of it. A third scalar-prefetch operand and a 5-D
-    ``BlockSpec`` address the same bytes, 4 % slower: the grid's 6400 steps
-    a layer are ~0.15 us each, and the scalar core pays for every term of
-    an index map (my chip run, PR 26: 56.7 against 54.4 ms for 48 calls)."""
+    ``l*N + n`` of it. So is the clamp that stops a row at its last live
+    block: the table the kernel gets is ``steps * P`` wide and slot ``s``
+    of row ``b`` holds ``block_table[b, min(s, last live slot)]`` (slot 0
+    for an idle row), made here by one small gather. The scalar core pays
+    for every term of an index map, once per operand and step: with the
+    division and the minimum inside the map the same 48 calls took 3.03 ms
+    for 2.69 at P = 4 (my chip runs, PR 29)."""
     b, h, d = q.shape
     n, _, bs, _ = k_pool.shape[-4:]
     m = block_table.shape[1]
@@ -283,41 +325,52 @@ def paged_attention_pallas(
     record_resolved_impl(
         "paged_attention", f"pallas ({pallas_mode(interpret)})"
     )
+    grid, per_step = paged_decode_grid(b, h, m, bs, d, k_pool.dtype.itemsize)
 
+    lengths = lengths.astype(jnp.int32)
+    last = jnp.clip((lengths - 1) // bs, 0, m - 1)
+    slots = jnp.minimum(
+        jnp.arange(grid[1] * per_step, dtype=jnp.int32), last[:, None]
+    )
+    table = jnp.take_along_axis(block_table.astype(jnp.int32), slots, axis=1)
+
+    row = pl.BlockSpec((1, h, 1, d), lambda b_, j, bt, ln: (b_, 0, 0, 0))
+
+    def block_spec(i):
+        # The paging trick: the pool's block axis is indexed by the
+        # PREFETCHED table, not the grid — block j*P + i of sequence b
+        # lives wherever the allocator put it, with all its heads.
+        return pl.BlockSpec(
+            (1, h, bs, d),
+            lambda b_, j, bt, ln: (bt[b_, j * per_step + i], 0, 0, 0),
+        )
+
+    blocks = [block_spec(i) for i in range(per_step)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, m),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d),
-                         lambda b_, h_, j, bt, ln: (b_, h_, 0, 0)),
-            # The paging trick: the pool's block axis is indexed by the
-            # PREFETCHED table, not the grid — block j of sequence b lives
-            # wherever the allocator put it.
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h_, j, bt, ln: (bt[b_, j], h_, 0, 0)),
-            pl.BlockSpec((1, 1, bs, d),
-                         lambda b_, h_, j, bt, ln: (bt[b_, j], h_, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, d),
-                               lambda b_, h_, j, bt, ln: (b_, h_, 0, 0)),
+        grid=grid,
+        in_specs=[row, *blocks, *blocks],
+        out_specs=row,
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((h, 1, 1), jnp.float32),
+            pltpu.VMEM((h, 1, 1), jnp.float32),
+            pltpu.VMEM((h, 1, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_fwd_kernel, block_size=bs),
+        functools.partial(
+            _paged_fwd_kernel, block_size=bs, per_step=per_step, table_width=m
+        ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=_DIMS),
         interpret=interpret,
     )(
-        block_table.astype(jnp.int32) + jnp.asarray(layer, jnp.int32) * n,
-        lengths.astype(jnp.int32),
+        table + jnp.asarray(layer, jnp.int32) * n,
+        lengths,
         q[:, :, None],               # [B, H, 1, D]
-        k_pool.reshape(-1, h, bs, d),
-        v_pool.reshape(-1, h, bs, d),
+        *[k_pool.reshape(-1, h, bs, d)] * per_step,
+        *[v_pool.reshape(-1, h, bs, d)] * per_step,
     )
     return out[:, :, 0]
 

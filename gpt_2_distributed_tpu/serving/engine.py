@@ -1025,10 +1025,14 @@ class ServingEngine:
             # of decode_ms is the wait for the token read-back, and
             # draft_ms. emit_ms: after the read-back to the step's end.
             # decode_rows/decode_attended: per decode step, active rows and
-            # the keys they attend, sum of pos + 1.
+            # the keys they attend, sum of pos + 1. decode_blocks_live/
+            # decode_blocks_table: of those rows' block-table slots, the
+            # ones that hold keys (ceil((pos + 1) / block_size) a row) and
+            # all of them; the rest is the tail the paged kernel skips.
             "steps": 0, "step_ms": 0.0, "admit_ms": 0.0, "grow_ms": 0.0,
             "decode_dispatch_ms": 0.0, "emit_ms": 0.0,
             "decode_rows": 0, "decode_attended": 0,
+            "decode_blocks_live": 0, "decode_blocks_table": 0,
             # Chunked prefill's twin of the two above (tokens a dispatch
             # takes, keys they attend), and - zero unless a layer selects
             # its blocks - rows through the selection (prefill and decode
@@ -1899,11 +1903,15 @@ class ServingEngine:
         return emitted + decoded
 
     def _count_decode(self, was_active: np.ndarray) -> int:
-        """Rows of this decode step, counted with the keys they attend."""
+        """Rows of this decode step, counted with the keys they attend and
+        with the table slots that hold keys, of all the rows' slots."""
         rows = int(was_active.sum())
+        positions = self.pos[was_active]
         self.stats["decode_rows"] += rows
-        self.stats["decode_attended"] += self._count_attended(
-            self.pos[was_active])
+        self.stats["decode_attended"] += self._count_attended(positions)
+        self.stats["decode_blocks_live"] += rows + int(
+            (positions // self.serve.block_size).sum())
+        self.stats["decode_blocks_table"] += rows * self._m
         return rows
 
     def _count_attended(self, positions: np.ndarray) -> int:

@@ -17,7 +17,10 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
     draft pass, split where the decode program's call returns.
     ``decode_rows``, ``decode_attended``: the rows a decode step advances
     and the keys they attend (of the selected blocks, where a layer
-    selects). ``prefill_tokens``, ``prefill_attended``: the same of a chunked
+    selects). ``decode_blocks_live``, ``decode_blocks_table``: of those
+    rows' block-table slots, the ones that hold keys and all of them
+    (``1 - live / table`` is the share of slots the paged kernel skips).
+    ``prefill_tokens``, ``prefill_attended``: the same of a chunked
     prefill dispatch. ``sparse_rows``, ``sparse_selected``,
     ``sparse_visible``: per step, the rows through a selecting attention
     (prefill and decode alike), the blocks they attend and the blocks they
@@ -45,6 +48,8 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
         ) / decodes,
         "decode_rows": total("decode_rows") / decodes,
         "decode_attended": total("decode_attended") / decodes,
+        "decode_blocks_live": total("decode_blocks_live") / decodes,
+        "decode_blocks_table": total("decode_blocks_table") / decodes,
         "prefill_tokens": total("prefill_tokens") / prefills,
         "prefill_attended": total("prefill_attended") / prefills,
         "sparse_rows": total("sparse_rows") / steps,

@@ -35,7 +35,10 @@ from gpt_2_distributed_tpu.ops.fused_matmul import (
     matmul_bias_gelu_dropout,
     matmul_bias_residual_dropout,
 )
-from gpt_2_distributed_tpu.ops.paged_attention import paged_attention_pallas
+from gpt_2_distributed_tpu.ops.paged_attention import (
+    paged_attention_pallas,
+    paged_decode_grid,
+)
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 HBM_BYTES = 16 * 1024**3   # one v5e chip
@@ -146,20 +149,37 @@ def test_flash_block_forward_backward(chip):
 # --- paged decode attention --------------------------------------------------
 
 
-@pytest.mark.parametrize("heads", [12, 25], ids=["124M-H12", "1.5B-H25"])
-def test_paged_decode(chip, heads):
-    batch, bs, d = 8, 16, 64
+PAGED_SHAPES = {
+    # heads, batch, block size; a full-context block table
+    "124M-H12": (12, 8, 16),
+    "1.5B-H25": (25, 8, 16),
+    "124M-H12-b128": (12, 128, 16),
+    "1.5B-H25-bs32": (25, 8, 32),
+}
+
+
+@pytest.mark.parametrize("shape", PAGED_SHAPES.values(), ids=PAGED_SHAPES)
+def test_paged_decode(chip, shape):
+    heads, batch, bs = shape
+    d = 64
     m = 1024 // bs                       # full-context block table
     pool = ((1 + batch * m, heads, bs, d), BF16)
-    n = _kernels(
-        chip,
-        lambda q, kp, vp, bt, ln: paged_attention_pallas(
-            q, kp, vp, bt, ln, interpret=False
-        ),
-        ((batch, heads, d), BF16), pool, pool,
-        ((batch, m), I32), ((batch,), I32),
-    )
-    assert n == 1
+    shapes = (((batch, heads, d), BF16), pool, pool,
+              ((batch, m), I32), ((batch,), I32))
+
+    def fn(q, kp, vp, bt, ln):
+        return paged_attention_pallas(q, kp, vp, bt, ln, interpret=False)
+
+    assert _kernels(chip, fn, *shapes) == 1
+    # The grid it was built with is the one the shapes give: a row's every
+    # head in one step, P >= 1 blocks a step, never (B, H, M) again.
+    grid, per_step = paged_decode_grid(batch, heads, m, bs, d, 2)
+    traced = jax.make_jaxpr(fn)(
+        *(jax.ShapeDtypeStruct(*s) for s in shapes))
+    built = [e.params["grid_mapping"].grid for e in traced.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert built == [grid] and 1 <= per_step <= m
+    assert grid[0] * grid[1] <= batch * -(-m // per_step) < batch * heads * m
 
 
 # --- fused epilogues and fused matmuls, C=768 --------------------------------

@@ -23,7 +23,8 @@ from test_obs import host_annotations
 
 CLOCKS = ("step_ms", "admit_ms", "grow_ms", "prefill_ms", "decode_ms",
           "decode_dispatch_ms", "emit_ms", "draft_ms", "verify_ms")
-COUNTS = ("steps", "decode_steps", "decode_rows", "decode_attended")
+COUNTS = ("steps", "decode_steps", "decode_rows", "decode_attended",
+          "decode_blocks_live", "decode_blocks_table")
 
 MODES = {
     # chunked prefill, worst-case reservation: the benchmark cell's mode
@@ -86,6 +87,7 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
         assert s["draft_ms"] == s["verify_ms"] == 0
     assert (s["grow_ms"] > 0) == (mode == "whole-watermark")
     assert s["decode_rows"] >= s["decode_steps"] and s["decode_attended"] > s["decode_rows"]
+    assert s["decode_rows"] <= s["decode_blocks_live"] <= s["decode_blocks_table"]
     # what /metrics and the --tb_dir sink show of them: means per step,
     # every one registered, and the fleet's the same as its one engine's
     snap = eng.metrics_snapshot()
@@ -94,7 +96,9 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
                 "emit_ms": s["emit_ms"]}
     per_decode = {"decode_dispatch_ms": s["decode_dispatch_ms"],
                   "decode_wait_ms": s["decode_ms"] - s["draft_ms"] - s["decode_dispatch_ms"],
-                  "decode_rows": s["decode_rows"], "decode_attended": s["decode_attended"]}
+                  "decode_rows": s["decode_rows"], "decode_attended": s["decode_attended"],
+                  "decode_blocks_live": s["decode_blocks_live"],
+                  "decode_blocks_table": s["decode_blocks_table"]}
     for key, total in per_step.items():
         assert snap[key] == pytest.approx(total / s["steps"]), key
     for key, total in per_decode.items():
@@ -130,6 +134,9 @@ def test_fleet_snapshot_and_the_servers_compile_lines(tiny_params, tiny_config, 
     assert snap == pytest.approx(
         {**snap, **step_clocks(e.stats for e in router.engines)})
     assert snap["engine_host_ms"] > 0 and 1 <= snap["decode_rows"] <= 2
+    width = serve.max_blocks_per_seq(tiny_config.n_positions)
+    assert snap["decode_blocks_table"] == pytest.approx(snap["decode_rows"] * width)
+    assert snap["decode_rows"] <= snap["decode_blocks_live"] <= snap["decode_blocks_table"]
     lines = [ln for ln in capsys.readouterr().err.splitlines()
              if ln.startswith("[serve] ")]
     assert lines[0].startswith("[serve] set-up: ") and " programs, " in lines[0]
@@ -162,6 +169,40 @@ def test_decode_counters_are_the_dispatch_arguments(tiny_params, tiny_config):
             per_step.append((now[0] - last[0], now[1] - last[1]))
             last = now
     assert per_step == seen and len(seen) == eng.stats["decode_steps"] > 3
+
+
+@pytest.mark.parametrize("mode", ["chunked", "whole-watermark"])
+def test_decode_block_counters_replay_the_positions(mode, tiny_params, tiny_config):
+    """``decode_blocks_live`` / ``decode_blocks_table``: of the block-table
+    slots of the rows a decode step advances, those that hold keys
+    (``ceil((pos + 1) / block_size)`` a row) and all of them - what a
+    replay of the positions the decode program is handed gives, step for
+    step; the rest is the tail the paged kernel neither fetches nor attends."""
+    eng = _engine(mode, tiny_params, tiny_config)
+    bs = eng.serve.block_size
+    inner, seen = eng._decode_fn, []
+
+    def spy(params, k_pool, v_pool, block_table, tokens, pos, active, keys):
+        active = np.asarray(active)
+        at = np.asarray(pos)[active]
+        seen.append((int(-(-(at + 1) // bs).sum()),
+                     int(active.sum()) * np.asarray(block_table).shape[1]))
+        return inner(params, k_pool, v_pool, block_table, tokens, pos, active, keys)
+
+    eng._decode_fn = spy
+    for i, p in enumerate((9, 4, 23, 16)):     # 16: a row that starts a block
+        eng.submit(list(range(1, p + 1)), 9, rng=i)
+    per_step, last = [], (0, 0)
+    while eng.has_work():
+        eng.step()
+        now = (eng.stats["decode_blocks_live"], eng.stats["decode_blocks_table"])
+        if now != last:
+            per_step.append((now[0] - last[0], now[1] - last[1]))
+            last = now
+    assert per_step == seen and len(seen) == eng.stats["decode_steps"] > 3
+    width = eng.block_table.shape[1]
+    assert all(table // width <= live <= table for live, table in seen)
+    assert 0 < last[0] < last[1]               # this traffic leaves a tail
 
 
 @pytest.mark.parametrize("mode", ["chunked", "whole-watermark"])

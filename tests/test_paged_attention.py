@@ -13,10 +13,18 @@ from gpt_2_distributed_tpu.ops.attention import MASK_VALUE
 from gpt_2_distributed_tpu.ops.paged_attention import (
     paged_attention,
     paged_attention_pallas,
+    paged_decode_grid,
     paged_attention_xla,
     paged_prefill_attention,
 )
 from gpt_2_distributed_tpu.serving.paged_cache import write_chunk, write_rows
+
+
+def _dense_view(pool, table):
+    """The contiguous per-sequence view ``[B, H, M*bs, D]`` a table encodes."""
+    c = np.asarray(pool, np.float32)[np.asarray(table)]    # [B, M, H, bs, D]
+    b, m, h, bs, d = c.shape
+    return c.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
 
 
 def _paged_case(rng, b=3, h=2, d=8, bs=4, m=4, n_blocks=32, scramble=True):
@@ -31,11 +39,8 @@ def _paged_case(rng, b=3, h=2, d=8, bs=4, m=4, n_blocks=32, scramble=True):
         perm = np.sort(perm)
     table = jnp.asarray(perm.reshape(b, m), jnp.int32)
     lengths = jnp.asarray(rng.integers(1, m * bs + 1, b), jnp.int32)
-    kc = np.asarray(k_pool)[np.asarray(table)]           # [B, M, H, bs, D]
-    kc = kc.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    vc = np.asarray(v_pool)[np.asarray(table)]
-    vc = vc.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    return q, k_pool, v_pool, table, lengths, kc, vc
+    return (q, k_pool, v_pool, table, lengths,
+            _dense_view(k_pool, table), _dense_view(v_pool, table))
 
 
 def _dense_reference(q, kc, vc, lengths):
@@ -62,11 +67,93 @@ def test_xla_matches_dense_reference(rng_np):
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
 
 
-def test_pallas_matches_dense_reference(rng_np):
-    q, kp, vp, table, lengths, kc, vc = _paged_case(rng_np)
+def _edge_case(rng, h, bs, dtype, m=7, k=3, d=64):
+    """One batch of the lengths a block table can hold awkwardly - 1,
+    ``k*bs - 1``, ``k*bs``, ``k*bs + 1``, the full table - beside an idle
+    row, at a head count and block size the presets run."""
+    lengths = [1, k * bs - 1, k * bs, k * bs + 1, m * bs, 0]
+    b, n_blocks = len(lengths), 1 + len(lengths) * m
+    q = jnp.asarray(rng.normal(size=(b, h, d)), dtype)
+    k_pool = jnp.asarray(rng.normal(size=(n_blocks, h, bs, d)), dtype)
+    v_pool = jnp.asarray(rng.normal(size=(n_blocks, h, bs, d)), dtype)
+    table = jnp.asarray(
+        rng.permutation(np.arange(1, n_blocks)).reshape(b, m), jnp.int32)
+    return (q, k_pool, v_pool, table, jnp.asarray(lengths, jnp.int32),
+            _dense_view(k_pool, table), _dense_view(v_pool, table))
+
+
+KERNEL_CASES = {
+    # heads, block_size, dtype, whether the chosen P leaves the table's width
+    # (7) a remainder: the last step's spare slots hold the clamp
+    "random-small": None,
+    "H12-bs16": (12, 16, jnp.float32, True),
+    "H16-bs16": (16, 16, jnp.float32, True),
+    "H20-bs16": (20, 16, jnp.float32, False),
+    "H25-bs16": (25, 16, jnp.float32, False),
+    "H12-bs32": (12, 32, jnp.float32, False),
+    "H25-bs32": (25, 32, jnp.float32, False),
+    "H12-bs16-bf16": (12, 16, jnp.bfloat16, True),
+    "H25-bs16-bf16": (25, 16, jnp.bfloat16, True),
+}
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_pallas_matches_dense_reference(rng_np, case):
+    if KERNEL_CASES[case] is None:
+        q, kp, vp, table, lengths, kc, vc = _paged_case(rng_np)
+        tol = dict(rtol=1e-4, atol=1e-5)
+    else:
+        h, bs, dtype, remainder = KERNEL_CASES[case]
+        q, kp, vp, table, lengths, kc, vc = _edge_case(rng_np, h, bs, dtype)
+        m = table.shape[1]
+        (rows, steps), per_step = paged_decode_grid(
+            q.shape[0], h, m, bs, q.shape[2], kp.dtype.itemsize)
+        assert rows == q.shape[0] and steps == -(-m // per_step)
+        assert bool(m % per_step) == remainder, per_step
+        # bf16: q, the probabilities and the output are rounded to it
+        tol = (dict(rtol=1e-4, atol=1e-5) if dtype == jnp.float32
+               else dict(rtol=3e-2, atol=3e-2))
     got = paged_attention_pallas(q, kp, vp, table, lengths)  # interpret=CPU
-    want = _dense_reference(q, kc, vc, lengths)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-5)
+    want = _dense_reference(np.asarray(q, np.float32), kc, vc, lengths)
+    np.testing.assert_allclose(np.asarray(got, np.float32), want, **tol)
+    xla = paged_attention_xla(q, kp, vp, table, lengths)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(xla, np.float32), **tol)
+    idle = np.asarray(lengths) == 0
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[idle], 0.0)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["one-layer-pool", "last-layer"])
+def test_table_tail_is_never_read(rng_np, layer):
+    """The kernel stops at each row's last live block: table entries past
+    it - every entry of an idle row - may point at blocks full of NaN, and
+    at the pool's very last index, and the output is bitwise what a table
+    parked on the null block gives. (The XLA gather does read them: 0 x NaN.)"""
+    h, bs, d, m, n = 12, 16, 64, 7, 40
+    lengths = [0, 1, 2 * bs, 3 * bs + 1, m * bs - 1]
+    b = len(lengths)
+    shape = (n, h, bs, d) if layer == 0 else (layer + 1, n, h, bs, d)
+    q = jnp.asarray(rng_np.normal(size=(b, h, d)), jnp.float32)
+    kp = rng_np.normal(size=shape).astype(np.float32)
+    vp = rng_np.normal(size=shape).astype(np.float32)
+    poisoned = [n - 3, n - 2, n - 1]          # n - 1: the pool's last index
+    kp[..., poisoned, :, :, :] = np.nan
+    vp[..., poisoned, :, :, :] = np.nan
+    parked = np.zeros((b, m), np.int32)
+    pointed = np.zeros((b, m), np.int32)
+    free = iter(rng_np.permutation(np.arange(1, n - 3)))
+    for i, ln in enumerate(lengths):
+        live = -(-ln // bs)
+        parked[i, :live] = pointed[i, :live] = [next(free) for _ in range(live)]
+        pointed[i, live:] = [poisoned[(i + j) % 3] for j in range(m - live)]
+    assert paged_decode_grid(b, h, m, bs, d, 4)[1] == 2      # 7 slots, 4 steps
+    out = [paged_attention_pallas(q, jnp.asarray(kp), jnp.asarray(vp),
+                                  jnp.asarray(t), jnp.asarray(lengths, jnp.int32),
+                                  jnp.int32(layer))
+           for t in (parked, pointed)]
+    assert np.isfinite(np.asarray(out[0])).all()
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(out[0]))
+    np.testing.assert_array_equal(np.asarray(out[1][0]), 0.0)    # the idle row
 
 
 def test_idle_slot_outputs_exact_zeros(rng_np):
@@ -139,11 +226,8 @@ def _prefill_case(rng, b=2, t=5, h=2, d=8, bs=4, m=6, n_blocks=32):
     perm = rng.permutation(np.arange(1, n_blocks))[: b * m]
     table = jnp.asarray(perm.reshape(b, m), jnp.int32)
     start = jnp.asarray(rng.integers(0, m * bs - t + 1, b), jnp.int32)
-    kc = np.asarray(k_pool)[np.asarray(table)]
-    kc = kc.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    vc = np.asarray(v_pool)[np.asarray(table)]
-    vc = vc.transpose(0, 2, 1, 3, 4).reshape(b, h, m * bs, d)
-    return q, k_pool, v_pool, table, start, kc, vc
+    return (q, k_pool, v_pool, table, start,
+            _dense_view(k_pool, table), _dense_view(v_pool, table))
 
 
 def _prefill_dense_reference(q, kc, vc, start):
@@ -195,7 +279,7 @@ def test_prefill_future_positions_are_bitwise_invisible(rng_np):
 
 
 @pytest.mark.parametrize("layer", [0, 2, 4], ids=["first", "middle", "last"])
-@pytest.mark.parametrize("heads", [25, 12], ids=["H25", "H12"])
+@pytest.mark.parametrize("heads", [25, 12, 16, 20], ids=["H25", "H12", "H16", "H20"])
 def test_layer_of_the_whole_pool_matches_its_slice(rng_np, heads, layer):
     """Every op takes the whole ``[L, N, H, bs, D]`` pool and a layer: the
     kernel (interpret mode; the layer folded into its block table) and the
