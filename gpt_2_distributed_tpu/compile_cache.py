@@ -1,10 +1,9 @@
 """Where JAX's persistent compilation cache lives.
 
-Every entry point (train, sample, serve, the HTTP front door, the worker,
-``bench.py``) calls :func:`ensure_compile_cache` before it builds a program,
-so that a second process compiling the same program — a ``--resume`` run, a
-respawned worker, the next benchmark cell — reads it back instead of
-compiling cold.
+Every entry point (train, sample, serve, the HTTP front door, the worker)
+calls :func:`ensure_compile_cache` before it builds a program, so that a
+second process compiling the same program — a ``--resume`` run, a respawned
+worker, the next benchmark cell — reads it back instead of compiling cold.
 
 The directory is placed from outside: where ``JAX_COMPILATION_CACHE_DIR`` is
 set JAX reads it itself and this module sets nothing. Otherwise the cache

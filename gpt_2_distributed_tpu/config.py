@@ -21,8 +21,9 @@ from dataclasses import dataclass
 
 # Row-chunk default for the blocked CE (ops/losses.py imports it back from
 # here). Defined in config — NOT in ops — so this module stays importable
-# without jax: CLIs (scripts/bench_serve.py) validate flags, including
-# serving mesh specs, before any jax import.
+# without jax: serve.py and frontend/server.py refuse bad flags before jax
+# loads, and the parent of a worker fleet (--placement subprocess|remote)
+# builds its ServeConfig and never loads it.
 DEFAULT_BLOCK_ROWS = 1024
 
 
@@ -77,10 +78,10 @@ class GPT2Config:
     # the attention->MLP junction (proj-dropout + residual + ln2, plus the
     # block-closing residual+dropout); "gelu" fuses the MLP's bias + tanh-GELU
     # + activation-dropout epilogue over the [*, 4C] tensor; "all" = both.
-    # Default "off" until the marginal microbench (scripts/bench_fused.py)
-    # proves the win on-chip. Shapes/meshes the kernels can't host (C not
-    # 128-aligned, sp/tp-sharded activations, decode's T=1 rows) fall back to
-    # the unfused path automatically — same math, different dropout stream.
+    # Default "off" until a benchmark cell proves the win on-chip (ROADMAP
+    # S4). Shapes/meshes the kernels can't host (C not 128-aligned,
+    # sp/tp-sharded activations, decode's T=1 rows) fall back to the unfused
+    # path automatically — same math, different dropout stream.
     fused_layers: str = "off"
     # Fused matmul+epilogue Pallas kernels (ops/fused_matmul.py) — the v2
     # step beyond fused_layers: the matmul itself runs in a tiled MXU kernel
@@ -92,10 +93,11 @@ class GPT2Config:
     # head-explicit einsum GSPMD shards). Composable with fused_layers: on a
     # leg both cover, fused_matmul wins (it subsumes the v1 epilogue; the v1
     # kernels keep the junctions fused_matmul doesn't reach, e.g. the
-    # attn->MLP LN). Default "off" until scripts/bench_fused.py proves the
-    # win on-chip. Unhostable shapes/meshes (K or M not 128-aligned — the
-    # 1.5B C=1600 — sp/tp-sharded activations, decode's T=1 rows) fall back
-    # to the unfused composition, recorded via the `fused_fallback` metric.
+    # attn->MLP LN). Default "off" until a benchmark cell proves the win
+    # on-chip (ROADMAP S4). Unhostable shapes/meshes (K or M not
+    # 128-aligned — the 1.5B C=1600 — sp/tp-sharded activations, decode's
+    # T=1 rows) fall back to the unfused composition, recorded via the
+    # `fused_fallback` metric.
     fused_matmul: str = "off"
     # Row-chunk size of the blocked CE ([rows, V] transient logits per
     # chunk). The default (DEFAULT_BLOCK_ROWS above — single source of
@@ -488,10 +490,10 @@ def parse_serve_mesh(mesh: str) -> tuple[int, int]:
     """Parse a serving mesh spec into ``(data, tp)`` degrees (``""`` ->
     (1, 1)).
 
-    Accepts ``"data:N[,tp:M]"`` (bench/CLI form) and ``"data=N[,tp=M]"``
+    Accepts ``"data:N[,tp:M]"`` (CLI form) and ``"data=N[,tp=M]"``
     (parallel/mesh.py MeshSpec form). Self-contained on purpose: config.py
-    stays importable without jax or the parallel package, so CLIs
-    (``scripts/bench_serve.py``) can validate mesh flags at parse time.
+    stays importable without jax or the parallel package, so the parent of
+    a worker fleet, which never loads jax, can refuse a bad mesh flag.
     """
     degrees = {"data": 1, "tp": 1}
     if not mesh:
@@ -532,8 +534,8 @@ def parse_serve_spec(spec: str) -> tuple[str | None, int]:
     required when the spec is non-empty: a draft model with no run
     length (or vice versa) is a configuration bug, not a default.
     Self-contained on purpose: config.py stays importable without jax,
-    so CLIs (``scripts/bench_serve.py``) can refuse a bad ``--spec_k``
-    or ``--draft_preset`` before any jax import.
+    so serve.py and frontend/server.py refuse a bad ``--spec_k`` or
+    ``--draft_preset`` before jax loads.
 
     The preset name is validated against :data:`MODEL_PRESETS` here; the
     draft-smaller-than-target check needs the *target* config and lives
@@ -594,7 +596,7 @@ PLACEMENTS = ("inprocess", "subprocess", "remote")
 
 def validate_worker_flags(p, args) -> None:
     """Parse-time validation of the ``--placement``/``--worker_*`` flag
-    family, shared by serve.py, server.py and bench_serve.py. jax-free on
+    family, shared by serve.py and frontend/server.py. jax-free on
     purpose (mirrors ``parse_serve_mesh``): a bad worker flag must be
     rejected before any CLI pays the jax import."""
     if args.placement not in PLACEMENTS:
@@ -657,9 +659,7 @@ def validate_worker_flags(p, args) -> None:
         p.error(f"--spec_k must be >= 1, got {spec_k}")
     draft = getattr(args, "draft_preset", None)
     if draft is None:
-        # bench_serve's --spec A/B supplies its own self-sliced draft, so
-        # --spec_k is honorable there without a preset.
-        if spec_k is not None and not getattr(args, "spec", False):
+        if spec_k is not None:
             p.error("--spec_k needs --draft_preset (speculation is opt-in "
                     "via the draft model)")
         if getattr(args, "draft_ckpt", None):

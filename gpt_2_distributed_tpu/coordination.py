@@ -126,25 +126,20 @@ class ConsensusBus:
     ``exchange(word)`` returns the pod-agreed word. Identity fast path when
     ``process_count() == 1``: no allgather is dispatched at all, so
     single-host behavior (and the CLI e2e suite) is bit-identical with the
-    bus in the loop. Overhead accounting (``last_exchange_ms`` /
-    ``total_exchange_ms`` / ``exchanges``) feeds bench.py's
-    ``consensus_overhead_ms`` record.
+    bus in the loop. Each exchange is a ``consensus_exchange`` span, which
+    is where its overhead is read.
     """
 
     def __init__(self) -> None:
         import jax
 
         self.process_count = jax.process_count()
-        self.exchanges = 0
-        self.last_exchange_ms = 0.0
-        self.total_exchange_ms = 0.0
 
     def exchange(self, word: int) -> int:
         # The span lives here (not at the call site) so every exchange — the
-        # step loop's, the epoch boundary's, bench.py's — lands in the trace
+        # step loop's, the epoch boundary's — lands in the trace
         # under one name, parented by whatever span the caller has open.
         with get_tracer().span("consensus_exchange", word=int(word)):
-            t0 = time.perf_counter()
             if word & ~_ALL_BITS:
                 raise ValueError(f"control word {word:#x} has unknown bits set")
             if self.process_count == 1:
@@ -157,14 +152,7 @@ class ConsensusBus:
                     np.asarray(word, np.int64)
                 )
                 agreed = or_reduce_words(np.ravel(gathered))
-            self.exchanges += 1
-            self.last_exchange_ms = (time.perf_counter() - t0) * 1e3
-            self.total_exchange_ms += self.last_exchange_ms
         return agreed
-
-    @property
-    def mean_exchange_ms(self) -> float:
-        return self.total_exchange_ms / self.exchanges if self.exchanges else 0.0
 
 
 # --- part 2: cross-host desync detector --------------------------------------

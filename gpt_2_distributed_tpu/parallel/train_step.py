@@ -391,33 +391,6 @@ def make_train_step(
     )
 
 
-def make_accum_step(
-    config: GPT2Config,
-    compute_dtype: jnp.dtype = jnp.bfloat16,
-    unroll_accum: bool = False,
-    accum_dtype: jnp.dtype | None = None,
-) -> Callable:
-    """Jitted forward+backward+accumulate+grad-norm with NO optimizer update.
-
-    ``(loss, grad_norm) = accum_step(params, x, y, rng, step_idx)`` — the
-    same accumulation HLO as the train step (grad_norm keeps the backward
-    alive against DCE), minus the AdamW update and state write-back. Exists
-    so bench.py can step-delta the update phase: ``update_ms = full-step ms −
-    this function's ms`` — the honest way to attribute the replicated-vs-
-    sharded update cost without a device trace. Params are NOT donated (the
-    caller reuses them across timing reps).
-    """
-    accumulate_grads = _make_accumulate_grads(
-        config, compute_dtype, unroll_accum, accum_dtype
-    )
-
-    def accum_step(params, x, y, rng, step_idx):
-        _, loss, grad_norm = accumulate_grads(params, x, y, rng, step_idx)
-        return loss, grad_norm
-
-    return jax.jit(accum_step)
-
-
 def make_eval_step(
     config: GPT2Config, compute_dtype: jnp.dtype = jnp.bfloat16
 ) -> Callable:

@@ -427,8 +427,8 @@ def parse_fault_spec(spec: str, flag: str) -> tuple[int, int | None]:
     """Parse ``"STEP"`` or ``"STEP:REPLICA"`` (the ``--inject_*`` flag
     grammar, mirroring ``--xla_profile_at``'s ``STEP[:N]``). Returns
     ``(step, replica)`` with ``replica=None`` meaning "first replica
-    stepped at/after STEP". Import-light on purpose: ``bench_serve``
-    validates these flags before jax loads."""
+    stepped at/after STEP". Import-light on purpose: ``serve.py`` and
+    ``frontend/server.py`` refuse a bad spec before jax loads."""
     parts = str(spec).split(":")
     if len(parts) > 2:
         raise ValueError(f"{flag}={spec!r}: expected STEP or STEP:REPLICA")
@@ -448,7 +448,7 @@ def parse_fault_spec(spec: str, flag: str) -> tuple[int, int | None]:
 
 class FaultInjector:
     """Deterministic fault injection for the serving fleet (tests and the
-    chaos bench — never constructed in production).
+    ``--inject_*`` flags — never constructed in production).
 
     The driver calls :meth:`tick(step, replica)` immediately before each
     replica's ``step()``, inside the containment wrapper and the watchdog

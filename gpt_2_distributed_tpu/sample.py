@@ -62,8 +62,8 @@ def build_argparser() -> argparse.ArgumentParser:
         "--decode_path", default="auto", choices=["auto", "cached", "reforward"],
         help="'cached' = KV-cache prefill+decode (wins at batch>=16 on v5e), "
         "'reforward' = full re-forward per token; 'auto' picks reforward "
-        "because this CLI always generates batch=1, below the measured "
-        "cache-path crossover (scripts/bench_decode.py)",
+        "because this CLI always generates batch=1, below the batch size "
+        "at which the cache path overtakes re-forwarding",
     )
     p.add_argument(
         "--stream", action="store_true",

@@ -1,13 +1,13 @@
 """Network-chaos harness: an in-path TCP proxy that breaks links on cue.
 
-``bench_serve.py --chaos_net`` (and ``tests/test_remote_fleet.py``) put
-one :class:`ChaosProxy` between the frontend and each remote worker, then
-injure the link mid-decode and assert the exactness contract holds: every
-stream finishes bit-identical to the in-process reference with zero
-re-emitted tokens. The proxy is deliberately dumb — it forwards bytes,
-never frames — because that is what a real network does: a partition or a
-mid-frame truncation does not respect message boundaries, and the framing
-layer (``rpc.py``) has to make the damage detectable.
+``tests/test_remote_fleet.py`` puts one :class:`ChaosProxy` between the
+frontend and each remote worker, then injures the link mid-decode and
+asserts the exactness contract holds: every stream finishes bit-identical
+to the in-process reference with zero re-emitted tokens. The proxy is
+deliberately dumb — it forwards bytes, never frames — because that is what
+a real network does: a partition or a mid-frame truncation does not
+respect message boundaries, and the framing layer (``rpc.py``) has to make
+the damage detectable.
 
 Injuries, matched to the failure taxonomy a cross-host fleet actually
 sees:

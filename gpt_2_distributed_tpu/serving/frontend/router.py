@@ -5,12 +5,12 @@ The continuous-batching engine is single-replica by construction (one KV
 pool, one decode program); serving heavy traffic means running several and
 deciding, per request, which one. Two forces pull on that decision:
 
-* **Prefix affinity.** BENCH_SERVE.json's shared-prefix record shows 91.8%
-  of prompt tokens served straight from a replica's prefix cache — but
-  only if the request lands on the replica that *has* the blocks. The
-  router probes every active replica's :class:`PrefixCache` with the
-  request's leading token blocks (``peek_run`` — a read that doesn't
-  touch LRU order or hit counters) and prefers the deepest hit. A
+* **Prefix affinity.** Prompt tokens whose blocks a replica's prefix
+  cache already holds skip prefill — but only if the request lands on the
+  replica that *has* the blocks. The router probes every active replica's
+  :class:`PrefixCache` with the request's leading token blocks
+  (``peek_run`` — a read that doesn't touch LRU order or hit counters) and
+  prefers the deepest hit. A
   hash-keyed *sticky map* (first-block token bytes -> last replica routed)
   covers the race where the prefix's first carrier is still prefilling
   (its blocks aren't registered yet) and the prefix-cache-off deployment,
@@ -19,7 +19,7 @@ deciding, per request, which one. Two forces pull on that decision:
   fall back to the replica with the fewest queued + in-flight requests
   (ties break to the lowest index, so routing is deterministic for a
   deterministic submit order). ``policy="round_robin"`` ignores both
-  signals — it exists as the control arm for the affinity benchmark.
+  signals — the control to compare affinity against.
 
 SLO-aware admission: with ``queue_slo_ms`` set, the router estimates the
 chosen replica's queue wait (queued requests x an EMA of recent request
@@ -87,7 +87,6 @@ class ReplicaRouter:
         ttft_slo_ms: float | None = None,
         queue_slo_ms: float | None = None,
         service_ms_prior: float = 100.0,
-        rid_start: int = 0,
     ):
         if replicas < 1:
             raise ValueError(f"replicas={replicas} must be >= 1")
@@ -119,9 +118,7 @@ class ReplicaRouter:
         self.migrated = 0           # requests moved off failed replicas
         self._sticky: dict[bytes, int] = {}
         self._rr_next = 0
-        # rid_start keeps rids distinct across routers sharing one trace
-        # (bench_serve's measured run vs its round_robin control).
-        self._next_rid = int(rid_start)
+        self._next_rid = 0
         # EMA of per-request wall time (submit -> finish), seeding the
         # queue-wait estimate before the first finish lands.
         self._ema_service_ms = float(service_ms_prior)

@@ -593,8 +593,10 @@ def main(argv: list[str] | None = None) -> None:
     if (args.ckpt is None) == (not args.init_random):
         p.error("exactly one of --ckpt / --init_random is required")
     from gpt_2_distributed_tpu.config import validate_worker_flags
+    from gpt_2_distributed_tpu.serving.serve import make_injector
 
     validate_worker_flags(p, args)
+    injector = make_injector(p, args)
     from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()
@@ -608,7 +610,6 @@ def main(argv: list[str] | None = None) -> None:
     from gpt_2_distributed_tpu.serving.serve import (
         build_serve_config,
         load_model,
-        make_injector,
         make_tracker,
         model_config_from_args,
         setup_observability,
@@ -685,7 +686,7 @@ def main(argv: list[str] | None = None) -> None:
         autoscale_every=args.autoscale_every,
         request_timeout_s=args.request_timeout_s,
         watchdog_timeout_s=args.watchdog_timeout_s,
-        injector=make_injector(p, args),
+        injector=injector,
     )
     server = FrontendServer(
         driver, host=args.host, port=args.port, model_name=args.model,

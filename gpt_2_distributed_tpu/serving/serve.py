@@ -146,7 +146,7 @@ def add_obs_flags(p: argparse.ArgumentParser) -> None:
 
 def add_placement_flags(p: argparse.ArgumentParser) -> None:
     """Replica placement + worker supervision flags, shared by the JSONL
-    CLI, the HTTP front end and the chaos bench. Validated jax-free via
+    CLI and the HTTP front end. Validated jax-free via
     ``config.validate_worker_flags``."""
     from gpt_2_distributed_tpu.config import PLACEMENTS
 
@@ -193,8 +193,7 @@ def add_placement_flags(p: argparse.ArgumentParser) -> None:
 
 
 def add_fault_flags(p: argparse.ArgumentParser) -> None:
-    """Fault-tolerance + fault-injection flags, shared with the front end
-    and the chaos bench."""
+    """Fault-tolerance + fault-injection flags, shared with the front end."""
     p.add_argument("--request_timeout_s", type=float, default=None,
                    help="per-request deadline from submission (queue wait "
                         "included); overdue requests are evicted with "
@@ -220,8 +219,8 @@ def add_fault_flags(p: argparse.ArgumentParser) -> None:
 
 def make_injector(p: argparse.ArgumentParser, args: argparse.Namespace):
     """Validate the fault flags; return a :class:`resilience.FaultInjector`
-    or None when no injection was asked for. Import-light (no jax) so
-    ``bench_serve`` can validate at parse time."""
+    or None when no injection was asked for. Import-light (no jax): a bad
+    fault flag is refused before jax loads."""
     from gpt_2_distributed_tpu.resilience import (
         FaultInjector,
         parse_fault_spec,
@@ -452,6 +451,7 @@ def main(argv: list[str] | None = None) -> None:
     from gpt_2_distributed_tpu.config import validate_worker_flags
 
     validate_worker_flags(p, args)
+    injector = make_injector(p, args)
     from gpt_2_distributed_tpu.compile_cache import ensure_compile_cache
 
     ensure_compile_cache()
@@ -551,7 +551,7 @@ def main(argv: list[str] | None = None) -> None:
                           xla_capture=xla_capture, preemption=handler,
                           request_timeout_s=args.request_timeout_s,
                           watchdog_timeout_s=args.watchdog_timeout_s,
-                          injector=make_injector(p, args))
+                          injector=injector)
 
     def on_token(req, tok):
         if args.stream:

@@ -369,9 +369,8 @@ def build_parser() -> argparse.ArgumentParser:
         help="fused Pallas layer-epilogue kernels (ops/fused_layer.py): 'ln' "
         "fuses residual+dropout+layernorm at the sublayer junctions, 'gelu' "
         "fuses the MLP's bias+GELU+dropout epilogue, 'all' both. Default "
-        "'off' until the marginal microbench (scripts/bench_fused.py) "
-        "confirms the win on-chip; unsupported shapes/meshes fall back to "
-        "the unfused path automatically",
+        "'off' until a benchmark cell confirms the win on-chip; unsupported "
+        "shapes/meshes fall back to the unfused path automatically",
     )
     p.add_argument(
         "--fused_matmul", default="off", choices=["off", "mlp", "proj", "all"],
@@ -381,7 +380,7 @@ def build_parser() -> argparse.ArgumentParser:
         "fuses the fc leg (matmul+bias+GELU+dropout), 'proj' the two proj "
         "legs (matmul+bias+residual+dropout), 'all' both plus the qkv leg. "
         "Composable with --fused_layers (fused_matmul wins on shared legs). "
-        "Default 'off' until scripts/bench_fused.py confirms the win "
+        "Default 'off' until a benchmark cell confirms the win "
         "on-chip; unsupported shapes/meshes fall back to the unfused path, "
         "counted in the fused_fallback metric",
     )
@@ -854,11 +853,17 @@ def main(argv: list[str] | None = None) -> None:
             if use_guard else None
         )
         # loss_scale is all-ones in production; --inject_nan_at swaps in
-        # nan_scale for one step (same shape/dtype, so no retrace).
+        # nan_scale for one step (same shape/dtype, so no retrace). Its NaN
+        # is written on the host, and only when asked for: made on the
+        # device it trips jax_debug_nans on a run that injects nothing.
         ones_scale = (
             jnp.ones((args.grad_accum_steps,), jnp.float32) if use_guard else None
         )
-        nan_scale = ones_scale.at[0].set(jnp.nan) if use_guard else None
+        nan_scale = None
+        if args.inject_nan_at:
+            poisoned = np.ones((args.grad_accum_steps,), np.float32)
+            poisoned[0] = np.nan
+            nan_scale = jnp.asarray(poisoned)
 
         # --- checkpoint lifecycle -------------------------------------------
         # One saver per run: async writes + commit protocol + retries + GC

@@ -3,10 +3,8 @@
 PERF_ANALYSIS.md §8: the unrolled (non-scan) 124M-class step at seq 1024
 with grad_accum=16 hits an XLA scheduling cliff — MFU collapses to ~18%
 versus ~50% at accum 12 or seq 2048 (the unrolled accumulation loop at that
-exact shape triggers a pathological schedule). The bench driver already
-sidesteps it when auto-picking (bench.py stops its accum ladder at 12);
-this module is the shared warning for users who select the cliff explicitly
-via ``train.py``/``bench.py`` flags.
+exact shape triggers a pathological schedule). This module is the warning
+for users who select the cliff explicitly via ``train.py`` flags.
 """
 
 from __future__ import annotations

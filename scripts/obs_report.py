@@ -367,8 +367,8 @@ def speculation_summary(records: list[dict[str, Any]]) -> dict[str, Any] | None:
     event (rid, drafted=k, accepted) per active row per speculative
     round, and the draft/verify spans already fold into the engine-step
     breakdown. The measured acceptance rate α here is what the expected
-    speedup model E[tokens/verify] = (1 − α^(k+1)) / (1 − α) plugs in
-    (PERF_ANALYSIS §21). None when the trace never speculated."""
+    speedup model E[tokens/verify] = (1 − α^(k+1)) / (1 − α) plugs in.
+    None when the trace never speculated."""
     evs = [r["attrs"] for r in records
            if r.get("ph") == "event" and r.get("name") == "spec_accept"]
     if not evs:
@@ -590,8 +590,8 @@ def main(argv: list[str] | None = None) -> int:
             _print_frontend(report, args.limit)
         else:
             print("no route/shed events in this trace — was the request "
-                  "routed through the front end (gpt2-tpu-frontend or "
-                  "bench_serve --duration) with --trace_dir?")
+                  "routed through the front end (gpt2-tpu-frontend) "
+                  "with --trace_dir?")
     if not any((report["train_steps"], report["engine_steps"], report["serving"])):
         print("no step spans or request events found — was tracing enabled?")
     return 0

@@ -289,8 +289,8 @@ def main():
             "AdamW update. The recorded operating point is **micro-batch 8,",
             "accum 8, remat=block with a BF16 accumulator carry (1.55 GiB,",
             "fits; reference precedent: its FSDP sums grads in bf16):",
-            "16.1k tok/s/chip, 42.6% MFU** (`python bench.py --model 774M`;",
-            "the suite's 774M@1024 row, accum_dtype recorded in-record).",
+            "16.1k tok/s/chip, 42.6% MFU** (round 5's training suite, since",
+            "deleted: its 774M@1024 row, accum_dtype recorded in-record).",
             "The fp32-carry torch-autocast-parity fallback is accum 1:",
             "14.9k tok/s, 39.4% MFU (`--accum_dtype fp32`). Boundary",
             "rows can diverge between the two compiles — the ATTACHED",

@@ -62,6 +62,49 @@ def forced_host_device_env(n_devices: int,
     return f(n_devices, extra)
 
 
+# The serving CLIs, each with the least it needs to get past argparse.
+SERVING_CLIS = {
+    "serve": ["-m", "gpt_2_distributed_tpu.serving.serve",
+              "--init_random", "--requests", "-"],
+    "frontend": ["-m", "gpt_2_distributed_tpu.serving.frontend.server",
+                 "--init_random"],
+    "worker": ["-m", "gpt_2_distributed_tpu.serving.frontend.worker",
+               "--init_random"],
+}
+
+
+@pytest.fixture()
+def run_jax_free(tmp_path):
+    """``run(*argv)`` starts ``python *argv`` with a poisoned ``jax`` first
+    on PYTHONPATH: whatever it printed and exited with, it did before jax
+    loaded."""
+    import subprocess
+    import sys
+
+    poison = tmp_path / "poison"
+    (poison / "jax").mkdir(parents=True)
+    (poison / "jax" / "__init__.py").write_text(
+        "raise ImportError('touched jax at parse time')\n"
+    )
+    env = dict(os.environ, PYTHONPATH=f"{poison}{os.pathsep}{REPO_ROOT}")
+
+    def run(*argv):
+        return subprocess.run(
+            [sys.executable, *argv], cwd=REPO_ROOT, env=env,
+            stdin=subprocess.DEVNULL, capture_output=True, text=True,
+            timeout=120,
+        )
+
+    return run
+
+
+@pytest.fixture()
+def run_cli_jax_free(run_jax_free):
+    """``run(cli, *flags)``: one of :data:`SERVING_CLIS` under
+    :func:`run_jax_free`."""
+    return lambda cli, *flags: run_jax_free(*SERVING_CLIS[cli], *flags)
+
+
 @pytest.fixture(scope="session")
 def shard_dir(tmp_path_factory):
     """Synthetic uint16 .bin shards shared across tests."""

@@ -38,19 +38,22 @@ def test_fresh_jit_is_counted_once_with_its_name():
     watch = compile_watch.install()
     assert watch is compile_watch.get_watch() and watch.installed
     fn = _fresh_program("once")
-    before = watch.summary()
+    # Counted from a mark on the clock, not over the whole list: the watch is
+    # the process's, and in a worker that has compiled MAX_EVENTS things the
+    # oldest fall off the list between two whole-list summaries.
+    mark = time.monotonic()
     fn(jnp.ones((5,), jnp.float32)).block_until_ready()
-    after_first = watch.summary()
+    after_first = watch.summary(after=mark)
     kinds = [e[1] for e in _named(watch, "watch_probe_once")]
     assert sorted(kinds) == ["compile", "lower", "trace"]
     compiled = [e for e in _named(watch, "watch_probe_once") if e[1] == "compile"]
     assert compiled[0][3] == "jit(watch_probe_once)" and compiled[0][2] > 0
-    assert after_first["programs"] >= before["programs"] + 1
-    assert after_first["seconds"] > before["seconds"]
+    assert after_first["programs"] >= 1
+    assert after_first["seconds"] > 0
     # the second call runs the program it has: nothing is counted
     fn(jnp.ones((5,), jnp.float32)).block_until_ready()
     assert len(_named(watch, "watch_probe_once")) == 3
-    assert watch.summary()["programs"] == after_first["programs"]
+    assert watch.summary(after=mark)["programs"] == after_first["programs"]
 
 
 def test_before_and_after_split_on_the_clock():

@@ -80,8 +80,6 @@ def test_consensus_bus_identity_single_process():
     # Identity: the agreed word IS the local word, no allgather dispatched.
     assert bus.exchange(word) == word
     assert bus.exchange(0) == 0
-    assert bus.exchanges == 2
-    assert bus.mean_exchange_ms >= 0.0
 
 
 def test_consensus_bus_rejects_unknown_bits():

@@ -12,9 +12,6 @@ from __future__ import annotations
 
 import http.client
 import json
-import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -40,9 +37,6 @@ from gpt_2_distributed_tpu.serving.frontend import (
     StepWatchdog,
 )
 from gpt_2_distributed_tpu.serving.frontend.server import FrontendServer
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(scope="module")
@@ -558,70 +552,19 @@ def test_clean_drain_exits_zero(tiny_params, tiny_config, capsys):
     assert "drained, exiting 0" in capsys.readouterr().err
 
 
-# ------------------------------------------------------------ bench CLI
+# ------------------------------------------------------------ fault flags
 
 
-def _poison(tmp_path):
-    (tmp_path / "jax").mkdir()
-    (tmp_path / "jax" / "__init__.py").write_text("raise ImportError('no')\n")
-    return str(tmp_path)
-
-
-def test_bench_serve_chaos_flags_rejected_jax_free(tmp_path):
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-
-    def run(*flags):
-        return subprocess.run(
-            [sys.executable, BENCH_SERVE, *flags],
-            cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-        )
-
-    for flags, named in (
-        (("--chaos", "--replicas", "1"), "--chaos"),
-        (("--chaos", "--duration", "1"), "--chaos"),
-        (("--chaos", "--baseline_only"), "--chaos"),
-        (("--inject_replica_fail_at", "0"), "STEP"),
-        (("--inject_replica_fail_at", "1:2:3"), "STEP"),
-        (("--inject_replica_fail_at", "5"), "fault injection"),
-        (("--chaos", "--inject_replica_hang_at", "5"),
-         "--watchdog_timeout_s"),
-        (("--chaos", "--request_timeout_s", "-1"), "--request_timeout_s"),
-    ):
-        r = run(*flags)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
-    r = run("--help")
-    assert r.returncode == 0
-    assert "--chaos" in r.stdout and "--inject_replica_fail_at" in r.stdout
-
-
-@pytest.mark.slow
-def test_bench_serve_chaos_end_to_end(tmp_path):
-    # The CI chaos record: kill replica 0 mid-run on a 2-replica fleet,
-    # assert the bench itself verified bit-parity and merged the record.
-    out = tmp_path / "bench_serve.json"
-    out.write_text('{"bench": "serve", "traces": {"original": {}}}\n')
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, BENCH_SERVE,
-         "--n_layer", "2", "--n_embd", "32", "--n_head", "2",
-         "--vocab_size", "257", "--seq_len", "64",
-         "--prompt_min", "4", "--prompt_max", "12",
-         "--new_min", "8", "--new_max", "16",
-         "--max_batch", "4", "--block_size", "8",
-         "--requests", "16", "--chaos", "--replicas", "2",
-         "--inject_replica_fail_at", "6:0",
-         "--json", str(out)],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=420,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads(r.stdout.strip().splitlines()[-1])["chaos"]
-    assert rec["chaos"]["replica_failures"] == 1
-    assert rec["chaos"]["migrated_streams"] >= 1
-    assert rec["chaos"]["re_emitted_tokens"] == 0
-    assert rec["chaos"]["streams_bit_identical"] is True
-    assert rec["reference"]["replica_failures"] == 0
-    merged = json.loads(out.read_text())
-    assert merged["traces"] == {"original": {}}     # preserved
-    assert merged["chaos"] == rec
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+@pytest.mark.parametrize("flags, named", [
+    (("--inject_replica_fail_at", "0"), "STEP must be >= 1"),
+    (("--inject_replica_fail_at", "1:2:3"), "STEP or STEP:REPLICA"),
+    (("--inject_replica_hang_at", "5"), "--watchdog_timeout_s"),
+    (("--request_timeout_s", "-1"), "--request_timeout_s"),
+], ids=" ".join)
+def test_fault_flags_rejected_jax_free(run_cli_jax_free, cli, flags, named):
+    # serve.make_injector (resilience.parse_fault_spec under it) runs with
+    # the other parse-time checks, before jax loads.
+    r = run_cli_jax_free(cli, *flags)
+    assert r.returncode == 2, r.stderr[-300:]
+    assert named in r.stderr, r.stderr[-300:]

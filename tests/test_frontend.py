@@ -41,7 +41,6 @@ from gpt_2_distributed_tpu.serving.frontend import (
 from gpt_2_distributed_tpu.serving.frontend.server import FrontendServer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(scope="module")
@@ -559,75 +558,22 @@ def test_router_retire_drains_parked_replica(tiny_params, tiny_config):
     assert len(router.engines) == 2
 
 
-# ------------------------------------------------------------ bench CLI
+# ------------------------------------------------------------ server CLI
 
 
-def _poison(tmp_path):
-    (tmp_path / "jax").mkdir()
-    (tmp_path / "jax" / "__init__.py").write_text("raise ImportError('no')\n")
-    return str(tmp_path)
-
-
-def _run_bench_serve(*flags, poison_jax_dir):
-    env = dict(os.environ,
-               PYTHONPATH=poison_jax_dir + os.pathsep + REPO)
-    return subprocess.run(
-        [sys.executable, BENCH_SERVE, *flags],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
-
-
-def test_bench_serve_frontend_flags_rejected_jax_free(tmp_path):
-    # Parse-time refusals for the front-door mode, before any jax import.
-    poison = _poison(tmp_path)
-    for flags, named in (
-        (("--ramp", "50"), "--ramp"),
-        (("--duration", "-1"), "--duration"),
-        (("--duration", "1", "--ramp", "0"), "--ramp"),
-        (("--duration", "1", "--baseline_only"), "baseline"),
-        (("--duration", "1", "--replicas", "0"), "--replicas"),
-        (("--duration", "1", "--replicas", "3", "--max_replicas", "2"),
-         "--max_replicas"),
-    ):
-        r = _run_bench_serve(*flags, poison_jax_dir=poison)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
-    r = _run_bench_serve("--help", poison_jax_dir=poison)
-    assert r.returncode == 0
-    assert "--duration" in r.stdout and "--ramp" in r.stdout
-
-
-@pytest.mark.slow
-def test_bench_serve_frontend_mode_end_to_end(tmp_path):
-    # Ramp-mode run on the tiny config: both the measured affinity run and
-    # the round_robin control complete, the affinity hit rate is strictly
-    # higher, and the record merges into an existing BENCH_SERVE.json
-    # without clobbering its traces.
-    out = tmp_path / "bench_serve.json"
-    out.write_text('{"bench": "serve", "traces": {"original": {}}}\n')
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, BENCH_SERVE,
-         "--n_layer", "2", "--n_embd", "32", "--n_head", "2",
-         "--vocab_size", "257", "--seq_len", "64",
-         "--prompt_min", "4", "--prompt_max", "12",
-         "--new_min", "4", "--new_max", "8",
-         "--max_batch", "4", "--block_size", "8",
-         "--shared_prefix_len", "16", "--shared_prefix_frac", "0.75",
-         "--duration", "2", "--rate", "5", "--ramp", "40",
-         "--replicas", "2", "--route", "affinity",
-         "--json", str(out)],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=420,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads(r.stdout.strip().splitlines()[-1])["frontend"]
-    assert rec["affinity"]["completed"] > 0
-    assert rec["affinity"]["tok_s"] > 0
-    assert (rec["affinity"]["prefix_cache_hit_rate"]
-            > rec["round_robin_control"]["prefix_cache_hit_rate"])
-    merged = json.loads(out.read_text())
-    assert merged["traces"] == {"original": {}}   # preserved
-    assert merged["frontend"] == rec
+@pytest.mark.parametrize("flags, named", [
+    (("--replicas", "0"), "replicas=0"),
+    (("--replicas", "3", "--max_replicas", "2"), "max_replicas=2"),
+    (("--queue_slo_ms", "0"), "queue_slo_ms"),
+], ids=" ".join)
+def test_server_cli_refuses_fleet_shape_jax_free(run_cli_jax_free, flags,
+                                                 named):
+    # The router's own refusals reach the user as argparse errors, and the
+    # parent of a worker fleet gets to them without jax and before it
+    # spawns a worker.
+    r = run_cli_jax_free("frontend", "--placement", "subprocess", *flags)
+    assert r.returncode == 2, r.stderr[-300:]
+    assert named in r.stderr, r.stderr[-300:]
 
 
 @pytest.mark.slow

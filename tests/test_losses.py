@@ -180,17 +180,6 @@ def test_config_loss_block_rows_threads_through(tiny_config, rng_np, monkeypatch
         GPT2Config(loss_block_rows=0)
 
 
-def test_bench_help_literal_matches_default_block_rows():
-    """bench.py's --loss_block_rows help hardcodes '1024' (importing the
-    constant there would drag jax into --help); keep it honest."""
-    from gpt_2_distributed_tpu.ops.losses import DEFAULT_BLOCK_ROWS
-
-    assert DEFAULT_BLOCK_ROWS == 1024, (
-        "DEFAULT_BLOCK_ROWS changed — update the literal in bench.py's "
-        "--loss_block_rows help string"
-    )
-
-
 def test_config_validates_impl_choices():
     import pytest
 

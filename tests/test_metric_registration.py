@@ -40,7 +40,6 @@ def production_files():
                 os.path.join(dirpath, f) for f in filenames
                 if f.endswith(".py")
             )
-    out.append(os.path.join(REPO, "bench.py"))
     return sorted(out)
 
 

@@ -51,7 +51,6 @@ from gpt_2_distributed_tpu.serving.frontend.worker import (
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(autouse=True)
@@ -742,19 +741,11 @@ def test_fail_host_last_resort_growth_lands_on_survivor():
 # ------------------------------------------------- jax-free flag checks
 
 
-def _poison(tmp_path):
-    (tmp_path / "jax").mkdir()
-    (tmp_path / "jax" / "__init__.py").write_text("raise ImportError('no')\n")
-    return str(tmp_path)
-
-
-def test_frontend_package_imports_jax_free(tmp_path):
+def test_frontend_package_imports_jax_free(run_jax_free):
     """The whole serving/frontend package — rpc, worker, router, driver,
     autoscale, server, netchaos — imports with jax poisoned: the worker
     CLI must bind its socket and the frontends must validate flags
     before any jax import."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
     code = (
         "import importlib, pkgutil\n"
         "import gpt_2_distributed_tpu.serving.frontend as fe\n"
@@ -764,8 +755,7 @@ def test_frontend_package_imports_jax_free(tmp_path):
         "    importlib.import_module(m)\n"
         "print('\\n'.join(mods))\n"
     )
-    r = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
-                       capture_output=True, text=True, timeout=120)
+    r = run_jax_free("-c", code)
     assert r.returncode == 0, r.stderr[-2000:]
     mods = r.stdout.split()
     for expected in ("netchaos", "rpc", "worker", "router", "driver",
@@ -774,86 +764,39 @@ def test_frontend_package_imports_jax_free(tmp_path):
                                                                mods)
 
 
-def test_new_fleet_flags_rejected_jax_free_all_three_clis(tmp_path):
-    """Every NEW cross-host flag is validated before the jax import, in
-    all three CLIs that share validate_worker_flags."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-    missing = str(tmp_path / "nonexistent")
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+@pytest.mark.parametrize("flags, named", [
+    (("--placement", "subprocess", "--worker_heartbeat_timeout_s", "0"),
+     "--worker_heartbeat_timeout_s"),
+    (("--placement", "subprocess", "--worker_heartbeat_timeout_s", "-2"),
+     "--worker_heartbeat_timeout_s"),
+    (("--placement", "subprocess", "--worker_auth_token_file", "{missing}"),
+     "--worker_auth_token_file"),
+    (("--placement", "subprocess", "--worker_auth_token_file", "{empty}"),
+     "--worker_auth_token_file"),
+    (("--placement", "remote"), "--worker_pool"),
+    (("--placement", "remote", "--worker_pool", "{missing}"),
+     "--worker_pool"),
+    (("--placement", "subprocess", "--worker_pool", "{pool}"),
+     "--worker_pool"),
+], ids=" ".join)
+def test_fleet_flags_rejected_jax_free(run_cli_jax_free, tmp_path, cli,
+                                       flags, named):
+    """Every cross-host flag is validated before the jax import, in both
+    CLIs that share validate_worker_flags."""
     empty = tmp_path / "empty_token"
     empty.write_text(" \n")
     pool = tmp_path / "pool"
     pool.write_text("h0 tcp://127.0.0.1:9000\n")
-
-    clis = {
-        "serve": [sys.executable, "-m",
-                  "gpt_2_distributed_tpu.serving.serve",
-                  "--init_random", "--requests", "-"],
-        "frontend": [sys.executable, "-m",
-                     "gpt_2_distributed_tpu.serving.frontend.server",
-                     "--init_random"],
-        "bench": [sys.executable, BENCH_SERVE, "--chaos"],
-    }
-    bad = (
-        (("--placement", "subprocess",
-          "--worker_heartbeat_timeout_s", "0"),
-         "--worker_heartbeat_timeout_s"),
-        (("--placement", "subprocess",
-          "--worker_heartbeat_timeout_s", "-2"),
-         "--worker_heartbeat_timeout_s"),
-        (("--placement", "subprocess",
-          "--worker_auth_token_file", missing),
-         "--worker_auth_token_file"),
-        (("--placement", "subprocess",
-          "--worker_auth_token_file", str(empty)),
-         "--worker_auth_token_file"),
-        (("--placement", "remote"), "--worker_pool"),
-        (("--placement", "remote", "--worker_pool", missing),
-         "--worker_pool"),
-        (("--placement", "subprocess", "--worker_pool", str(pool)),
-         "--worker_pool"),
-    )
-    for name, argv in clis.items():
-        for flags, named in bad:
-            r = subprocess.run(argv + list(flags), cwd=REPO, env=env,
-                               capture_output=True, text=True, timeout=120)
-            assert r.returncode != 0, (name, flags)
-            assert named in r.stderr, (name, flags, r.stderr[-300:])
+    files = {"missing": tmp_path / "nonexistent", "empty": empty,
+             "pool": pool}
+    r = run_cli_jax_free(cli, *(f.format(**files) for f in flags))
+    assert r.returncode != 0
+    assert named in r.stderr, r.stderr[-300:]
 
 
-def test_chaos_net_flag_rules_rejected_jax_free(tmp_path):
-    """--chaos_net provisions its own fleet: it refuses to combine with
-    process-chaos kills or an explicit placement, and requires --chaos —
-    all at parse time with jax poisoned."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-    bad = (
-        (("--chaos_net", "partition"), "--chaos"),
-        (("--chaos", "--chaos_net", "bogus"), "--chaos_net"),
-        (("--chaos", "--chaos_net", "partition",
-          "--chaos_kill", "sigkill"), "--chaos_kill"),
-        (("--chaos", "--chaos_net", "torn",
-          "--placement", "subprocess"), "--placement"),
-        (("--chaos", "--chaos_net", "slow",
-          "--placement", "remote"), "--placement"),
-    )
-    for flags, named in bad:
-        r = subprocess.run([sys.executable, BENCH_SERVE, *flags], cwd=REPO,
-                           env=env, capture_output=True, text=True,
-                           timeout=120)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
-
-
-def test_worker_cli_rejects_bad_socket_spec_jax_free(tmp_path):
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-    r = subprocess.run(
-        [sys.executable, "-m",
-         "gpt_2_distributed_tpu.serving.frontend.worker",
-         "--init_random", "--socket", "tcp://nohost"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=120,
-    )
+def test_worker_cli_rejects_bad_socket_spec_jax_free(run_cli_jax_free):
+    r = run_cli_jax_free("worker", "--socket", "tcp://nohost")
     assert r.returncode != 0
     assert "tcp://" in r.stderr
 

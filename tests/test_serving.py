@@ -1,6 +1,6 @@
 """Serving subsystem: block allocator units, engine bit-parity against
 ``generate_cached``, compile-once across admission/eviction churn, EOS
-eviction, padding edges, streaming, and the bench_serve CLI contract.
+eviction, padding edges, streaming, and the serving CLIs' flag contract.
 
 The exactness bar is deliberately BIT-equality, not allclose: the decode
 step mirrors ``decode.decode_step`` op-for-op with batch a parallel dim
@@ -31,7 +31,6 @@ from gpt_2_distributed_tpu.serving import (
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(scope="module")
@@ -636,123 +635,40 @@ def test_pool_garbage_is_invisible_under_chunked_prefill(
         assert got == ref
 
 
-# ------------------------------------------------------ bench_serve CLI
+# -------------------------------------------------------- serving CLIs
 
 
-def _run_bench_serve(*argv, poison_jax_dir=None, timeout=120):
-    env = dict(os.environ)
-    if poison_jax_dir is not None:
-        env["PYTHONPATH"] = (
-            poison_jax_dir + os.pathsep + env.get("PYTHONPATH", "")
-        )
-    return subprocess.run(
-        [sys.executable, BENCH_SERVE, *argv], cwd=REPO, env=env,
-        capture_output=True, text=True, timeout=timeout,
-    )
-
-
-def _poison(tmp_path):
-    d = tmp_path / "poison"
-    d.mkdir()
-    (d / "jax.py").write_text(
-        "raise ImportError('bench_serve touched jax at parse time')"
-    )
-    return str(d)
-
-
-def test_bench_serve_help_is_jax_free(tmp_path):
-    r = _run_bench_serve("--help", poison_jax_dir=_poison(tmp_path))
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+def test_cli_help_is_jax_free(run_cli_jax_free, cli):
+    r = run_cli_jax_free(cli, "--help")
     assert r.returncode == 0, r.stderr[-500:]
-    assert "--rate" in r.stdout
-    assert "--shared_prefix_frac" in r.stdout
-    assert "--admission" in r.stdout
+    for flag in ("--prefill_chunk", "--admission", "--serve_mesh",
+                 "--placement", "--inject_replica_fail_at", "--spec_k"):
+        assert flag in r.stdout, flag
 
 
-def test_bench_serve_rejects_unhonorable_flags(tmp_path):
-    # Parse-time refusals, before any jax import (bench.py's --suite
-    # pattern): contradictions and impossible traces fail fast and name
-    # the flag.
-    poison = _poison(tmp_path)
-    for flags, named in (
-        (("--baseline_only", "--no_baseline"), "--baseline_only"),
-        (("--requests", "0"), "--requests"),
-        (("--rate", "0"), "--rate"),
-        (("--prompt_min", "0"), "--prompt_min"),
-        (("--new_min", "9", "--new_max", "3"), "--new_min"),
-        (("--shared_prefix_frac", "1.5"), "--shared_prefix_frac"),
-        (("--traces", "shared_prefix", "--shared_prefix_len", "0"),
-         "--shared_prefix_len"),
-        (("--num_blocks_shared", "-1"), "--num_blocks_shared"),
-        (("--prefill_chunk", "-1"), "--prefill_chunk"),
-        (("--watermark_blocks", "-1"), "--watermark_blocks"),
-        (("--repeats", "0"), "--repeats"),
-        (("--prefill_batch", "0"), "--prefill_batch"),
-        # mesh specs are validated jax-free via config.parse_serve_mesh
-        (("--serve_mesh", "fsdp:2"), "--serve_mesh"),
-        (("--serve_mesh", "data:1"), "--serve_mesh"),
-        (("--serve_mesh", "data:2", "--chaos"), "--serve_mesh"),
-    ):
-        r = _run_bench_serve(*flags, poison_jax_dir=poison)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
-
-
-def test_bench_serve_rejects_trace_exceeding_context(capsys):
-    # This refusal needs the model config (n_positions), so it runs after
-    # the jax import — exercise it in-process to keep it cheap.
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench_serve", BENCH_SERVE)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    with pytest.raises(SystemExit):
-        mod.main(["--seq_len", "64", "--prompt_max", "40",
-                  "--new_max", "40"])
-    assert "n_positions" in capsys.readouterr().err
-    # The shared-prefix trace lengthens prompts to prefix+1: the fit check
-    # must account for that, not just --prompt_max.
-    with pytest.raises(SystemExit):
-        mod.main(["--seq_len", "64", "--traces", "shared_prefix",
-                  "--shared_prefix_len", "60"])
-    assert "n_positions" in capsys.readouterr().err
-
-
-@pytest.mark.slow
-def test_bench_serve_end_to_end(tmp_path):
-    # Both traces on the tiny config, one repeat: engine + PR 7 replay +
-    # one-shot baseline per trace, JSON artifact written, and the streams
-    # bit-identical across the two scheduler configurations.
-    out = tmp_path / "bench_serve.json"
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, BENCH_SERVE,
-         "--n_layer", "2", "--n_embd", "32", "--n_head", "2",
-         "--vocab_size", "257", "--seq_len", "64",
-         "--requests", "8", "--prompt_min", "2", "--prompt_max", "10",
-         "--new_min", "4", "--new_max", "10",
-         "--max_batch", "4", "--block_size", "8",
-         "--traces", "both", "--shared_prefix_len", "8",
-         "--num_blocks_shared", "12", "--repeats", "1",
-         "--json", str(out)],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=420,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    for name in ("original", "shared_prefix"):
-        sec = rec["traces"][name]
-        assert sec["engine"]["tok_s"] > 0, name
-        assert sec["engine"]["decode_steps"] > 0, name
-        assert sec["streams_bit_identical"] is True, name
-        assert sec["speedup_vs_pr7"] > 0, name
-        assert sec["oneshot_baseline"]["tok_s"] > 0, name
-        assert sec["speedup_vs_oneshot"] > 0, name
-    # The shared trace shares a full block per prefixed prompt, so the
-    # engine-under-test (prefix cache on) must report hits; the PR 7
-    # replay (cache off) must not.
-    shared = rec["traces"]["shared_prefix"]
-    assert shared["engine"]["prefix_cache_hit_rate"] > 0
-    assert shared["engine_pr7"]["prefix_cache_hit_rate"] == 0
-    assert json.loads(out.read_text()) == rec
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+@pytest.mark.parametrize("flags, named", [
+    (("--prefill_chunk", "-1"), "prefill_chunk=-1"),
+    (("--watermark_blocks", "-1"), "watermark_blocks=-1"),
+    (("--prefill_batch", "0"), "prefill_batch=0"),
+    # mesh specs go through config.parse_serve_mesh
+    (("--serve_mesh", "fsdp:2"), "unknown axis 'fsdp'"),
+    (("--serve_mesh", "data:0"), "degree must be >= 1"),
+], ids=" ".join)
+def test_cli_fleet_parent_refuses_engine_flags_jax_free(
+    run_cli_jax_free, tmp_path, cli, flags, named
+):
+    """The parent of a worker fleet builds its ServeConfig, whose ranges
+    and mesh grammar refuse these, before it spawns a worker — and never
+    loads jax (the poisoned one would stop it first)."""
+    requests = tmp_path / "requests.jsonl"
+    requests.write_text('{"prompt_ids": [1, 2, 3]}\n')
+    r = run_cli_jax_free(cli, "--placement", "subprocess", *flags,
+                         *(("--requests", str(requests))
+                           if cli == "serve" else ()))
+    assert r.returncode != 0
+    assert named in r.stderr, r.stderr[-300:]
 
 
 @pytest.mark.slow

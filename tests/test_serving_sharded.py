@@ -319,41 +319,3 @@ def test_submit_rejects_over_shard_capacity(tiny_params, tiny_config):
     with pytest.raises(ValueError, match="data shard"):
         eng.submit(list(range(1, 33)), 32)
     eng.submit(list(range(1, 17)), 8)  # 3 blocks: fits one shard
-
-
-@pytest.mark.slow
-def test_bench_serve_sharded_record(tmp_path):
-    """scripts/bench_serve.py --serve_mesh end to end on 8 forced host
-    devices: the merged 'sharded' record must certify bit-identical
-    streams and the >=2x concurrent-slot capacity win at matched
-    per-device pool bytes."""
-    import json
-    import subprocess
-    import sys
-
-    from conftest import REPO_ROOT, forced_host_device_env
-
-    out = tmp_path / "bench.json"
-    out.write_text('{"bench": "serve", "keep": 1}\n')  # merge, not clobber
-    r = subprocess.run(
-        [sys.executable, "scripts/bench_serve.py",
-         "--n_layer", "2", "--n_embd", "32", "--n_head", "2",
-         "--vocab_size", "257", "--seq_len", "64",
-         "--requests", "8", "--prompt_min", "2", "--prompt_max", "10",
-         "--new_min", "4", "--new_max", "10",
-         "--max_batch", "2", "--block_size", "8",
-         "--serve_mesh", "data:2,tp:2", "--repeats", "1",
-         "--json", str(out)],
-        cwd=REPO_ROOT, env=forced_host_device_env(8),
-        capture_output=True, text=True, timeout=420,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    rec = json.loads(out.read_text())
-    assert rec["keep"] == 1                      # merge preserved the file
-    s = rec["sharded"]
-    assert s["streams_bit_identical"] is True
-    assert s["slot_capacity_ratio"] >= 2.0
-    assert (s["single"]["kv_pool_bytes_per_device"]
-            == s["sharded"]["kv_pool_bytes_per_device"])
-    assert s["sharded"]["concurrent_slots"] == 2 * s["single"]["concurrent_slots"]
-    assert s["devices"] == 4 and s["data"] == 2 and s["tp"] == 2

@@ -13,10 +13,6 @@ family is refused jax-free at parse time on all three CLIs.
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -35,9 +31,6 @@ from gpt_2_distributed_tpu.serving.engine import (
 from gpt_2_distributed_tpu.serving.paged_cache import draft_serve_view
 
 from test_serving import _oneshot, _serve
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(scope="module")
@@ -248,13 +241,15 @@ def test_greedy_bit_equality_sharded(tiny_params, tiny_config, draft,
 
 
 def test_migration_during_speculation_across_mesh_shapes(
-    tiny_params, tiny_config, draft, prompts, greedy_refs
+    tiny_params, tiny_config, draft, prompts
 ):
     """extract_inflight mid-speculation on a data:2 engine, adopt into a
     data:2,tp:2 engine: draft KV is disposable — the adopting engine
     re-drafts from the committed stream — so every stream completes
     bit-identically with zero re-emitted tokens and no wire-format
     change."""
+    import jax
+
     serve_a = _serve(max_batch=4, num_blocks=64, mesh="data:2",
                      spec="draft:124M,k:3")
     serve_b = _serve(max_batch=4, num_blocks=64, mesh="data:2,tp:2",
@@ -266,22 +261,25 @@ def test_migration_during_speculation_across_mesh_shapes(
     def on_token(req, tok):
         streams.setdefault(req.id, []).append(tok)
 
-    hs = [eng_a.submit(p, 8, rng=i, on_token=on_token)
+    # k=3 emits up to 4 tokens a round: 24 new tokens outlast the three
+    # steps before the extraction, where the shared refs' 8 do not.
+    new = 24
+    hs = [eng_a.submit(p, new, rng=i, on_token=on_token)
           for i, p in enumerate(prompts)]
     for _ in range(3):                   # prefills + at least one round
         eng_a.step()
     moved = eng_a.extract_inflight()
-    # k=3 emits up to 4 tokens per round, so a short request may already
-    # be done — everything still in flight must move, mid-stream.
     assert moved, "nothing in flight to migrate"
     assert len(moved) == sum(1 for h in hs if not h.done)
-    assert any(0 < len(h.generated) < 8 for h in hs)
+    assert any(0 < len(h.generated) < new for h in hs)
     eng_b = _spec_engine(tiny_params, tiny_config, serve_b, draft,
                          temperature=0.0)
     for req in moved:
         eng_b.adopt(req)
     eng_b.run_until_idle(max_steps=500)
-    for h, ref in zip(hs, greedy_refs):
+    for i, (h, p) in enumerate(zip(hs, prompts)):
+        ref = _oneshot(tiny_params, tiny_config, p, jax.random.PRNGKey(i),
+                       new, temperature=0.0)
         assert h.generated == ref
         assert streams[h.id] == h.generated   # no re-emits, no gaps
 
@@ -496,76 +494,19 @@ def test_metrics_snapshot_carries_spec_keys(tiny_params, tiny_config,
 # ------------------------------------------- jax-free CLI refusals
 
 
-def _poison(tmp_path):
-    (tmp_path / "jax").mkdir()
-    (tmp_path / "jax" / "__init__.py").write_text("raise ImportError('no')\n")
-    return str(tmp_path)
-
-
-def test_spec_flags_rejected_jax_free_all_three_clis(tmp_path):
-    """serve.py, frontend/server.py and bench_serve.py refuse bad
-    speculation flags at parse time with jax poisoned on PYTHONPATH:
-    the draft-flag family is validated by config.validate_worker_flags,
-    which imports no jax."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-    clis = {
-        "serve": [sys.executable, "-m",
-                  "gpt_2_distributed_tpu.serving.serve",
-                  "--init_random", "--requests", "-"],
-        "frontend": [sys.executable, "-m",
-                     "gpt_2_distributed_tpu.serving.frontend.server",
-                     "--init_random"],
-        "bench": [sys.executable, BENCH_SERVE],
-    }
-    bad = (
-        (("--draft_preset", "124M", "--spec_k", "0"), "--spec_k"),
-        (("--spec_k", "2"), "--draft_preset"),    # speculation is opt-in
-        (("--draft_preset", "bogus"), "--draft_preset"),
-        # draft must be strictly smaller than the (default 124M) target
-        (("--draft_preset", "124M"), "--draft_preset"),
-    )
-    for name, argv in clis.items():
-        for flags, named in bad:
-            r = subprocess.run(argv + list(flags), cwd=REPO, env=env,
-                               capture_output=True, text=True, timeout=120)
-            assert r.returncode != 0, (name, flags)
-            assert named in r.stderr, (name, flags, r.stderr[-300:])
-    # serve/frontend only: --draft_ckpt rides on --draft_preset
-    for name in ("serve", "frontend"):
-        r = subprocess.run(clis[name] + ["--draft_ckpt", "ckpt"],
-                           cwd=REPO, env=env, capture_output=True,
-                           text=True, timeout=120)
-        assert r.returncode != 0, name
-        assert "--draft_preset" in r.stderr, (name, r.stderr[-300:])
-
-
-def test_bench_spec_flags_rejected_jax_free(tmp_path):
-    """Bench-only speculation refusals: mode combos and the self-slice
-    depth, all before any jax import."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-    bad = (
-        (("--spec", "--serve_mesh", "data:2"), "--spec"),
-        (("--spec", "--chaos"), "--spec"),
-        (("--spec", "--temperature", "1.0"), "--spec"),
-        (("--spec", "--spec_draft_layers", "0"), "--spec_draft_layers"),
-        (("--spec", "--spec_draft_layers", "12"), "--spec_draft_layers"),
-        (("--spec_draft_layers", "1"), "--spec_draft_layers"),
-        (("--spec", "--draft_preset", "124M",
-          "--spec_draft_layers", "1"), "--spec_draft_layers"),
-    )
-    for flags, named in bad:
-        r = subprocess.run([sys.executable, BENCH_SERVE, *flags],
-                           cwd=REPO, env=env, capture_output=True,
-                           text=True, timeout=120)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
-    # and the flags are visible jax-free
-    r = subprocess.run([sys.executable, BENCH_SERVE, "--help"],
-                       cwd=REPO, env=env, capture_output=True, text=True,
-                       timeout=120)
-    assert r.returncode == 0, r.stderr[-500:]
-    for flag in ("--spec", "--draft_preset", "--spec_k",
-                 "--spec_draft_layers"):
-        assert flag in r.stdout, flag
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+@pytest.mark.parametrize("flags, named", [
+    (("--draft_preset", "124M", "--spec_k", "0"), "--spec_k"),
+    (("--spec_k", "2"), "--draft_preset"),    # speculation is opt-in
+    (("--draft_preset", "bogus"), "--draft_preset"),
+    # draft must be strictly smaller than the (default 124M) target
+    (("--draft_preset", "124M"), "--draft_preset"),
+    (("--draft_ckpt", "ckpt"), "--draft_preset"),   # rides on the preset
+], ids=" ".join)
+def test_spec_flags_rejected_jax_free(run_cli_jax_free, cli, flags, named):
+    """serve.py and frontend/server.py refuse bad speculation flags at
+    parse time with jax poisoned on PYTHONPATH: the draft-flag family is
+    validated by config.validate_worker_flags, which imports no jax."""
+    r = run_cli_jax_free(cli, *flags)
+    assert r.returncode != 0
+    assert named in r.stderr, r.stderr[-300:]

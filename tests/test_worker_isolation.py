@@ -13,11 +13,9 @@ never touches jax, and the respawn budget gives up like supervise.sh.
 from __future__ import annotations
 
 import json
-import os
 import signal
 import socket
 import struct
-import subprocess
 import sys
 import time
 
@@ -35,9 +33,6 @@ from gpt_2_distributed_tpu.serving.frontend.worker import (
     WorkerSpawner,
     spawner_from_args,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-BENCH_SERVE = os.path.join(REPO, "scripts", "bench_serve.py")
 
 
 @pytest.fixture(autouse=True)
@@ -163,57 +158,27 @@ def test_request_wire_version_rejected():
 # ------------------------------------------------- jax-free flag checks
 
 
-def _poison(tmp_path):
-    (tmp_path / "jax").mkdir()
-    (tmp_path / "jax" / "__init__.py").write_text("raise ImportError('no')\n")
-    return str(tmp_path)
-
-
-def test_worker_flags_rejected_jax_free_all_three_clis(tmp_path):
-    """All three CLIs refuse bad placement/worker flags at parse time,
-    with a poisoned jax on PYTHONPATH proving validation never pays the
-    jax import."""
-    poison = _poison(tmp_path)
-    env = dict(os.environ, PYTHONPATH=poison + os.pathsep + REPO)
-
-    clis = {
-        "serve": [sys.executable, "-m", "gpt_2_distributed_tpu.serving.serve",
-                  "--init_random", "--requests", "-"],
-        "frontend": [sys.executable, "-m",
-                     "gpt_2_distributed_tpu.serving.frontend.server",
-                     "--init_random"],
-        "bench": [sys.executable, BENCH_SERVE, "--chaos"],
-    }
-    bad = (
-        (("--placement", "bogus"), "--placement"),
-        (("--placement", "subprocess", "--worker_max_respawns", "-1"),
-         "--worker_max_respawns"),
-        (("--placement", "subprocess", "--worker_respawn_backoff_s", "-1"),
-         "--worker_respawn_backoff_s"),
-        (("--placement", "subprocess", "--worker_rpc_timeout_s", "0"),
-         "--worker_rpc_timeout_s"),
-        (("--placement", "subprocess", "--worker_heartbeat_s", "0"),
-         "--worker_heartbeat_s"),
-        (("--placement", "subprocess", "--worker_connect_timeout_s", "0"),
-         "--worker_connect_timeout_s"),
-    )
-    for name, argv in clis.items():
-        for flags, named in bad:
-            r = subprocess.run(argv + list(flags), cwd=REPO, env=env,
-                               capture_output=True, text=True, timeout=120)
-            assert r.returncode != 0, (name, flags)
-            assert named in r.stderr, (name, flags, r.stderr[-300:])
-    # Bench-only refusals: real signals need a subprocess, subprocess
-    # placement in the bench is chaos-only.
-    for flags, named in (
-        (("--chaos", "--chaos_kill", "sigkill"), "--placement"),
-        (("--placement", "subprocess"), "--chaos"),
-    ):
-        r = subprocess.run([sys.executable, BENCH_SERVE, *flags], cwd=REPO,
-                           env=env, capture_output=True, text=True,
-                           timeout=120)
-        assert r.returncode != 0, flags
-        assert named in r.stderr, (flags, r.stderr[-300:])
+@pytest.mark.parametrize("cli", ["serve", "frontend"])
+@pytest.mark.parametrize("flags, named", [
+    (("--placement", "bogus"), "--placement"),
+    (("--placement", "subprocess", "--worker_max_respawns", "-1"),
+     "--worker_max_respawns"),
+    (("--placement", "subprocess", "--worker_respawn_backoff_s", "-1"),
+     "--worker_respawn_backoff_s"),
+    (("--placement", "subprocess", "--worker_rpc_timeout_s", "0"),
+     "--worker_rpc_timeout_s"),
+    (("--placement", "subprocess", "--worker_heartbeat_s", "0"),
+     "--worker_heartbeat_s"),
+    (("--placement", "subprocess", "--worker_connect_timeout_s", "0"),
+     "--worker_connect_timeout_s"),
+], ids=" ".join)
+def test_worker_flags_rejected_jax_free(run_cli_jax_free, cli, flags, named):
+    """Both CLIs refuse bad placement/worker flags at parse time, with a
+    poisoned jax on PYTHONPATH proving validation never pays the jax
+    import."""
+    r = run_cli_jax_free(cli, *flags)
+    assert r.returncode != 0
+    assert named in r.stderr, r.stderr[-300:]
 
 
 def test_validate_worker_flags_accepts_defaults():
