@@ -258,6 +258,7 @@ for _name, _dist in (
     ("watchdog_trips", "sum"),         # cumulative step-watchdog firings
     ("serve_mesh_devices", "max"),     # devices across the fleet's serving meshes
     ("kv_pool_bytes_per_device", "max"),  # largest per-device KV pool footprint
+    ("weight_bytes", "max"),           # per-device bytes of the weights an engine holds
     ("prefill_batched", "sum"),        # cumulative extra rows batched into prefills
     ("worker_restarts", "sum"),        # cumulative replacement worker respawns
     ("host_failures", "sum"),          # cumulative whole-host domains lost

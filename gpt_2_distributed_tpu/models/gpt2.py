@@ -32,6 +32,7 @@ Reference compute graph being reproduced (``/root/reference/model.py``):
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -130,6 +131,44 @@ def init_params(
 
 def count_params(params: Params) -> int:
     return sum(x.size for x in jax.tree_util.tree_leaves(params))
+
+
+# The block leaves every forward casts whole, `bp[name].astype(cdt)`, at
+# their one use. The LayerNorm leaves are read as float32
+# (`ops/layers.layer_norm`) and are not among them.
+_MATMUL_LEAVES = (
+    "attn_qkv_w", "attn_qkv_b", "attn_proj_w", "attn_proj_b",
+    "mlp_fc_w", "mlp_fc_b", "mlp_proj_w", "mlp_proj_b",
+)
+
+
+@functools.partial(jax.jit, static_argnames="dtype")
+def _cast_weights(tree, dtype):
+    return jax.tree_util.tree_map(lambda a: a.astype(dtype), tree)
+
+
+def serving_weights(params: Params, dtype) -> Params:
+    """``params`` as a server holds them: the embeddings and every matmul
+    leaf of the blocks in ``dtype``, cast once; the LayerNorm leaves as given.
+
+    Weights do not change between a server's steps, and the forwards take
+    ``leaf.astype(compute_dtype)`` at every use: on a float32 tree XLA hoists
+    those casts out of the layer scan and runs them whole on every call
+    (1.5B: 6.2 GB read and 3.1 GB written a decode step). On this tree they
+    are no-ops, and the values are the same, ``bf16(w)`` either way.
+
+    Same structure and shapes. A leaf already in ``dtype`` is the caller's
+    own array; the rest come from one jitted call that donates nothing, so
+    the tree passed in stays whole and alive with its caller.
+    """
+    dtype = jnp.dtype(dtype)
+    block = params["block"]
+    top = {k: params[k] for k in ("wte", "wpe") if params[k].dtype != dtype}
+    inner = {k: block[k] for k in _MATMUL_LEAVES if block[k].dtype != dtype}
+    if not top and not inner:
+        return params
+    top, inner = _cast_weights((top, inner), dtype)
+    return {**params, **top, "block": {**block, **inner}}
 
 
 def qkv_proj(

@@ -93,6 +93,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
+import math
 import time
 from typing import Callable, Sequence
 
@@ -138,6 +139,10 @@ from gpt_2_distributed_tpu.serving.step_clocks import step_clocks
 
 # Whoever builds the engine's programs has the compile watch first.
 compile_watch.install()
+
+# What an engine computes in unless told otherwise, and so what the CLIs'
+# loaders (`serve.load_model`) cast a tree to before they hand it over.
+DEFAULT_COMPUTE_DTYPE = jnp.bfloat16
 
 
 def _program(name: str, impl: Callable, **static) -> Callable:
@@ -748,6 +753,14 @@ def _spec_accept(
 class ServingEngine:
     """Continuous-batching serving engine. See the module docstring.
 
+    What it holds: ``params`` (and ``draft_params``) of a GPT-2 model are
+    ``gpt2.serving_weights(params, compute_dtype)`` - the embeddings and
+    every matmul leaf in ``compute_dtype``, cast once here, the LayerNorm
+    leaves float32 - and never the float32 tree passed in, which stays its
+    caller's, whole, to keep or drop. The step programs still take either
+    (their ``.astype`` is a no-op on a leaf already cast); the engine hands
+    them only what it holds. A ``SalaConfig``'s tree is held as given.
+
     Typical loop::
 
         eng = ServingEngine(params, config, ServeConfig(max_batch=8))
@@ -765,7 +778,7 @@ class ServingEngine:
         *,
         temperature: float = 0.0,
         top_k: int | None = None,
-        compute_dtype=jnp.bfloat16,
+        compute_dtype=DEFAULT_COMPUTE_DTYPE,
         draft_params=None,
         draft_config: GPT2Config | None = None,
     ):
@@ -825,14 +838,21 @@ class ServingEngine:
                 "speculation is opt-in via ServeConfig.spec "
                 "('draft:<preset>,k:<K>')"
             )
-        self.draft_params = draft_params
         self.draft_config = draft_config
-        self.params = params
         self.config = config
         self.serve = serve
         self.temperature = float(temperature)
         self.top_k = top_k
         self.compute_dtype = compute_dtype
+        # What the engine holds is what its programs multiply by. A
+        # SalaConfig's tree arrives so (bfloat16 among float32 norms) and is
+        # kept as given; a GPT-2 tree is cast here, once, and the tree passed
+        # in is its caller's to drop.
+        self.params, self.draft_params = params, draft_params
+        if not self._sala:
+            self.params = self._serving_weights(params, "target")
+            if draft_params is not None:
+                self.draft_params = self._serving_weights(draft_params, "draft")
 
         self._m = serve.max_blocks_per_seq(config.n_positions)
         self._seq_limit = serve.seq_limit(config.n_positions)
@@ -945,6 +965,13 @@ class ServingEngine:
                     out_shardings=(rep_sh, pool_sharding, pool_sharding),
                 )
             self._scatter_fn, self._copy_fn = make_pool_jits(pool_sharding)
+        # Per-device bytes of the weights held, as placed (under a mesh the
+        # head-sharded qkv leaves count one shard): `metrics_snapshot`.
+        self.weight_bytes = sum(
+            math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+            if isinstance(a, jax.Array) else a.nbytes
+            for a in jax.tree_util.tree_leaves(self.params)
+        )
         self.k_pool, self.v_pool = init_pools(
             config.kv_pool_view if self._sala else config, serve,
             compute_dtype, sharding=pool_sharding,
@@ -1136,6 +1163,30 @@ class ServingEngine:
             "engine_mesh", mesh=serve.mesh or "single",
             devices=self._dp * self._tp, data=self._dp, tp=self._tp,
         )
+
+    def _serving_weights(self, params, model: str):
+        """``gpt2.serving_weights`` of a GPT-2 tree at the engine's dtype.
+        Under a tracer, an ``engine_weights`` event says what the cast did:
+        ``bytes_given`` / ``bytes_held`` are the whole tree's before and
+        after, ``cast_leaves`` how many leaves changed dtype (0: the engine
+        holds the caller's arrays), ``ms`` the host's wait for the cast -
+        waited for only then, so that an untraced start-up goes on building
+        its programs while the device still makes the weights."""
+        tracer = get_tracer()
+        t0 = time.monotonic()
+        held = gpt2.serving_weights(params, self.compute_dtype)
+        if tracer.enabled:
+            jax.block_until_ready(held)
+            given, kept = (jax.tree_util.tree_leaves(t) for t in (params, held))
+            tracer.event(
+                "engine_weights", model=model,
+                dtype=jnp.dtype(self.compute_dtype).name,
+                cast_leaves=sum(a is not b for a, b in zip(given, kept)),
+                bytes_given=sum(a.nbytes for a in given),
+                bytes_held=sum(a.nbytes for a in kept),
+                ms=(time.monotonic() - t0) * 1e3,
+            )
+        return held
 
     def _mesh_scope(self):
         """Context every device dispatch runs under: activates the serving
@@ -2139,6 +2190,7 @@ class ServingEngine:
             ),
             "serve_mesh_devices": float(self._dp * self._tp),
             "kv_pool_bytes_per_device": float(self.kv_pool_bytes_per_device),
+            "weight_bytes": float(self.weight_bytes),
             "prefill_batched": float(self.stats["prefill_batched"]),
             "spec_draft_tokens": float(self.stats["spec_draft_tokens"]),
             "spec_accepted_tokens": float(

@@ -489,6 +489,7 @@ class ReplicaRouter:
             "kv_pool_bytes_per_device": float(
                 max(e.kv_pool_bytes_per_device for e in self.engines)
             ),
+            "weight_bytes": float(max(e.weight_bytes for e in self.engines)),
             "prefill_batched": float(
                 sum(e.stats["prefill_batched"] for e in self.engines)
             ),

@@ -110,6 +110,7 @@ class WorkerHandle:
         serve: ServeConfig,
         *,
         kv_pool_bytes_per_device: int = 0,
+        weight_bytes: int = 0,
         rpc_timeout_s: float = 300.0,
         heartbeat_s: float = 1.0,
         heartbeat_timeout_s: float | None = None,
@@ -132,6 +133,7 @@ class WorkerHandle:
         self._sock = sock
         self.serve = serve
         self.kv_pool_bytes_per_device = int(kv_pool_bytes_per_device)
+        self.weight_bytes = int(weight_bytes)
         self.rpc_timeout_s = float(rpc_timeout_s)
         self.heartbeat_s = float(heartbeat_s)
         # Satellite: the heartbeat reply deadline is a flag now — a
@@ -536,6 +538,7 @@ class WorkerSpawner:
         return WorkerHandle(
             proc, sock, serve,
             kv_pool_bytes_per_device=hello["kv_pool_bytes_per_device"],
+            weight_bytes=hello.get("weight_bytes", 0),
             rpc_timeout_s=self.rpc_timeout_s,
             heartbeat_s=self.heartbeat_s,
             heartbeat_timeout_s=self.heartbeat_timeout_s,
@@ -883,6 +886,7 @@ class RemoteSpawner:
         return WorkerHandle(
             None, sock, serve,
             kv_pool_bytes_per_device=hello["kv_pool_bytes_per_device"],
+            weight_bytes=hello.get("weight_bytes", 0),
             rpc_timeout_s=self.rpc_timeout_s,
             heartbeat_s=self.heartbeat_s,
             heartbeat_timeout_s=self.heartbeat_timeout_s,
@@ -1056,6 +1060,7 @@ def _serve_loop(conn: socket.socket, state: _WorkerState,
                     "pid": os.getpid(),
                     "serve": dataclasses.asdict(eng.serve),
                     "kv_pool_bytes_per_device": eng.kv_pool_bytes_per_device,
+                    "weight_bytes": eng.weight_bytes,
                     "stats": eng.stats,
                 }, peer=peer)
             except WireError:

@@ -311,13 +311,17 @@ def model_config_from_args(args: argparse.Namespace):
 
 
 def load_model(args: argparse.Namespace):
-    """(config, params) from --model overrides + checkpoint/--init_random.
+    """(config, params) from --model overrides + checkpoint/--init_random,
+    the params as an engine holds them (``gpt2.serving_weights`` at the
+    engine's dtype: matmul and embedding leaves cast once, LayerNorm float32),
+    so that no float32 copy outlives this call beside the engine's tree.
     Call after the jax platform is pinned."""
     import jax
 
     from gpt_2_distributed_tpu.checkpoint import latest_checkpoint, restore_params
     from gpt_2_distributed_tpu.config import SalaConfig
     from gpt_2_distributed_tpu.models import gpt2
+    from gpt_2_distributed_tpu.serving.engine import DEFAULT_COMPUTE_DTYPE
     from gpt_2_distributed_tpu.utils.device_info import device_banner
 
     config = model_config_from_args(args)
@@ -346,7 +350,7 @@ def load_model(args: argparse.Namespace):
         shardings = jax.tree_util.tree_map(lambda _: one_device, template)
         params, meta = restore_params(path, template, shardings)
         print(f"checkpoint: {path} (step {meta.step})", file=sys.stderr)
-    return config, params
+    return config, gpt2.serving_weights(params, DEFAULT_COMPUTE_DTYPE)
 
 
 def load_draft_model(args: argparse.Namespace, config):
@@ -357,12 +361,14 @@ def load_draft_model(args: argparse.Namespace, config):
     prefix), keeping the preset's depth/width. Weights come from
     ``--draft_ckpt`` when given, seeded init otherwise — a random draft
     is still *correct* (verification guarantees the output distribution),
-    it just accepts little. Call after the jax platform is pinned."""
+    it just accepts little. Returned as ``load_model`` returns the target.
+    Call after the jax platform is pinned."""
     draft = getattr(args, "draft_preset", None)
     if draft is None:
         return None, None
     from gpt_2_distributed_tpu.config import MODEL_PRESETS
     from gpt_2_distributed_tpu.models import gpt2
+    from gpt_2_distributed_tpu.serving.engine import DEFAULT_COMPUTE_DTYPE
 
     draft_config = MODEL_PRESETS[draft].replace(
         vocab_size=config.vocab_size, n_positions=config.n_positions
@@ -390,7 +396,7 @@ def load_draft_model(args: argparse.Namespace, config):
               file=sys.stderr)
     else:
         draft_params = gpt2.init_params(draft_config)
-    return draft_config, draft_params
+    return draft_config, gpt2.serving_weights(draft_params, DEFAULT_COMPUTE_DTYPE)
 
 
 def build_serve_config(args: argparse.Namespace, config):

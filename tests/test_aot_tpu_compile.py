@@ -323,10 +323,13 @@ def test_train_step_flash_kernel_names(chip, topo, monkeypatch):
     assert text.count("tpu_custom_call") == 2 * config.n_layer
 
 
-def _engine_programs(chip, topo, monkeypatch, num_blocks=4 * 64 + 1):
+def _engine_programs(chip, topo, monkeypatch, num_blocks=4 * 64 + 1,
+                     held=None):
     """The decode step and the 256-wide chunk prefill, bound, named, donated
     and handed their pools as ``ServingEngine`` does - stored in
-    ``paged_cache.pool_shape`` - at the 1.5B widths, 2 layers, 4 slots."""
+    ``paged_cache.pool_shape`` - at the 1.5B widths, 2 layers, 4 slots.
+    The weights are the float32 tree ``init_params`` gives, through ``held``
+    if given (``gpt2.serving_weights`` at a dtype: what an engine holds)."""
     from gpt_2_distributed_tpu.config import ServeConfig
     from gpt_2_distributed_tpu.models import gpt2
     from gpt_2_distributed_tpu.serving import engine as eng
@@ -340,9 +343,10 @@ def _engine_programs(chip, topo, monkeypatch, num_blocks=4 * 64 + 1):
     def arr(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
-    params = jax.tree_util.tree_map(
-        lambda a: arr(a.shape, a.dtype),
-        jax.eval_shape(lambda: gpt2.init_params(config)))
+    params = jax.eval_shape(lambda: gpt2.init_params(config))
+    if held is not None:
+        params = jax.eval_shape(held, params)
+    params = jax.tree_util.tree_map(lambda a: arr(a.shape, a.dtype), params)
     pool = arr(pool_shape(config, serve), BF16)
     b, m = serve.max_batch, serve.max_blocks_per_seq(config.n_positions)
     decode = jax.jit(
@@ -360,7 +364,7 @@ def _engine_programs(chip, topo, monkeypatch, num_blocks=4 * 64 + 1):
         params, pool, pool, arr((1, m), I32),
         arr((1, serve.prefill_chunk), I32), arr((1,), I32), arr((1,), I32),
         arr((1, 2), jnp.uint32)).compile()
-    return decode, chunk, pool.shape
+    return decode, chunk, pool.shape, params
 
 
 def test_engine_decode_step_kernel_name(chip, topo, monkeypatch):
@@ -395,7 +399,7 @@ def test_engine_programs_never_copy_their_pools(chip, topo, monkeypatch):
     assert shape == (2, 6, 43, 25, 16, 64)
     stored = "bf16[" + ",".join(map(str, shape)) + "]{5,4,3,2,1,0:"
     blocks = {"6,43", "257", "258"}     # split, asked for, merged
-    for name, compiled, bigger in zip(("decode", "chunk"), small, large):
+    for name, compiled, bigger in zip(("decode", "chunk"), small[:2], large[:2]):
         text = compiled.as_text()
         head = text.splitlines()[0]
         assert head.count(stored) == 4, (name, head)    # 2 pools in, 2 out
@@ -410,3 +414,61 @@ def test_engine_programs_never_copy_their_pools(chip, topo, monkeypatch):
         temp = compiled.memory_analysis().temp_size_in_bytes
         grown = bigger.memory_analysis().temp_size_in_bytes
         assert abs(grown - temp) < 2**20, (name, temp, grown)
+
+
+def _weight_casts(hlo_text: str, params) -> list[str]:
+    """``convert`` instructions whose result is a bfloat16 array of a weight
+    leaf's shape, whole or one layer's slice of a stacked leaf: a cast of
+    that weight (no activation of these programs has such a shape)."""
+    import re
+
+    shapes = set()
+    for a in jax.tree_util.tree_leaves(params):
+        shapes |= {a.shape, a.shape[1:]}
+    found = []
+    for line in hlo_text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = bf16\[([\d,]+)\]\S* convert\(", line)
+        if made and tuple(map(int, made.group(1).split(","))) in shapes:
+            found.append(line.strip()[:160])
+    return found
+
+
+def test_engine_programs_never_cast_their_weights(chip, topo, monkeypatch):
+    """What an engine holds is what its programs multiply by (PR 31). On the
+    tree ``gpt2.serving_weights`` gives at bfloat16 - the engine's own - the
+    decode step and the chunk prefill convert no weight, take the bf16 tree
+    and the two pools as their arguments, and keep under a tenth of the
+    weights' bytes in temporaries beside one relayout copy of ``wte`` (the
+    row gather and the head want it two ways: ``PERF.md`` section 7; at 48
+    layers copy and all are under that tenth). On the float32 tree, which
+    the step impls still accept and ``tests/benchmark/test_aot_v5e.py``
+    still lowers, XLA hoists the ``.astype`` of every stacked matmul weight
+    out of the layer scan and runs it whole on every call, into temporaries.
+    That is what an engine must never be handed again."""
+    from gpt_2_distributed_tpu.models import gpt2
+
+    given = _engine_programs(chip, topo, monkeypatch)
+    held = _engine_programs(
+        chip, topo, monkeypatch, held=lambda t: gpt2.serving_weights(t, BF16))
+
+    def nbytes(tree):
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(tree))
+
+    f32_bytes, held_bytes = nbytes(given[3]), nbytes(held[3])
+    norms = nbytes([a for a in jax.tree_util.tree_leaves(held[3])
+                    if a.dtype == F32])
+    assert 0 < norms < 2**16 and held_bytes - norms == (f32_bytes - norms) // 2
+    wte = nbytes(held[3]["wte"])
+    for name, before, after in zip(("decode", "chunk"), given[:2], held[:2]):
+        assert len(_weight_casts(before.as_text(), given[3])) >= 4, name
+        assert not _weight_casts(after.as_text(), given[3]), name
+        mem = after.memory_analysis()
+        assert mem.temp_size_in_bytes - wte < held_bytes // 10, (
+            name, mem.temp_size_in_bytes, held_bytes)
+        assert before.memory_analysis().temp_size_in_bytes > mem.temp_size_in_bytes
+        # The pools as the device holds them (D 64 in 128 lanes) are the
+        # bytes aliased to the outputs; the rest is the bf16 tree, the
+        # padding of its tiles and the step's rows.
+        rest = mem.argument_size_in_bytes - mem.alias_size_in_bytes - held_bytes
+        assert 0 <= rest < held_bytes // 100, (name, mem.argument_size_in_bytes)

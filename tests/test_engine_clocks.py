@@ -108,6 +108,55 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
     assert step_clocks([s, s]) == pytest.approx(step_clocks([s]))
     assert all(key in METRIC_REGISTRY for key in snap)
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_weight_bytes_and_the_engine_weights_event(
+        dtype, tmp_path, tiny_params, tiny_config):
+    """What the engine holds, as it says it: ``weight_bytes`` in the
+    snapshot (registered) is the bytes of ``engine.params``, and one
+    ``engine_weights`` event a model at construction gives the cast's two
+    sides. A float32 tree held at bfloat16 is half of what was given but
+    for the LayerNorm leaves; held at float32 nothing is cast or copied."""
+    from scripts.obs_report import load_trace_dir
+
+    draft_config = tiny_config.replace(n_layer=1)
+    draft_params = gpt2.init_params(draft_config, seed=1)
+    serve = ServeConfig(max_batch=3, block_size=8, num_blocks=32,
+                        attn_impl="xla", **MODES["speculative"])
+    get_tracer().configure(str(tmp_path))
+    try:
+        eng = ServingEngine(
+            tiny_params, tiny_config, serve, temperature=0.0,
+            compute_dtype=dtype, draft_params=draft_params,
+            draft_config=draft_config)
+    finally:
+        get_tracer().configure(None, enabled=False)
+
+    def nbytes(tree, keep=lambda name: True):
+        return sum(a.nbytes for path, a in
+                   jax.tree_util.tree_leaves_with_path(tree)
+                   if keep(jax.tree_util.keystr(path)))
+
+    snap = eng.metrics_snapshot()
+    assert snap["weight_bytes"] == eng.weight_bytes == nbytes(eng.params)
+    assert "weight_bytes" in METRIC_REGISTRY
+    events = {r["attrs"]["model"]: r["attrs"]
+              for r in load_trace_dir(str(tmp_path))
+              if r.get("ph") == "event" and r["name"] == "engine_weights"}
+    assert sorted(events) == ["draft", "target"]
+    for model, given, held in (("target", tiny_params, eng.params),
+                               ("draft", draft_params, eng.draft_params)):
+        ev = events[model]
+        norms = nbytes(given, lambda name: "ln" in name)
+        assert ev["dtype"] == dtype and ev["ms"] >= 0
+        assert ev["bytes_given"] == nbytes(given)
+        assert ev["bytes_held"] == nbytes(held)
+        if dtype == "bfloat16":
+            assert ev["cast_leaves"] == 10      # wte, wpe, 4 weights, 4 biases
+            assert ev["bytes_given"] - norms == 2 * (ev["bytes_held"] - norms)
+        else:
+            assert ev["cast_leaves"] == 0 and held is given
+
+
 def test_fleet_snapshot_and_the_servers_compile_lines(tiny_params, tiny_config, capsys):
     """The router's snapshot (what ``/metrics`` and ``--tb_dir`` show)
     carries the step clocks over every engine's steps, each registered;

@@ -105,6 +105,18 @@ def test_counters_follow_the_selection(fresh_tokens):
     assert snap["kv_pool_bytes_per_device"] > 0
 
 
+def test_the_engine_holds_the_tree_it_was_given(params, fresh_tokens):
+    """The cast at construction is the GPT-2 family's. A SalaConfig's tree
+    (bfloat16 among float32 norm weights as ``init_params`` makes it; all
+    float32 here) is held as it came, whatever the engine computes in."""
+    eng, _ = fresh_tokens
+    assert eng.params is params
+    at_bf16 = ServingEngine(params, CONFIG, serve_config(), temperature=0.0)
+    assert at_bf16.compute_dtype == jnp.bfloat16 and at_bf16.params is params
+    assert eng.metrics_snapshot()["weight_bytes"] == sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(params))
+
+
 def test_a_reused_slot_serves_what_a_fresh_engine_does(params, fresh_tokens):
     eng = engine(params, max_batch=1, num_blocks=17)
     prompts = requests()

@@ -183,33 +183,153 @@ def _mixed_trace():
     return prompts, news, keys
 
 
-def test_engine_greedy_bit_matches_generate_cached(tiny_params, tiny_config):
+# The engine holds `gpt2.serving_weights(params, compute_dtype)`; the one-shot
+# reference is handed the float32 tree and casts each weight at its use. At
+# bfloat16 the two multiply by the same bf16(w); at float32 the engine holds
+# the caller's own arrays.
+DTYPES = pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                                 ids=["bfloat16", "float32"])
+
+
+@DTYPES
+def test_engine_greedy_bit_matches_generate_cached(
+        dtype, tiny_params, tiny_config):
     prompts, news, keys = _mixed_trace()
-    eng = ServingEngine(tiny_params, tiny_config, _serve(), temperature=0.0)
+    eng = ServingEngine(tiny_params, tiny_config, _serve(), temperature=0.0,
+                        compute_dtype=dtype)
     handles = [eng.submit(p, n, rng=k)
                for p, n, k in zip(prompts, news, keys)]
     eng.run_until_idle(max_steps=200)
     for h, p, n, k in zip(handles, prompts, news, keys):
-        ref = _oneshot(tiny_params, tiny_config, p, k, n, temperature=0.0)
+        ref = _oneshot(tiny_params, tiny_config, p, k, n, temperature=0.0,
+                       compute_dtype=dtype)
         assert h.generated == ref, h.id
         assert h.done and h.finish_reason == "length"
     # All blocks back after drain; no leak across the whole trace.
     assert eng.allocator.available == eng.serve.num_blocks - 1
 
 
-def test_engine_sampled_bit_matches_generate_cached(tiny_params, tiny_config):
+@DTYPES
+def test_engine_sampled_bit_matches_generate_cached(
+        dtype, tiny_params, tiny_config):
     # temperature>0 + top_k: the per-slot PRNG chains must replay the exact
     # threefry split order of the one-shot path regardless of batch mates.
     prompts, news, keys = _mixed_trace()
     eng = ServingEngine(tiny_params, tiny_config, _serve(),
-                        temperature=0.9, top_k=40)
+                        temperature=0.9, top_k=40, compute_dtype=dtype)
     handles = [eng.submit(p, n, rng=k)
                for p, n, k in zip(prompts, news, keys)]
     eng.run_until_idle(max_steps=200)
     for h, p, n, k in zip(handles, prompts, news, keys):
         ref = _oneshot(tiny_params, tiny_config, p, k, n,
-                       temperature=0.9, top_k=40)
+                       temperature=0.9, top_k=40, compute_dtype=dtype)
         assert h.generated == ref, h.id
+
+
+# ------------------------------------------------------ the weights it holds
+
+LAYER_NORMS = {"ln_f_scale", "ln_f_bias", "ln1_scale", "ln1_bias",
+               "ln2_scale", "ln2_bias"}
+
+
+def _leaves(tree):
+    """{leaf name: array} of a GPT-2 tree (no two leaves share a name)."""
+    return {path[-1].key: a
+            for path, a in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+def test_serving_weights_casts_what_the_forwards_cast(tiny_config):
+    """``gpt2.serving_weights``: every leaf the forwards take
+    ``.astype(compute_dtype)`` of is in that dtype, bit for bit what the
+    cast at the use gives; the LayerNorm leaves, read as float32, are the
+    caller's; the tree given is neither donated nor changed; and a tree
+    that needs no cast comes back as it is."""
+    given = gpt2.init_params(tiny_config, seed=3)
+    # biases and LayerNorm leaves start at 0 and 1: give every leaf values
+    # that bfloat16 rounds
+    given = jax.tree_util.tree_map(
+        lambda a: a + jnp.linspace(0.1, 0.7, a.size).reshape(a.shape), given)
+    before = jax.tree_util.tree_map(np.asarray, given)
+    held = gpt2.serving_weights(given, jnp.bfloat16)
+    assert (jax.tree_util.tree_structure(held)
+            == jax.tree_util.tree_structure(given))
+    for name, leaf in _leaves(held).items():
+        mine = _leaves(given)[name]
+        assert leaf.shape == mine.shape, name
+        if name in LAYER_NORMS:
+            assert leaf is mine and leaf.dtype == jnp.float32, name
+        else:
+            assert leaf.dtype == jnp.bfloat16, name
+            np.testing.assert_array_equal(
+                np.asarray(leaf), np.asarray(mine.astype(jnp.bfloat16)), name)
+    assert sorted(n for n, a in _leaves(held).items()
+                  if a.dtype == jnp.float32) == sorted(LAYER_NORMS)
+    for name, mine in _leaves(given).items():
+        assert not mine.is_deleted(), name
+        np.testing.assert_array_equal(np.asarray(mine), _leaves(before)[name])
+    assert gpt2.serving_weights(given, jnp.float32) is given
+    assert gpt2.serving_weights(held, jnp.bfloat16) is held
+    # a tree cast in part: only the leaves that need it are made anew
+    mixed = dict(given, wte=held["wte"])
+    again = gpt2.serving_weights(mixed, jnp.bfloat16)
+    assert again["wte"] is held["wte"] and again["wpe"].dtype == jnp.bfloat16
+    # abstract, as the AOT tests hand it over
+    shapes = jax.eval_shape(lambda t: gpt2.serving_weights(t, jnp.bfloat16), given)
+    assert ({n: a.dtype for n, a in _leaves(shapes).items()}
+            == {n: a.dtype for n, a in _leaves(held).items()})
+
+
+def test_engine_holds_its_weights_in_the_compute_dtype(tiny_params, tiny_config):
+    """A bfloat16 engine keeps no float32 copy of a matmul or embedding
+    leaf, and leaves its caller's tree alone; a float32 engine holds the
+    caller's own arrays; the step programs are handed what is held."""
+    before = jax.tree_util.tree_map(np.asarray, tiny_params)
+    eng = ServingEngine(tiny_params, tiny_config, _serve(prefill_chunk=4),
+                        temperature=0.0)
+    held = _leaves(eng.params)
+    assert sorted(n for n, a in held.items()
+                  if a.dtype == jnp.float32) == sorted(LAYER_NORMS)
+    assert all(a.dtype == jnp.bfloat16 for n, a in held.items()
+               if n not in LAYER_NORMS)
+    seen = []
+    inner = eng._decode_fn
+    eng._decode_fn = lambda params, *a: (seen.append(params), inner(params, *a))[1]
+    h = eng.submit([5, 6, 7, 8, 9], 4, rng=0)
+    eng.run_until_idle(max_steps=50)
+    assert h.done and seen and all(p is eng.params for p in seen)
+    for name, mine in _leaves(tiny_params).items():
+        assert not mine.is_deleted() and mine.dtype == jnp.float32, name
+        np.testing.assert_array_equal(np.asarray(mine), _leaves(before)[name])
+    same = ServingEngine(tiny_params, tiny_config, _serve(), temperature=0.0,
+                         compute_dtype=jnp.float32)
+    assert same.params is tiny_params
+    assert same.weight_bytes > eng.weight_bytes > same.weight_bytes // 2
+
+
+def test_load_model_returns_what_an_engine_holds():
+    """``serve.load_model`` / ``load_draft_model`` (the CLIs' and the
+    workers' loaders) hand back the tree already cast, so that no float32
+    copy lives on in their caller's frame beside the engine's tree; the
+    engine then takes it as it is."""
+    from gpt_2_distributed_tpu.serving import serve as serve_cli
+
+    args = serve_cli.build_argparser().parse_args([
+        "--init_random", "--requests", "-", "--n_layer", "2", "--n_embd", "32",
+        "--n_head", "2", "--vocab_size", "257", "--seq_len", "64",
+        "--draft_preset", "124M"])
+    config, params = serve_cli.load_model(args)
+    from gpt_2_distributed_tpu.config import MODEL_PRESETS
+    small = MODEL_PRESETS["124M"].replace(n_layer=1, n_embd=32, n_head=2)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setitem(MODEL_PRESETS, "124M", small)
+        draft_config, draft_params = serve_cli.load_draft_model(args, config)
+    assert draft_config.vocab_size == 257 and draft_config.n_layer == 1
+    for tree in (params, draft_params):
+        assert sorted(n for n, a in _leaves(tree).items()
+                      if a.dtype == jnp.float32) == sorted(LAYER_NORMS)
+        assert _leaves(tree)["mlp_fc_w"].dtype == jnp.bfloat16
+    eng = ServingEngine(params, config, _serve(), temperature=0.0)
+    assert eng.params is params
 
 
 def test_compile_once_across_admission_eviction_churn(

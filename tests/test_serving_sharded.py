@@ -311,6 +311,38 @@ def test_kv_pool_bytes_and_snapshot_keys(tiny_params, tiny_config):
     assert "prefill_batched" in snap
 
 
+@pytest.mark.parametrize("mesh", ["data:2", "tp:2", "data:2,tp:2"])
+def test_placed_weights_are_cast_and_still_head_sharded(
+        tiny_params, tiny_config, mesh):
+    """The mesh places the tree the engine holds - cast, then put: every
+    matmul and embedding leaf bfloat16 on every device of the mesh, the qkv
+    leaves split over 'tp' on their head axis as ``serve_param_pspecs`` has
+    it, the LayerNorm leaves float32; ``weight_bytes`` counts one device's
+    share."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from test_serving import LAYER_NORMS, _leaves
+
+    one = ServingEngine(tiny_params, tiny_config, _serve(max_batch=8, num_blocks=64))
+    eng = ServingEngine(tiny_params, tiny_config,
+                        _serve(max_batch=8, num_blocks=64, mesh=mesh))
+    held = _leaves(eng.params)
+    assert sorted(n for n, a in held.items()
+                  if a.dtype == jnp.float32) == sorted(LAYER_NORMS)
+    assert all(len(a.sharding.device_set) == eng.mesh.size for a in held.values())
+    tp = eng.mesh.shape["tp"]
+    heads = P(None, None, None, "tp", None) if tp > 1 else P()
+    assert held["attn_qkv_w"].sharding.spec == heads
+    assert held["attn_qkv_w"].dtype == jnp.bfloat16
+    assert held["mlp_fc_w"].sharding.is_fully_replicated
+    qkv = held["attn_qkv_w"].nbytes + held["attn_qkv_b"].nbytes
+    assert eng.weight_bytes == one.weight_bytes - qkv + qkv // tp
+    assert eng.metrics_snapshot()["weight_bytes"] == float(eng.weight_bytes)
+    assert all(not a.is_deleted() for a in jax.tree_util.tree_leaves(tiny_params))
+
+
 def test_submit_rejects_over_shard_capacity(tiny_params, tiny_config):
     # 32 blocks over 4 shards = 7 usable on the smallest shard; a request
     # needing 8 could never be admitted even with the pool idle.

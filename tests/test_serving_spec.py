@@ -237,6 +237,29 @@ def test_greedy_bit_equality_sharded(tiny_params, tiny_config, draft,
     assert [h.generated for h in hs] == greedy_refs
 
 
+@pytest.mark.parametrize("mesh", ["", "data:2,tp:2"])
+def test_draft_tree_is_held_in_the_compute_dtype(tiny_params, tiny_config,
+                                                 draft, mesh):
+    """The draft model's weights are held as the target's are: cast once
+    at construction (its LayerNorm leaves stay float32), placed under the
+    mesh as cast, and the caller's float32 tree left alive."""
+    import jax
+    import jax.numpy as jnp
+
+    from test_serving import LAYER_NORMS, _leaves
+
+    eng = _spec_engine(tiny_params, tiny_config,
+                       _serve(spec="draft:124M,k:2", mesh=mesh, num_blocks=64),
+                       draft, temperature=0.0)
+    for held, given in ((eng.params, tiny_params), (eng.draft_params, draft[0])):
+        assert sorted(n for n, a in _leaves(held).items()
+                      if a.dtype == jnp.float32) == sorted(LAYER_NORMS)
+        assert all(a.dtype == jnp.float32 and not a.is_deleted()
+                   for a in jax.tree_util.tree_leaves(given))
+    if mesh:
+        assert "tp" in str(eng.draft_params["block"]["attn_qkv_w"].sharding.spec)
+
+
 # ------------------------------------- migration during speculation
 
 
