@@ -274,7 +274,8 @@ for _name, _dist in (
     ("grow_ms", "mean"),               # of it: watermark block-table growth
     ("emit_ms", "mean"),               # of it: after the read-back to the end
     ("decode_dispatch_ms", "mean"),    # decode program's call to its return
-    ("decode_wait_ms", "mean"),        # from there to the token read-back
+    ("decode_wait_ms", "mean"),        # from there to the read-back of the step before
+    ("decode_overlapped", "mean"),     # share of decode steps dispatched over an unread one
     ("decode_rows", "mean"),           # rows one decode step advances
     ("decode_attended", "mean"),       # keys those rows attend
     ("decode_blocks_live", "mean"),    # table slots of those rows that hold keys

@@ -49,9 +49,29 @@ is the serving-shaped alternative:
   rows keep flowing through the compiled step with ``length 0`` — the
   paged-attention mask makes them exact no-ops.
 * **Streaming**: every sampled token is pushed through the request's
-  ``on_token`` callback the step it is produced. A preempted request's
-  resume never re-emits: its last sampled token is carried as the pending
-  decode input, so TTFT reflects first emission, not re-admission.
+  ``on_token`` callback as soon as the host has read it. A preempted
+  request's resume never re-emits: its last sampled token is carried as the
+  pending decode input, so TTFT reflects first emission, not re-admission.
+* **The decode loop is pipelined one deep.** ``step`` dispatches decode
+  step N+1 BEFORE it reads step N's tokens back: the sampled tokens and the
+  advanced keys stay on the device and feed the next step there (rows that
+  a prefill has just opened are patched in by row, ``_feed_impl``), so the
+  read-back, the emit loop, admission and the next step's arguments all run
+  while the device computes, and none of the host's time lies between two
+  device steps. What does not hang on a token's value is settled at
+  dispatch: a row's position advances, and a row whose token in flight is
+  its ``max_new_tokens``-th leaves its slot and blocks at once. What does
+  hang on it is read first or thrown away: with ``eos_id`` set a row may
+  run one step past its EOS, and that step's token is dropped (the position
+  it wrote lies in the row's own blocks). Whatever needs the host to hold
+  every sampled token - a prefill dispatch (it waits for its own first
+  token), preemption, deadline eviction, migration, ``decode_keys``,
+  ``clear_prefix_cache``, ``close`` - reads the unread step back first
+  (``collect``); the speculative round decides on values and never leaves a
+  step unread. ``has_work`` is true while a step is unread. The emitted ids
+  are those of an engine that collects after every dispatch, greedy or
+  sampled: ``stats["decode_overlapped"]`` of ``stats["decode_steps"]`` were
+  dispatched over an unread step.
 
 * **Multi-chip serving** (``ServeConfig.mesh``, e.g. ``"data:4"`` or
   ``"data:2,tp:2"``): the engine builds a data×tp mesh
@@ -94,8 +114,9 @@ import collections
 import contextlib
 import functools
 import math
+import sys
 import time
-from typing import Callable, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -172,6 +193,18 @@ class _Phase:
         for key in self._keys:
             self._stats[key] += ms
         return self._span.__exit__(*exc)
+
+
+class _Unread(NamedTuple):
+    """A decode step that was dispatched and whose tokens the host has not
+    read yet."""
+
+    tokens: jax.Array      # [B + counters] the sampled rows, then the family's counters
+    keys: jax.Array        # [B, 2] the advanced chains - both still on the device
+    rows: np.ndarray       # [B] bool - the rows it advanced
+    reqs: list             # who held each slot when it was dispatched
+    last: np.ndarray       # [B] bool - rows whose token in it is their
+                           # max_new_tokens-th: they left their slots there
 
 
 # Version tag of the serialized request form (`RequestHandle.to_wire`).
@@ -544,6 +577,22 @@ def _decode_step_impl(
     return next_tokens.astype(jnp.int32), keys, k_pool, v_pool
 
 
+def _feed_impl(
+    sampled: jnp.ndarray,      # [B + counters] int32 - the last decode step's tokens
+    chains: jnp.ndarray,       # [B, 2] uint32 - and its advanced keys
+    tokens: jnp.ndarray,       # [B] int32 - the host's rows
+    keys: jnp.ndarray,         # [B, 2] uint32
+    fresh: jnp.ndarray,        # [B] bool - rows whose host values are newer
+):
+    """The next decode step's ``tokens`` and ``keys``: what the step before
+    sampled, as it lies on the device (without the counters a family puts
+    behind the rows), with the rows a prefill has opened since - first token
+    or pending token, and the request's key - patched in from the host."""
+    rows = tokens.shape[0]
+    return (jnp.where(fresh, tokens, sampled[:rows]),
+            jnp.where(fresh[:, None], keys, chains))
+
+
 def _draft_step_impl(
     params,
     k_pool: jnp.ndarray,       # [L, N, H, bs, D], or as stored — DRAFT pool
@@ -861,6 +910,7 @@ class ServingEngine:
         self._scatter_fn, self._copy_fn = scatter_prefill, copy_block
         pool_sharding = None
         decode_kw: dict = {}
+        feed_kw: dict = {}
         chunk_kw: dict = {}
         prefill_kw: dict = {}
         spec_draft_kw: dict = {}
@@ -914,6 +964,7 @@ class ServingEngine:
                               vec_sh, row_sh, row_sh, row_sh, vec_sh),
                 out_shardings=(row_sh, vec_sh, pool_sharding, pool_sharding),
             )
+            feed_kw = dict(out_shardings=(row_sh, vec_sh))
             # Chunk-prefill rows are replicated over 'data' (R is small and
             # unconstrained by the mesh; the matmuls still shard over 'tp'
             # and the pool writes land data-sharded).
@@ -996,6 +1047,29 @@ class ServingEngine:
         self.tokens = np.zeros((serve.max_batch,), np.int32)
         self.active = np.zeros((serve.max_batch,), bool)
         self.keys = np.zeros((serve.max_batch, 2), np.uint32)
+        # The one-deep pipeline of the decode loop (module docstring).
+        # `tokens` and `keys` above are the HOST's rows: a decoding row's
+        # `keys` is its chain after its last token read back, its `tokens`
+        # is what a prefill (or a speculative round) put there; what the
+        # next decode step takes lies on the device, `_sampled` - the last
+        # decode step's two outputs - with the rows marked `_fresh` patched
+        # in from here (`_feed_fn`). `_left`: tokens a row has yet to
+        # sample once the dispatched steps are in; `_unread`: the step
+        # dispatched and not read back; `_parting`: requests that left
+        # their slot with their last token in a step still unread, by id.
+        behind = len(self._family.counters) if self._family is not None else 0
+        sampled_sh, chains_sh = feed_kw.get("out_shardings", (None, None))
+        self._sampled = (
+            jax.device_put(
+                np.zeros((serve.max_batch + behind,), np.int32), sampled_sh),
+            jax.device_put(
+                np.zeros((serve.max_batch, 2), np.uint32), chains_sh),
+        )
+        self._fresh = np.zeros((serve.max_batch,), bool)
+        self._left = np.zeros((serve.max_batch,), np.int64)
+        self._unread: _Unread | None = None
+        self._parting: dict[int, RequestHandle] = {}
+        self._feed_fn = jax.jit(_program("decode_feed", _feed_impl), **feed_kw)
 
         # --- draft-model state (speculative decoding) ---------------------
         # The draft pool pairs slot-for-slot with the target pool but is
@@ -1036,7 +1110,7 @@ class ServingEngine:
         self.stats = {
             "admitted": 0, "finished": 0, "prefills": 0, "prefill_chunks": 0,
             "prefill_dispatches": 0, "prefill_batched": 0,
-            "decode_steps": 0, "tokens_out": 0,
+            "decode_steps": 0, "decode_overlapped": 0, "tokens_out": 0,
             "preemptions": 0, "resumes": 0, "timeouts": 0,
             "prefix_hit_tokens": 0, "cow_copies": 0,
             "prefill_ms": 0.0, "decode_ms": 0.0, "queue_wait_ms": 0.0,
@@ -1044,13 +1118,18 @@ class ServingEngine:
             "spec_rollbacks": 0, "draft_ms": 0.0, "verify_ms": 0.0,
             # The step's own clocks (`_phase`), host time.monotonic in ms;
             # `metrics_snapshot` shows each per step. steps/step_ms: calls
-            # of step() that found work, whole wall time. admit_ms: deadline
-            # evictions + admission less the prefill dispatches admission
-            # made. decode_dispatch_ms: the part of decode_ms from the
-            # decode program's call to its return (argument transfer and
-            # enqueue; the verify pass's in a speculative round); the rest
-            # of decode_ms is the wait for the token read-back, and
-            # draft_ms. emit_ms: after the read-back to the step's end.
+            # of step() that found work, whole wall time (and a collect()
+            # outside any). admit_ms: deadline evictions + admission less
+            # the prefill dispatches admission made. decode_ms: a decode
+            # turn - the dispatch of one step and the read-back of the step
+            # BEFORE it (decode_overlapped of decode_steps were dispatched
+            # over an unread step; a turn that only collects has no
+            # dispatch, the first after one no read-back).
+            # decode_dispatch_ms: the part of decode_ms from the feed's and
+            # the decode program's calls to their return (argument transfer
+            # and enqueue; the verify pass's in a speculative round); the
+            # rest of decode_ms is the wait for the token read-back, and
+            # draft_ms. emit_ms: the emit loop over the tokens read back.
             # decode_rows/decode_attended: per decode step, active rows and
             # the keys they attend, sum of pos + 1. decode_blocks_live/
             # decode_blocks_table: of those rows' block-table slots, the
@@ -1616,6 +1695,8 @@ class ServingEngine:
             self.keys[slot] = np.asarray(req._key)
         self.pos[slot] = p_work
         self.active[slot] = True
+        self._fresh[slot] = True
+        self._left[slot] = req.max_new_tokens - len(req.generated)
         return emitted
 
     def _register_prefix(self, req: RequestHandle) -> None:
@@ -1631,24 +1712,28 @@ class ServingEngine:
         for j in range(len(w) // self.serve.block_size):
             self._cache.insert(w, j, req._blocks[j], self.allocator)
 
-    def _prefill_tick(self) -> int:
-        """Chunked mode: advance up to ``ServeConfig.prefill_batch``
-        in-progress prefills — oldest first — by one chunk each, in ONE
-        batched dispatch per engine step; decode steps interleave between
-        chunks, which is the whole point. Rows pad to ``prefill_batch`` so
-        the dispatch compiles once regardless of how many prefills are in
-        flight (``prefill_batch=1`` is exactly the old one-row tick)."""
+    def _prefill_slots(self) -> list[int]:
+        """Chunked mode: the slots this step's prefill dispatch advances -
+        up to ``ServeConfig.prefill_batch`` in-progress prefills, oldest
+        first; empty when there is none (or in whole-prompt mode)."""
         if self.serve.prefill_chunk == 0:
-            return 0
+            return []
         cands = sorted(
             (self._slots[s]._admit_order, s)
             for s in range(self.serve.max_batch)
             if self._slots[s] is not None
             and self._slots[s]._prefill_pos is not None
         )
-        if not cands:
+        return [s for _, s in cands[:self.serve.prefill_batch]]
+
+    def _prefill_tick(self, slots: list[int]) -> int:
+        """Chunked mode: advance ``slots`` by one chunk each, in ONE
+        batched dispatch per engine step; decode steps interleave between
+        chunks, which is the whole point. Rows pad to ``prefill_batch`` so
+        the dispatch compiles once regardless of how many prefills are in
+        flight (``prefill_batch=1`` is exactly the old one-row tick)."""
+        if not slots:
             return 0
-        slots = [s for _, s in cands[:self.serve.prefill_batch]]
         return self._prefill_rows(
             slots, self.serve.prefill_chunk, self.serve.prefill_batch
         )
@@ -1666,6 +1751,7 @@ class ServingEngine:
         self.block_table[slot, :] = 0
         self.pos[slot] = 0
         self.active[slot] = False
+        self._left[slot] = 0
         if self._spec_k and self._draft_blocks[slot] is not None:
             # Draft KV dies with the slot — it is disposable state, never
             # carried through preemption or migration (the next occupant
@@ -1703,22 +1789,24 @@ class ServingEngine:
         )
         self._queue.appendleft(req)
 
-    def _evict_overdue(self) -> int:
-        """Evict every request past its deadline — slotted rows via
-        ``_evict`` (blocks freed, slot reopened), queued requests by
+    def _overdue_slots(self, now: float) -> list[int]:
+        return [slot for slot, req in enumerate(self._slots)
+                if req is not None and req.deadline is not None
+                and now >= req.deadline]
+
+    def _evict_overdue(self, now: float) -> int:
+        """Evict every request past its deadline at ``now`` — slotted rows
+        via ``_evict`` (blocks freed, slot reopened), queued requests by
         removal. Runs at step boundaries only when some live request
         actually carries a deadline, so deadline-free deployments pay
         nothing."""
         if not self._deadlines:
             return 0
-        now = time.monotonic()
         evicted = 0
-        for slot, req in enumerate(self._slots):
-            if req is not None and req.deadline is not None \
-                    and now >= req.deadline:
-                self._evict(slot, "timeout")
-                self.stats["timeouts"] += 1
-                evicted += 1
+        for slot in self._overdue_slots(now):
+            self._evict(slot, "timeout")
+            self.stats["timeouts"] += 1
+            evicted += 1
         overdue = [r for r in self._queue
                    if r.deadline is not None and now >= r.deadline]
         for req in overdue:
@@ -1742,8 +1830,27 @@ class ServingEngine:
         generated tokens plus the per-slot PRNG chain head — so a healthy
         engine's ``adopt`` resumes each stream bit-identically with zero
         re-emitted tokens. Block release is best-effort: the engine is
-        presumed failed and its pools are abandoned with it."""
-        out = []
+        presumed failed and its pools are abandoned with it.
+
+        The unread decode step is read back first, so that the state
+        captured is the state after every token sampled; where the device
+        no longer answers, that step is lost with the engine, and a request
+        that had left its slot with its last token in it goes along with
+        the slotted ones to sample it again."""
+        try:
+            self.collect()
+        except Exception as e:
+            self._unread = None
+            print(f"[serve] extract_inflight: the unread decode step is lost "
+                  f"with the engine ({type(e).__name__}: {e})",
+                  file=sys.stderr, flush=True)
+        out = sorted(
+            (req for req in self._parting.values() if not req.done),
+            key=lambda req: req._admit_order,
+        )
+        self._parting.clear()
+        for req in out:
+            req._pending_token = req.generated[-1]
         slotted = sorted(
             (s for s in range(self.serve.max_batch)
              if self._slots[s] is not None),
@@ -1775,7 +1882,10 @@ class ServingEngine:
         ``extract_inflight`` would capture — a worker SIGKILLed between
         steps migrates from the mirrors with zero re-emission. Requests
         queued or mid-prefill are absent: their chain never advanced, the
-        mirror's last-known key is already the head."""
+        mirror's last-known key is already the head. The unread decode
+        step is read back first: a chain head goes with the tokens it has
+        sampled."""
+        self.collect()
         return {
             req.id: [int(k) for k in self.keys[slot]]
             for slot, req in enumerate(self._slots)
@@ -1799,7 +1909,12 @@ class ServingEngine:
         preempt the NEWEST-admitted request (possibly a prefilling one)
         and retry — oldest-first iteration means an old request steals
         from newer ones, never the reverse, so the oldest always runs to
-        completion and the engine cannot livelock."""
+        completion and the engine cannot livelock.
+
+        False: the pool is exhausted with a decode step unread. A victim
+        carries its last sampled token and its chain head away with it, so
+        the caller reads that step back (which may free blocks by itself)
+        and calls again."""
         bs = self.serve.block_size
         order = sorted(
             (s for s in range(self.serve.max_batch)
@@ -1833,10 +1948,13 @@ class ServingEngine:
                      and self._slot_shard(s) == shard),
                     key=lambda s: self._slots[s]._admit_order,
                 )
+                if self._unread is not None:
+                    return False
                 self._preempt(victim)
                 if victim == slot:
                     break   # preempted ourselves: row is gone (safety net —
                             # submit() guarantees one request always fits)
+        return True
 
     def _has_active(self) -> bool:
         return any(s is not None for s in self._slots)
@@ -1850,8 +1968,10 @@ class ServingEngine:
         )
 
     def has_work(self) -> bool:
-        """Anything queued or in flight — the driver's step/skip gate."""
-        return bool(self._queue) or self._has_active()
+        """Anything queued, in a slot, or sampled and not yet read back —
+        the driver's step/skip gate."""
+        return (bool(self._queue) or self._has_active()
+                or self._unread is not None)
 
     @property
     def queue_depth(self) -> int:
@@ -1872,8 +1992,10 @@ class ServingEngine:
     def step(self) -> int:
         """One engine step: admit what fits, advance one prefill chunk
         (chunked mode), grow/preempt block tables (watermark mode), then
-        one compiled decode step for every active row. Returns tokens
-        emitted this step (prefill first-tokens + decode samples)."""
+        one turn of the decode loop: dispatch the compiled decode step for
+        every active row, and read back and emit the tokens of the step
+        dispatched the turn BEFORE, while this one runs. Returns tokens
+        emitted this step (prefill first-tokens + the samples read back)."""
         if not self.has_work():
             return 0
         tracer = get_tracer()
@@ -1882,83 +2004,179 @@ class ServingEngine:
                          n=self.stats["decode_steps"]):
             return self._step_impl(tracer)
 
+    def collect(self) -> int:
+        """Read the unread decode step back and emit its tokens, if there
+        is one: afterwards the host holds every token sampled and every
+        chain head. Whatever must see those calls this first - inside a
+        step the loop does it itself. Returns tokens emitted."""
+        if self._unread is None:
+            return 0
+        tracer = get_tracer()
+        with self._phase(tracer, "engine_step", "step_ms",
+                         n=self.stats["decode_steps"]):
+            return self._decode_turn(tracer, dispatch=False)
+
+    def close(self) -> None:
+        """Leave nothing unread (``EngineDriver.close``): a token sampled
+        is a token delivered."""
+        self.collect()
+
     def _phase(self, tracer, name: str, *keys: str, **attrs) -> _Phase:
         """Span ``name`` and the clock of the counters ``keys``, as one."""
         return _Phase(self.stats, keys, tracer.span(name, **attrs))
 
     def _step_impl(self, tracer) -> int:
+        # Where the step is about to do something that must see every token
+        # sampled so far, the unread decode step is read back first (a turn
+        # of the loop that dispatches nothing); everything else runs while
+        # the device computes it.
+        emitted = 0
+        now = time.monotonic() if self._deadlines else 0.0
+        if self._unread is not None and (
+            # an overdue row's stream ends with its last sampled token
+            (self._deadlines and self._overdue_slots(now))
+            # whole-prompt mode prefills inside admission (see below)
+            or (self.serve.prefill_chunk == 0 and self._queue
+                and None in self._slots)
+        ):
+            emitted += self._decode_turn(tracer, dispatch=False)
         prefill_ms = self.stats["prefill_ms"]
         with self._phase(tracer, "admit", "admit_ms"):
-            self._evict_overdue()
+            self._evict_overdue(now)
             self._try_admit()
         # Whole-prompt mode prefills inside admission: that is prefill_ms.
         self.stats["admit_ms"] -= self.stats["prefill_ms"] - prefill_ms
+        # A prefill dispatch waits for its own first token, which the device
+        # computes after the decode step in flight: read that step back
+        # first, so that prefill_ms holds the chunk and nothing else. The
+        # loop then runs one step ahead between consecutive chunk-free steps.
+        slots = self._prefill_slots()
+        if slots and self._unread is not None:
+            emitted += self._decode_turn(tracer, dispatch=False)
         with tracer.span("prefill"):
-            emitted = self._prefill_tick()
-        if not bool(self.active.any()):
-            return emitted
-        if self.serve.admission == "watermark":
+            emitted += self._prefill_tick(slots)
+        if self.serve.admission == "watermark" and self.active.any():
             with self._phase(tracer, "grow", "grow_ms"):
-                self._grow_tables()
-            if not bool(self.active.any()):
-                return emitted
-
-        if self._spec_k:
+                grown = self._grow_tables()
+            if not grown:
+                emitted += self._decode_turn(tracer, dispatch=False)
+                with self._phase(tracer, "grow", "grow_ms"):
+                    self._grow_tables()
+        dispatch = bool(self.active.any())
+        if dispatch and self._spec_k:
             # Two-model step: draft k tokens, verify them in one target
             # pass, emit the accepted prefix (plus a bonus token when the
-            # whole draft survives). Replaces the single decode dispatch.
+            # whole draft survives). Replaces the single decode dispatch;
+            # acceptance is decided on token values, so nothing stays unread.
             return emitted + self._spec_round(tracer)
+        if dispatch or self._unread is not None:
+            emitted += self._decode_turn(tracer, dispatch)
+        return emitted
 
-        was_active = self.active.copy()
-        rows = self._count_decode(was_active)
-        # `decode` is decode_ms: the dispatch, which returns async, and the
-        # token read-back the scheduler blocks on, each a child span. In the
-        # sharded engine the read-back is the cross-shard all-gather of the
-        # row-sharded sampled tokens, and is named for it.
-        with self._phase(tracer, "decode", "decode_ms", rows=rows):
-            with self._phase(tracer, "dispatch", "decode_dispatch_ms"), \
-                    self._mesh_scope():
-                if self.state is None:
-                    next_tokens, new_keys, self.k_pool, self.v_pool = \
-                        self._decode_fn(
-                            self.params, self.k_pool, self.v_pool,
-                            self.block_table, self.tokens, self.pos,
-                            self.active, self.keys,
-                        )
-                else:
-                    (next_tokens, new_keys, self.k_pool, self.v_pool,
-                     self.state) = self._decode_fn(
-                        self.params, self.k_pool, self.v_pool, self.state,
-                        self.block_table, self.tokens, self.pos, self.active,
-                        self.keys,
-                    )
+    def _decode_turn(self, tracer, dispatch: bool) -> int:
+        """One turn of the decode loop: dispatch the decode step of the
+        active rows (``dispatch``; else the turn only collects), THEN read
+        back the step dispatched the turn before, which the device has
+        finished or is about to while it already holds the new one, and
+        emit its tokens. Returns tokens emitted.
+
+        `decode` is decode_ms: the dispatch, which returns async, and the
+        token read-back the scheduler blocks on - of the step BEFORE - each
+        a child span. In the sharded engine the read-back is the cross-shard
+        all-gather of the row-sharded sampled tokens, and is named for it."""
+        unread = self._unread
+        rows = self.active if dispatch else unread.rows
+        with self._phase(tracer, "decode", "decode_ms", rows=int(rows.sum())):
+            self._unread = self._dispatch_decode(tracer) if dispatch else None
+            if unread is None:
+                return 0
             with tracer.span(
                 "readback" if self.mesh is None else "token_allgather",
-                rows=rows,
+                rows=int(unread.rows.sum()),
             ):
                 toks_host = self._count_behind(
-                    np.asarray(next_tokens), self.serve.max_batch)
-        self.stats["decode_steps"] += 1
+                    np.asarray(unread.tokens), self.serve.max_batch)
         with self._phase(tracer, "emit", "emit_ms"):
-            self.keys = np.array(new_keys)  # writable copy: admission writes rows
-            # Advance every row that decoded this step; evictions below then
-            # reset their rows. Prefilling rows (occupied, inactive) hold still.
-            self.tokens = np.where(was_active, toks_host, self.tokens)
-            self.pos = np.where(was_active, self.pos + 1, self.pos)
-            decoded = 0
-            for slot, req in enumerate(self._slots):
-                if req is None or not was_active[slot]:
-                    continue
-                t = int(toks_host[slot])
-                req.generated.append(t)
-                decoded += 1
-                req._emit(t)
-                if self.serve.eos_id is not None and t == self.serve.eos_id:
+            return self._emit_step(unread, toks_host)
+
+    def _dispatch_decode(self, tracer) -> _Unread:
+        """Dispatch one decode step for every active row and settle what
+        does not hang on a token's value: positions advance, and a row whose
+        token in this step is its ``max_new_tokens``-th leaves its slot and
+        its blocks now (the device runs this step, then whatever is
+        admitted into them, in order).
+
+        The step's ``tokens`` and ``keys`` are the step before's outputs, on
+        the device (``_feed_fn``). The host arrays handed over are private
+        copies: a program may read a host argument after its call returns,
+        and the scheduler writes its own in place."""
+        rows = self.active.copy()
+        self._count_decode(rows)
+        with self._phase(tracer, "dispatch", "decode_dispatch_ms"), \
+                self._mesh_scope():
+            tokens, keys = self._feed_fn(
+                *self._sampled, self.tokens.copy(), self.keys.copy(),
+                self._fresh)
+            block_table = self.block_table.copy()
+            if self.state is None:
+                sampled, chains, self.k_pool, self.v_pool = self._decode_fn(
+                    self.params, self.k_pool, self.v_pool,
+                    block_table, tokens, self.pos, rows, keys,
+                )
+            else:
+                (sampled, chains, self.k_pool, self.v_pool,
+                 self.state) = self._decode_fn(
+                    self.params, self.k_pool, self.v_pool, self.state,
+                    block_table, tokens, self.pos, rows, keys,
+                )
+        sampled.copy_to_host_async()
+        chains.copy_to_host_async()
+        self._sampled = (sampled, chains)
+        self._fresh = np.zeros_like(self._fresh)
+        self.stats["decode_steps"] += 1
+        self.stats["decode_overlapped"] += self._unread is not None
+        self.pos = np.where(rows, self.pos + 1, self.pos)
+        self._left[rows] -= 1
+        reqs = list(self._slots)
+        last = rows & (self._left == 0)
+        for slot in np.flatnonzero(last).tolist():
+            req = reqs[slot]
+            req._key = np.array(self.keys[slot])
+            self._parting[req.id] = req
+            self._release_slot(slot)
+        return _Unread(sampled, chains, rows, reqs, last)
+
+    def _emit_step(self, step: _Unread, toks_host: np.ndarray) -> int:
+        """The engine's one emit loop, over a decode step's tokens as read
+        back: append and stream each row's token, end the streams that end
+        (EOS by value; length as settled at dispatch), keep the chain heads."""
+        keys_host = np.asarray(step.keys)
+        eos_id = self.serve.eos_id
+        decoded = 0
+        for slot in np.flatnonzero(step.rows).tolist():
+            req = step.reqs[slot]
+            if req.done:
+                continue    # ran a step past its EOS: that token is dropped
+            t = int(toks_host[slot])
+            req.generated.append(t)
+            decoded += 1
+            req._emit(t)
+            eos = eos_id is not None and t == eos_id
+            if self._slots[slot] is req:
+                self.keys[slot] = keys_host[slot]
+                if eos:
                     self._evict(slot, "eos")
-                elif len(req.generated) >= req.max_new_tokens:
-                    self._evict(slot, "length")
-            self.stats["tokens_out"] += decoded  # prefill firsts counted at emit
-        return emitted + decoded
+                continue
+            # The request left its slot when its last step was dispatched:
+            # this one, or the one still unread (its chain head stays with
+            # it, should that step be lost).
+            req._key = keys_host[slot]
+            if eos or step.last[slot]:
+                del self._parting[req.id]
+                req._finish("eos" if eos else "length")
+                self.stats["finished"] += 1
+        self.stats["tokens_out"] += decoded  # prefill firsts counted at emit
+        return decoded
 
     def _count_decode(self, was_active: np.ndarray) -> int:
         """Rows of this decode step, counted with the keys they attend and
@@ -2174,7 +2392,7 @@ class ServingEngine:
         terminates."""
         total = 0
         steps = 0
-        while self._queue or self._has_active():
+        while self.has_work():
             total += self.step()
             steps += 1
             if max_steps is not None and steps > max_steps:
@@ -2215,6 +2433,8 @@ class ServingEngine:
 
     def clear_prefix_cache(self) -> None:
         """Drop every unpinned prefix-cache entry and return its blocks
-        (bench isolation between warmup and the measured run)."""
+        (bench isolation between warmup and the measured run); whatever
+        was sampled before is read back and emitted first."""
+        self.collect()
         if self._cache is not None:
             self._cache.clear(self.allocator)

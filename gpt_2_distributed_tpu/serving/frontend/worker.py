@@ -946,6 +946,11 @@ class _WorkerState:
         """The step/drain reply: everything the frontend mirrors need to
         stay bit-equal to a preempt-at-this-boundary snapshot."""
         eng = self.engine
+        # The engine's decode loop runs a step ahead of its read-back; a
+        # reply is a snapshot, so what is unread is read (and streamed into
+        # `buf`) before anything of it is taken: tokens, finishes and chain
+        # heads then belong to one boundary.
+        emitted += eng.collect()
         events, self.buf = self.buf, []
         first, finished = {}, []
         for rid, h in list(self.handles.items()):

@@ -14,7 +14,10 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
     that is neither a prefill dispatch nor the decode step, of which
     ``admit_ms``, ``grow_ms`` and ``emit_ms`` are the clocked parts.
     ``decode_dispatch_ms`` + ``decode_wait_ms``: a decode step less its
-    draft pass, split where the decode program's call returns.
+    draft pass, split where the decode program's call returns; the wait
+    is for the read-back of the step BEFORE the one dispatched
+    (``decode_overlapped``: the share of decode steps dispatched while the
+    step before was still unread - how often the one-deep pipeline engaged).
     ``decode_rows``, ``decode_attended``: the rows a decode step advances
     and the keys they attend (of the selected blocks, where a layer
     selects). ``decode_blocks_live``, ``decode_blocks_table``: of those
@@ -53,6 +56,7 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
             total("decode_ms") - total("draft_ms")
             - total("decode_dispatch_ms")
         ) / decodes,
+        "decode_overlapped": total("decode_overlapped") / decodes,
         "decode_rows": total("decode_rows") / decodes,
         "decode_attended": total("decode_attended") / decodes,
         "decode_blocks_live": total("decode_blocks_live") / decodes,

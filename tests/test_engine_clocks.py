@@ -87,6 +87,12 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
         assert s["draft_ms"] == s["verify_ms"] == 0
     assert (s["grow_ms"] > 0) == (mode == "whole-watermark")
     assert s["decode_rows"] >= s["decode_steps"] and s["decode_attended"] > s["decode_rows"]
+    # how often a decode step went out over the step before, still unread:
+    # never in a speculative round, which decides on token values
+    if mode == "speculative":
+        assert s["decode_overlapped"] == 0
+    else:
+        assert 0 < s["decode_overlapped"] < s["decode_steps"]
     assert s["decode_rows"] <= s["decode_blocks_live"] <= s["decode_blocks_table"]
     # what /metrics and the --tb_dir sink show of them: means per step,
     # every one registered, and the fleet's the same as its one engine's
@@ -95,6 +101,7 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
                 "admit_ms": s["admit_ms"], "grow_ms": s["grow_ms"],
                 "emit_ms": s["emit_ms"]}
     per_decode = {"decode_dispatch_ms": s["decode_dispatch_ms"],
+                  "decode_overlapped": s["decode_overlapped"],
                   "decode_wait_ms": s["decode_ms"] - s["draft_ms"] - s["decode_dispatch_ms"],
                   "decode_rows": s["decode_rows"], "decode_attended": s["decode_attended"],
                   "decode_blocks_live": s["decode_blocks_live"],
@@ -107,6 +114,63 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
     assert snap["decode_wait_ms"] > 0
     assert step_clocks([s, s]) == pytest.approx(step_clocks([s]))
     assert all(key in METRIC_REGISTRY for key in snap)
+
+def test_a_step_unread_is_work_and_the_clocks_still_partition(tiny_params, tiny_config):
+    """Between two ``step()`` calls the decode loop leaves one step unread:
+    ``has_work()`` says so until its tokens are out, the turn that reads the
+    last one dispatches nothing, and with every turn counted ``decode`` holds
+    one dispatch and one read-back a decode step."""
+    eng = _engine("chunked", tiny_params, tiny_config)
+    h = eng.submit([1, 2, 3, 4, 5], 4, rng=0)
+    turns = []
+    while eng.has_work():
+        before = eng.stats["decode_steps"], len(h.generated)
+        eng.step()
+        turns.append((eng.stats["decode_steps"] - before[0],
+                      len(h.generated) - before[1], eng.occupancy, h.done))
+    # 2 chunks (the second opens the row and emits the first token), then
+    # three decode steps: the last leaves its slot as it is dispatched, and
+    # one more turn reads its token with nothing to dispatch
+    assert turns == [(0, 0, 1, False), (1, 1, 1, False), (1, 1, 1, False),
+                     (1, 1, 0, False), (0, 1, 0, True)]
+    s = eng.stats
+    assert (s["decode_steps"], s["decode_overlapped"], s["steps"]) == (3, 2, 5)
+    assert eng.collect() == 0 and s == eng.stats        # nothing left to read
+    parts = s["admit_ms"] + s["grow_ms"] + s["prefill_ms"] + s["decode_ms"] + s["emit_ms"]
+    assert s["step_ms"] >= parts > 0
+    assert eng.metrics_snapshot()["decode_overlapped"] == pytest.approx(2 / 3)
+
+
+@pytest.mark.parametrize("how", ["drain", "close"])
+def test_the_driver_leaves_nothing_unread_and_loses_no_token(how, tiny_params, tiny_config):
+    """``EngineDriver.drain()`` runs until the last token is out; ``close()``
+    in mid-run reads the unread step back, so what was sampled is streamed -
+    and a collect outside any step keeps the clocks a partition."""
+    from gpt_2_distributed_tpu.serving.frontend.driver import EngineDriver
+    from gpt_2_distributed_tpu.serving.frontend.router import ReplicaRouter
+
+    eng = _engine("chunked", tiny_params, tiny_config)
+    driver = EngineDriver(ReplicaRouter(lambda: eng, replicas=1))
+    streamed, finished = [], []
+    h = driver.submit(list(range(1, 8)), 6, rng=0,
+                      on_token=lambda req, t: streamed.append(t),
+                      on_finish=finished.append)
+    if how == "drain":
+        driver.drain()
+        assert h.done and len(h.generated) == 6 and finished == [h]
+    else:
+        while eng.stats["decode_overlapped"] < 2:
+            driver.step()
+        sampled = 1 + eng.stats["decode_steps"]       # the prefill's, then one a step
+        assert len(h.generated) == sampled - 1 and eng.has_work()
+    driver.close()
+    assert streamed == h.generated and eng.collect() == 0
+    if how == "close":
+        assert len(h.generated) == sampled
+    s = eng.stats
+    parts = s["admit_ms"] + s["grow_ms"] + s["prefill_ms"] + s["decode_ms"] + s["emit_ms"]
+    assert s["step_ms"] >= parts > 0
+
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_weight_bytes_and_the_engine_weights_event(

@@ -16,6 +16,7 @@ import pytest
 from gpt_2_distributed_tpu.config import ServeConfig
 from gpt_2_distributed_tpu.serving.engine import RequestHandle, ServingEngine
 from gpt_2_distributed_tpu.serving.families import family_of
+from tests import pipelined_cases
 from tests.conftest import REPO_ROOT
 from tests.test_nemotron_model import CONFIG, SIZES, raised, ref
 
@@ -141,8 +142,9 @@ def test_counters_on_an_example_reckoned_by_hand(weights, params):
 def test_the_counters_reach_the_host_behind_the_tokens(params):
     """Both step programs end in the sampled tokens with the family's device
     counters after them, one int32 a name: the engine takes them off in the
-    read-back it makes anyway, and a callback sees a token in the step that
-    sampled it."""
+    read-back it makes anyway - of the step BEFORE the one it has just
+    dispatched: a callback sees the second token once the third's step is
+    out, and the last in a turn that dispatches nothing."""
     from gpt_2_distributed_tpu.models.generate import sample_rows
     from gpt_2_distributed_tpu.serving import nemotron_programs, sala_programs
 
@@ -154,7 +156,7 @@ def test_the_counters_reach_the_host_behind_the_tokens(params):
     h = eng.submit(requests()[3], 6, rng=0, on_token=lambda req, t: emitted_at.setdefault(
         len(req.generated), eng.stats["decode_steps"]))
     eng.run_until_idle(max_steps=50)
-    assert [emitted_at[n] for n in range(1, 7)] == [0, 1, 2, 3, 4, 5] and h.done
+    assert [emitted_at[n] for n in range(1, 7)] == [0, 2, 3, 4, 5, 5] and h.done
     tokens, keys, eng.k_pool, eng.v_pool, eng.state = eng._decode_fn(
         eng.params, eng.k_pool, eng.v_pool, eng.state, eng.block_table, eng.tokens,
         eng.pos, np.zeros(3, bool), eng.keys)
@@ -171,6 +173,24 @@ def test_the_engine_holds_the_tree_it_was_given(params, fresh_tokens):
     assert at_bf16.state["ssm"].dtype == jnp.float32
     assert eng.metrics_snapshot()["weight_bytes"] == sum(
         a.nbytes for a in jax.tree_util.tree_leaves(params))
+
+
+@pytest.mark.parametrize("case", pipelined_cases.CASES)
+def test_pipelined_loop_serves_what_a_collecting_loop_does(case, weights, params):
+    """The engine dispatches decode step N+1 before it reads step N's tokens
+    back; the ids it serves are those of the same engine made to collect
+    after every dispatch (``tests/pipelined_cases.py``) - and, greedy, the
+    reference's best at every position."""
+    def make_engine(temperature=0.0, **serve):
+        return ServingEngine(params, CONFIG, serve_config(**serve),
+                             temperature=temperature, compute_dtype=jnp.float32)
+
+    ids = pipelined_cases.run(
+        case, make_engine, requests(),
+        squeeze=dict(max_batch=2, admission="watermark", num_blocks=21,
+                     watermark_blocks=0))
+    if case == "greedy":
+        assert_tokens_are_the_references(weights, requests(), ids)
 
 
 def test_a_reused_slot_serves_what_a_fresh_engine_does(params, fresh_tokens):

@@ -10,6 +10,7 @@ import pytest
 
 from gpt_2_distributed_tpu.config import ServeConfig
 from gpt_2_distributed_tpu.serving.engine import RequestHandle, ServingEngine
+from tests import pipelined_cases
 from tests.test_sala_model import CONFIG, SIZES, ref
 
 PROMPTS, NEW = (70, 41, 90, 9), (20, 30, 12, 40)
@@ -115,6 +116,24 @@ def test_the_engine_holds_the_tree_it_was_given(params, fresh_tokens):
     assert at_bf16.compute_dtype == jnp.bfloat16 and at_bf16.params is params
     assert eng.metrics_snapshot()["weight_bytes"] == sum(
         a.nbytes for a in jax.tree_util.tree_leaves(params))
+
+
+@pytest.mark.parametrize("case", pipelined_cases.CASES)
+def test_pipelined_loop_serves_what_a_collecting_loop_does(case, weights, params):
+    """The engine dispatches decode step N+1 before it reads step N's tokens
+    back; the ids it serves are those of the same engine made to collect
+    after every dispatch (``tests/pipelined_cases.py``) - and, greedy, the
+    reference's best at every position."""
+    def make_engine(temperature=0.0, **serve):
+        return ServingEngine(params, CONFIG, serve_config(**serve),
+                             temperature=temperature, compute_dtype=jnp.float32)
+
+    ids = pipelined_cases.run(
+        case, make_engine, requests(),
+        squeeze=dict(max_batch=2, admission="watermark", num_blocks=21,
+                     watermark_blocks=0))
+    if case == "greedy":
+        assert_tokens_are_the_references(weights, requests(), ids)
 
 
 def test_a_reused_slot_serves_what_a_fresh_engine_does(params, fresh_tokens):

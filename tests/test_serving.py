@@ -29,6 +29,9 @@ from gpt_2_distributed_tpu.serving import (
     PrefixCache,
     ServingEngine,
 )
+from gpt_2_distributed_tpu.serving.engine import RequestHandle
+
+import pipelined_cases
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -224,6 +227,67 @@ def test_engine_sampled_bit_matches_generate_cached(
         ref = _oneshot(tiny_params, tiny_config, p, k, n,
                        temperature=0.9, top_k=40, compute_dtype=dtype)
         assert h.generated == ref, h.id
+
+
+# ------------------------------------------- the one-deep decode pipeline
+
+
+@pytest.mark.parametrize("chunk", [0, 5], ids=["whole-prompt", "chunked"])
+@pytest.mark.parametrize("case", pipelined_cases.CASES)
+def test_pipelined_loop_serves_what_a_collecting_loop_does(
+        case, chunk, tiny_params, tiny_config):
+    """The engine dispatches decode step N+1 before it reads step N's tokens
+    back; the ids it serves are those of the same engine made to collect
+    after every dispatch (``tests/pipelined_cases.py``), and - greedy and
+    sampled - those of ``generate_cached(batch=1)``."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (5, 11, 17, 3)]
+
+    def make_engine(temperature=0.0, **serve):
+        return ServingEngine(
+            tiny_params, tiny_config, _serve(prefill_chunk=chunk, **serve),
+            temperature=temperature)
+
+    ids = pipelined_cases.run(
+        case, make_engine, prompts,
+        squeeze=dict(num_blocks=10, admission="watermark", watermark_blocks=1))
+    if case in ("greedy", "sampled"):
+        temperature = 0.8 if case == "sampled" else 0.0
+        for i, (p, got) in enumerate(zip(prompts, ids)):
+            assert got == _oneshot(
+                tiny_params, tiny_config, p, jax.random.PRNGKey(100 + i),
+                len(got), temperature=temperature), i
+
+
+def test_a_lost_engine_hands_back_the_request_whose_last_token_was_unread(
+        tiny_params, tiny_config):
+    """``extract_inflight`` on an engine whose device no longer answers: the
+    unread step is lost with it, and the request that had left its slot with
+    its last token in that step crosses with the slotted ones - its chain
+    head beside the tokens it has - and samples that token again elsewhere."""
+    prompts, new = [[1, 2, 3], [7, 8, 9, 10, 11]], (5, 12)
+    want = []
+    for i, (p, n) in enumerate(zip(prompts, new)):
+        want.append(_oneshot(tiny_params, tiny_config, p, jax.random.PRNGKey(i),
+                             n, temperature=0.9))
+    src, dst = (ServingEngine(tiny_params, tiny_config, _serve(), temperature=0.9)
+                for _ in range(2))
+    handles = [src.submit(p, n, rng=i) for i, (p, n) in enumerate(zip(prompts, new))]
+    while sum(not h.done for h in handles) == src.occupancy + src.queue_depth:
+        src.step()
+    assert not handles[0].done and len(handles[0].generated) == new[0] - 1
+
+    def lost(*args, **kw):
+        raise RuntimeError("device lost")
+
+    src._decode_turn = lost
+    moved = src.extract_inflight()
+    assert [h.id for h in moved] == [0, 1] and not src.has_work()
+    for h in moved:
+        dst.adopt(RequestHandle.from_wire(h.to_wire()))
+    moved = list(dst._queue)
+    dst.run_until_idle(max_steps=200)
+    assert [h.generated for h in moved] == want
 
 
 # ------------------------------------------------------ the weights it holds

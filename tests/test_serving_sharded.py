@@ -23,6 +23,7 @@ from gpt_2_distributed_tpu.serving import (
     ServingEngine,
 )
 
+import pipelined_cases
 from test_serving import _oneshot, _serve
 
 MESHES = ["data:4", "data:2,tp:2"]
@@ -220,6 +221,23 @@ def test_sharded_scheduler_churn_bit_parity(tiny_params, tiny_config,
     )
     assert got == expect
     assert eng._decode_fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("mesh", MESHES)
+@pytest.mark.parametrize("case", ["sampled", "eos"])
+def test_sharded_pipelined_loop_serves_what_a_collecting_loop_does(
+        case, mesh, tiny_params, tiny_config, prompts):
+    """The sharded engine runs the same one-deep decode pipeline (its
+    read-back is the tokens' all-gather; the step's outputs feed the next
+    step where they lie, row-sharded): the ids are those of the same engine
+    made to collect after every dispatch."""
+    def make_engine(temperature=0.0, **serve):
+        return ServingEngine(
+            tiny_params, tiny_config,
+            _serve(mesh=mesh, prefill_chunk=8, prefill_batch=2, **serve),
+            temperature=temperature)
+
+    pipelined_cases.run(case, make_engine, prompts[:4], squeeze=None)
 
 
 def test_migration_across_mesh_shapes(tiny_params, tiny_config, prompts,
