@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import os
 from dataclasses import dataclass
-from typing import Callable, NamedTuple
+from typing import Callable, ClassVar, NamedTuple
 
 # Row-chunk default for the blocked CE (ops/losses.py imports it back from
 # here). Defined in config — NOT in ops — so this module stays importable
@@ -808,6 +808,10 @@ class SalaConfig:
     initializer_range: float = 0.02
     sparse: SparseAttentionConfig = SparseAttentionConfig()
 
+    # This family's words in `refuse_for_state_family`.
+    recurrent_state: ClassVar[str] = "linear-attention state"
+    unsharded: ClassVar[str] = "grouped-query pools and the per-slot state"
+
     def __post_init__(self) -> None:
         object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
         bad = set(self.mixer_types) - {SPARSE_MIXER, LIGHTNING_MIXER}
@@ -838,6 +842,12 @@ class SalaConfig:
     @property
     def lightning_layers(self) -> tuple[int, ...]:
         return tuple(i for i, m in enumerate(self.mixer_types) if m == LIGHTNING_MIXER)
+
+    @property
+    def pool_block(self) -> int:
+        """One pool block is one selection block: the only block size the
+        engine may serve this family with."""
+        return self.sparse.block
 
     @property
     def kv_pool_view(self):
@@ -900,35 +910,6 @@ SALA_PRESETS: dict[str, SalaConfig] = {
 }
 
 
-def refuse_for_sala(config: SalaConfig, serve: "ServeConfig",
-                    speculative: bool = False) -> str | None:
-    """What the serving engine cannot do for a :class:`SalaConfig` yet, as
-    the sentence to refuse it with (None = it can serve). jax-free: the CLIs
-    call it at parse time, the engine at construction. ``serve`` is read by
-    attribute (``prefix_cache``, ``spec``, ``prefill_chunk``, ``mesh_devices``,
-    ``prefill_batch``, ``block_size``)."""
-    if serve.prefix_cache:
-        return ("prefix_cache: a hit would need a snapshot of the "
-                "linear-attention state at the block boundary")
-    if speculative or serve.spec:
-        return "speculative decoding: the two-model round is written for GPT-2"
-    if serve.prefill_chunk == 0:
-        return ("whole-prompt prefill (prefill_chunk=0): this family "
-                "prefills in chunks through the pools and the state")
-    if serve.mesh_devices > 1:
-        return ("a serving mesh (tp or data over 1): grouped-query pools "
-                "and the per-slot state have no sharding yet")
-    if serve.prefill_batch != 1:
-        return "prefill_batch over 1: a chunk dispatch carries one slot's state"
-    if serve.block_size != config.sparse.block:
-        return (f"block_size={serve.block_size}: one pool block is one "
-                f"selection block of {config.sparse.block} keys")
-    if serve.prefill_chunk % serve.block_size:
-        return (f"prefill_chunk={serve.prefill_chunk}: a chunk covers whole "
-                f"blocks of {serve.block_size}")
-    return None
-
-
 MAMBA_LAYER, ATTENTION_LAYER, EXPERT_LAYER = "M", "*", "E"
 
 
@@ -973,6 +954,11 @@ class NemotronHConfig:
     time_step_min: float = 0.001
     time_step_max: float = 0.1
     time_step_floor: float = 1e-4
+
+    # This family's words in `refuse_for_state_family`.
+    recurrent_state: ClassVar[str] = "state-space and convolution state"
+    unsharded: ClassVar[str] = ("the per-slot state and the expert layer (no "
+                                "expert-parallel axis, no exchange)")
 
     def __post_init__(self) -> None:
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
@@ -1026,6 +1012,11 @@ class NemotronHConfig:
     @property
     def n_held(self) -> int:
         return self.experts_held[1] - self.experts_held[0]
+
+    @property
+    def scan_chunk(self) -> int:
+        """Tokens of one sub-chunk of the chunked scan (``ops/ssd.py``)."""
+        return self.chunk_size
 
     @property
     def kv_pool_view(self):
@@ -1103,31 +1094,176 @@ NEMOTRON_PRESETS: dict[str, NemotronHConfig] = {
 }
 
 
-def refuse_for_nemotron(config: NemotronHConfig, serve: "ServeConfig",
-                        speculative: bool = False) -> str | None:
-    """What the serving engine cannot do for a :class:`NemotronHConfig` yet,
-    as the sentence to refuse it with (None = it can serve). jax-free, read
-    by attribute, like :func:`refuse_for_sala`."""
+@dataclass(frozen=True)
+class JambaConfig:
+    """Jamba (``models/jamba.py``): RMSNorm, no positions, a tied head, and in
+    every layer a mixer and a gated MLP - the mixer grouped-query attention
+    where ``i % attn_layer_period == attn_layer_offset``, a Mamba-1
+    selective-scan mixer (``ops/selective_scan.py``) otherwise. Field names
+    follow the published ``config.json``; ``num_experts`` is 1 (the dense
+    feed-forward in every layer: sparse experts of this family are not
+    written). ``first_layer``, the published index of the first layer held
+    (``cut``), is no published key."""
+
+    vocab_size: int = 65536
+    hidden_size: int = 2560
+    intermediate_size: int = 8192
+    num_hidden_layers: int = 28
+    attn_layer_period: int = 14
+    attn_layer_offset: int = 7
+    num_experts: int = 1
+    num_attention_heads: int = 20
+    num_key_value_heads: int = 1
+    head_dim: int = 128
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 160
+    rms_norm_eps: float = 1e-6
+    max_position_embeddings: int = 262144
+    tie_word_embeddings: bool = True
+    initializer_range: float = 0.02
+    time_step_min: float = 0.001
+    time_step_max: float = 0.1
+    first_layer: int = 0
+
+    # This family's words in `refuse_for_state_family`.
+    recurrent_state: ClassVar[str] = "state-space and convolution state"
+    unsharded: ClassVar[str] = "grouped-query pools and the per-slot state"
+
+    def __post_init__(self) -> None:
+        if self.num_experts != 1:
+            raise ValueError(
+                f"num_experts={self.num_experts}: only the dense feed-forward "
+                f"(num_experts=1) of this family is written")
+        if not self.tie_word_embeddings:
+            raise ValueError("tie_word_embeddings=False: the head is the embedding")
+        if self.num_attention_heads % self.num_key_value_heads:
+            raise ValueError(
+                f"num_attention_heads={self.num_attention_heads} must be a "
+                f"multiple of num_key_value_heads={self.num_key_value_heads}")
+        if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+            raise ValueError(
+                f"attn_layer_offset={self.attn_layer_offset} is no layer of a "
+                f"period of {self.attn_layer_period}")
+        if self.num_hidden_layers < 1 or self.first_layer < 0:
+            raise ValueError(
+                f"num_hidden_layers={self.num_hidden_layers} from layer "
+                f"{self.first_layer} on is no stack")
+
+    # What the engine and the generation checks read off any model config.
+    @property
+    def n_positions(self) -> int:
+        return self.max_position_embeddings
+
+    @property
+    def n_layer(self) -> int:
+        return self.num_hidden_layers
+
+    @property
+    def layer_kinds(self) -> str:
+        """One character a layer held, ``*`` attention and ``M`` Mamba."""
+        return "".join(
+            ATTENTION_LAYER if i % self.attn_layer_period == self.attn_layer_offset
+            else MAMBA_LAYER
+            for i in range(self.first_layer, self.first_layer + self.num_hidden_layers))
+
+    def layers_of(self, kind: str) -> tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.layer_kinds) if k == kind)
+
+    @property
+    def d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def kv_pool_view(self):
+        """What ``paged_cache.init_pools`` reads of a model: only the
+        attention layers hold K/V, in ``num_key_value_heads`` heads."""
+        import types
+
+        return types.SimpleNamespace(
+            n_layer=len(self.layers_of(ATTENTION_LAYER)),
+            n_head=self.num_key_value_heads, head_dim=self.head_dim)
+
+    def replace(self, **kwargs) -> "JambaConfig":
+        return dataclasses.replace(self, **kwargs)
+
+    def cut(self, n_layer: int, first: int = 0) -> "JambaConfig":
+        """``n_layer`` consecutive layers of this stack from layer ``first``
+        on; each keeps the kind its published index gives it."""
+        if n_layer < 1 or first < 0 or first + n_layer > self.num_hidden_layers:
+            raise ValueError(
+                f"layers [{first}, {first + n_layer}) are not within the "
+                f"stack's {self.num_hidden_layers}")
+        return self.replace(num_hidden_layers=n_layer,
+                            first_layer=self.first_layer + first)
+
+    def layer_params(self, kind: str) -> int:
+        """Parameters of one layer of ``kind``: its mixer, its gated MLP and
+        its two norms."""
+        c, d, n, r = self.hidden_size, self.d_inner, self.mamba_d_state, self.mamba_dt_rank
+        mlp = 2 * c + 3 * c * self.intermediate_size
+        if kind == MAMBA_LAYER:
+            return (mlp + c * 2 * d + d * (self.mamba_d_conv + 1) + d * (r + 2 * n)
+                    + r + 2 * n + r * d + d + d * n + d + d * c)
+        a = self.num_attention_heads * self.head_dim
+        kv = self.num_key_value_heads * self.head_dim
+        return mlp + 2 * c * a + 2 * c * kv
+
+    def num_params(self, include_embeddings: bool = True) -> int:
+        n = self.hidden_size + sum(self.layer_params(k) for k in self.layer_kinds)
+        if include_embeddings:
+            n += self.vocab_size * self.hidden_size       # tied: counted once
+        return n
+
+
+JAMBA_PRESETS: dict[str, JambaConfig] = {
+    # ai21labs/AI21-Jamba2-3B config.json: 28 layers, attention at 7 and 21.
+    "jamba2-3b": JambaConfig(),
+    # The CPU tests' size: M M * M, one KV head under four query heads.
+    "jamba-tiny": JambaConfig(
+        vocab_size=257, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=4, attn_layer_period=4, attn_layer_offset=2,
+        num_attention_heads=4, num_key_value_heads=1, head_dim=16,
+        mamba_d_state=16, mamba_dt_rank=8, max_position_embeddings=4096),
+}
+
+
+def refuse_for_state_family(config, serve: "ServeConfig",
+                            speculative: bool = False) -> str | None:
+    """What the serving engine cannot do yet for a family that keeps a
+    recurrent state beside the paged pools (every row of ``FAMILY_FLAGS``), as
+    the sentence to refuse it with (None = it can serve). jax-free: the CLIs
+    call it at parse time, the engine at construction. ``serve`` is read by
+    attribute (``prefix_cache``, ``spec``, ``prefill_chunk``, ``mesh_devices``,
+    ``prefill_batch``, ``block_size``); of ``config`` its family's own words
+    (``recurrent_state``, ``unsharded``) and, where it has them, the one block
+    size its pools take (``pool_block``) and the tokens of a sub-chunk of its
+    scan (``scan_chunk``)."""
     if serve.prefix_cache:
-        return ("prefix_cache: a hit would need a snapshot of the state-space "
-                "and convolution state at the block boundary")
+        return (f"prefix_cache: a hit would need a snapshot of the "
+                f"{config.recurrent_state} at the block boundary")
     if speculative or serve.spec:
         return "speculative decoding: the two-model round is written for GPT-2"
     if serve.prefill_chunk == 0:
         return ("whole-prompt prefill (prefill_chunk=0): this family "
                 "prefills in chunks through the pools and the state")
     if serve.mesh_devices > 1:
-        return ("a serving mesh (tp or data over 1): the per-slot state and "
-                "the expert layer have no sharding yet (no expert-parallel "
-                "axis, no exchange)")
+        return (f"a serving mesh (tp or data over 1): {config.unsharded} "
+                f"have no sharding yet")
     if serve.prefill_batch != 1:
         return "prefill_batch over 1: a chunk dispatch carries one slot's state"
+    block = getattr(config, "pool_block", None)
+    if block is not None and serve.block_size != block:
+        return (f"block_size={serve.block_size}: one pool block is one "
+                f"selection block of {block} keys")
     if serve.prefill_chunk % serve.block_size:
         return (f"prefill_chunk={serve.prefill_chunk}: a chunk covers whole "
                 f"blocks of {serve.block_size}")
-    if serve.prefill_chunk % config.chunk_size:
+    sub = getattr(config, "scan_chunk", None)
+    if sub is not None and serve.prefill_chunk % sub:
         return (f"prefill_chunk={serve.prefill_chunk}: a chunk covers whole "
-                f"sub-chunks of the scan, chunk_size={config.chunk_size}")
+                f"sub-chunks of the scan, {sub} tokens each")
     return None
 
 
@@ -1144,8 +1280,9 @@ class FamilyFlags(NamedTuple):
 
 
 FAMILY_FLAGS = (
-    FamilyFlags(SalaConfig, SALA_PRESETS, refuse_for_sala),
-    FamilyFlags(NemotronHConfig, NEMOTRON_PRESETS, refuse_for_nemotron),
+    FamilyFlags(SalaConfig, SALA_PRESETS, refuse_for_state_family),
+    FamilyFlags(NemotronHConfig, NEMOTRON_PRESETS, refuse_for_state_family),
+    FamilyFlags(JambaConfig, JAMBA_PRESETS, refuse_for_state_family),
 )
 FAMILY_MODELS = tuple(m for row in FAMILY_FLAGS for m in sorted(row.presets))
 
@@ -1175,7 +1312,7 @@ def validate_model_flags(p, args) -> None:
     """Parse-time check of the model flags for a model of a family beside
     GPT-2 (:data:`FAMILY_FLAGS`), jax-free like the rest of this file: sizes
     that are GPT-2's, a checkpoint (these families have no trainer, so no
-    checkpoint format), and every engine option the family's ``refuse_for_...``
+    checkpoint format), and every engine option that ``refuse_for_state_family``
     names are refused before any CLI pays the jax import."""
     import types
 

@@ -290,6 +290,8 @@ for _name, _dist in (
     ("moe_experts_touched", "mean"),   # held experts a dispatch gives a row, over layers
     ("moe_expert_slots", "mean"),      # held experts a dispatch asks, over layers
     ("ssm_rows", "mean"),              # live rows a decode step's state updates take
+    ("sscan_tokens", "mean"),          # tokens a prefill dispatch's selective scans take
+    ("sscan_rows", "mean"),            # live rows a decode step's selective-scan updates take
 ):
     METRIC_REGISTRY.metric(
         _name, reduction=ReductionStrategy.CURRENT, tb_prefix="serve/",

@@ -44,6 +44,7 @@ from gpt_2_distributed_tpu.config import (
 )
 from gpt_2_distributed_tpu.models.minicpm_sala import rms_norm
 from gpt_2_distributed_tpu.ops import moe, ssd
+from gpt_2_distributed_tpu.ops.attention import causal_grouped_attention
 
 
 # --- parameters -------------------------------------------------------------
@@ -223,18 +224,6 @@ def logits_of(config: NemotronHConfig, params, h):
 # --- the plain dense forward -------------------------------------------------
 
 
-def _dense_attention(q, k, v):
-    """One sequence's causal grouped-query attention: [T, H, d] over [T, KV, d]."""
-    t, heads, d = q.shape
-    kv = k.shape[1]
-    qg = q.reshape(t, kv, heads // kv, d)
-    s = jnp.einsum("tkgd,skd->kgts", qg, k,
-                   preferred_element_type=jnp.float32) / math.sqrt(d)
-    s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -jnp.inf)
-    o = jnp.einsum("kgts,skd->tkgd", jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
-    return o.reshape(t, heads * d)
-
-
 def forward(params, config: NemotronHConfig, ids, router_dtype=jnp.float32):
     """[B, T] token ids -> [B, T, V] float32 logits, every position, with
     nothing cached: each mixer over the whole sequence at once, a row at a
@@ -262,7 +251,7 @@ def forward(params, config: NemotronHConfig, ids, router_dtype=jnp.float32):
                      config.ssm_state_size), jnp.float32), sub)
                 out = ssm_out(lp, ssm_gate(config, lp, y, xs, z))
             else:
-                out = _dense_attention(*attention_qkv(config, lp, x)) @ lp["wo"]
+                out = causal_grouped_attention(*attention_qkv(config, lp, x)) @ lp["wo"]
             h = h + out.astype(jnp.float32)
         return logits_of(config, params, h)
 

@@ -76,6 +76,22 @@ def causal_attention_bthd(
     return out.transpose(0, 2, 1, 3)
 
 
+def causal_grouped_attention(q, k, v):
+    """One sequence's causal grouped-query attention, dense: q [T, H, d] over
+    k and v [T, KV, d], softmax at 1/sqrt(d) in float32; [T, H * d]. What the
+    layer-pattern families' plain forwards (``models/nemotron_h.py``,
+    ``models/jamba.py``) attend with; their serving programs go through the
+    paged pools."""
+    t, heads, d = q.shape
+    kv = k.shape[1]
+    qg = q.reshape(t, kv, heads // kv, d)
+    s = jnp.einsum("tkgd,skd->kgts", qg, k,
+                   preferred_element_type=jnp.float32) / jnp.sqrt(jnp.float32(d))
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -jnp.inf)
+    o = jnp.einsum("kgts,skd->tkgd", jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
+    return o.reshape(t, heads * d)
+
+
 def _ring_mesh():
     """The active mesh when its 'sp' axis is >1 (ring attention applies)."""
     from gpt_2_distributed_tpu.parallel.mesh import SP_AXIS, active_mesh

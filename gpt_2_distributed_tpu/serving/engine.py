@@ -63,15 +63,18 @@ is the serving-shaped alternative:
   its ``max_new_tokens``-th leaves its slot and blocks at once. What does
   hang on it is read first or thrown away: with ``eos_id`` set a row may
   run one step past its EOS, and that step's token is dropped (the position
-  it wrote lies in the row's own blocks). Whatever needs the host to hold
-  every sampled token - a prefill dispatch (it waits for its own first
-  token), preemption, deadline eviction, migration, ``decode_keys``,
-  ``clear_prefix_cache``, ``close`` - reads the unread step back first
-  (``collect``); the speculative round decides on values and never leaves a
-  step unread. ``has_work`` is true while a step is unread. The emitted ids
-  are those of an engine that collects after every dispatch, greedy or
-  sampled: ``stats["decode_overlapped"]`` of ``stats["decode_steps"]`` were
-  dispatched over an unread step.
+  it wrote lies in the row's own blocks). A prefill chunk goes the same way:
+  it is dispatched behind the step in flight, the next decode step behind
+  it, and its first token is read after that step's read-back, so the row
+  it opens joins the decode step after (under watermark admission, whose
+  growth may preempt the chunk's row, it is settled at once). Whatever needs
+  the host to hold every sampled token - preemption, deadline eviction,
+  migration, ``decode_keys``, ``clear_prefix_cache``, ``close`` - reads the
+  unread step back first (``collect``); the speculative round decides on
+  values and never leaves a step unread. ``has_work`` is true while a step
+  is unread. The emitted ids are those of an engine that collects after
+  every dispatch, greedy or sampled: ``stats["decode_overlapped"]`` of
+  ``stats["decode_steps"]`` were dispatched over an unread step.
 
 * **Multi-chip serving** (``ServeConfig.mesh``, e.g. ``"data:4"`` or
   ``"data:2,tp:2"``): the engine builds a data×tp mesh
@@ -205,6 +208,16 @@ class _Unread(NamedTuple):
     reqs: list             # who held each slot when it was dispatched
     last: np.ndarray       # [B] bool - rows whose token in it is their
                            # max_new_tokens-th: they left their slots there
+
+
+class _Chunk(NamedTuple):
+    """A prefill dispatch whose first tokens the host has not read yet."""
+
+    first: jax.Array       # [R + counters] the rows' sampled first tokens
+    keys: jax.Array        # [R, 2] their advanced chains
+    slots: list            # the slots it advanced, row by row
+    lens: list             # the real tokens of each row's chunk
+    at: float              # time.monotonic() as it was dispatched
 
 
 # Version tag of the serialized request form (`RequestHandle.to_wire`).
@@ -1068,6 +1081,7 @@ class ServingEngine:
         self._fresh = np.zeros((serve.max_batch,), bool)
         self._left = np.zeros((serve.max_batch,), np.int64)
         self._unread: _Unread | None = None
+        self._turned_at = 0.0   # when the last decode turn had dispatched and read
         self._parting: dict[int, RequestHandle] = {}
         self._feed_fn = jax.jit(_program("decode_feed", _feed_impl), **feed_kw)
 
@@ -1152,9 +1166,11 @@ class ServingEngine:
             # experts that got a row (both from the device, behind the
             # tokens in their read-back), and held experts, each summed over
             # the expert layers. ssm_rows: a decode step's live rows through
-            # a state-space update, summed over those layers.
+            # a state-space update, summed over those layers. sscan_tokens /
+            # sscan_rows: a chunk's real tokens and a decode step's live rows
+            # through a selective scan, summed over those layers.
             "moe_rows": 0, "moe_experts_touched": 0, "moe_expert_slots": 0,
-            "ssm_rows": 0,
+            "ssm_rows": 0, "sscan_tokens": 0, "sscan_rows": 0,
         }
 
         # Per-engine jits so tests can count THIS engine's compilations:
@@ -1600,16 +1616,22 @@ class ServingEngine:
     def _prefill_rows(self, slots: list[int], width: int,
                       pad_rows: int) -> int:
         """Advance one prefill chunk for each slot in ``slots`` in ONE
-        batched dispatch (rows padded to ``pad_rows`` with ``clen=0`` so
+        batched dispatch and wait for it. Returns tokens emitted."""
+        return self._settle_chunk(self._dispatch_chunk(slots, width, pad_rows))
+
+    def _dispatch_chunk(self, slots: list[int], width: int,
+                        pad_rows: int) -> _Chunk:
+        """Dispatch one prefill chunk for each slot in ``slots`` in ONE
+        batched program call (rows padded to ``pad_rows`` with ``clen=0`` so
         the program's shape — and so its compile — is independent of how
-        many prefills happen to be in flight). Returns tokens emitted."""
+        many prefills happen to be in flight). The call returns async:
+        ``_settle_chunk`` reads its first tokens."""
         r = max(pad_rows, len(slots))
         bt = np.zeros((r, self._m), np.int32)
         chunk = np.zeros((r, width), np.int32)
         start = np.zeros((r,), np.int32)
         clen = np.zeros((r,), np.int32)
         keys = np.zeros((r, 2), np.uint32)
-        cls: list[int] = []
         for i, slot in enumerate(slots):
             req = self._slots[slot]
             s = req._prefill_pos
@@ -1619,7 +1641,6 @@ class ServingEngine:
             start[i] = s
             clen[i] = cl
             keys[i] = req._key
-            cls.append(cl)
         self._count_prefill(start, clen)
         t0 = time.monotonic()
         with self._mesh_scope():
@@ -1635,18 +1656,31 @@ class ServingEngine:
                     bt, chunk, start, clen, keys,
                     np.asarray(slots, np.int32),
                 )
-        first.block_until_ready()
-        dur_ms = (time.monotonic() - t0) * 1e3
-        first_host = self._count_behind(np.asarray(first), r)
-        keys_host = np.asarray(out_keys)
+        return _Chunk(first, out_keys, slots, clen[:len(slots)].tolist(), t0)
+
+    def _settle_chunk(self, chunk: _Chunk) -> int:
+        """Wait for a dispatched chunk and settle its rows: a prompt with
+        tokens left moves on, one that is through opens its decode row with
+        the first token sampled. Returns tokens emitted.
+
+        prefill_ms holds the chunk and nothing else: its clock starts where
+        the device does, at the dispatch or - where a decode step was still
+        running then - at that step's read-back, the end of the decode
+        turn's own clock (``_turned_at``). The turn's emit loop runs beside
+        the chunk and is on both clocks."""
+        chunk.first.block_until_ready()
+        dur_ms = (time.monotonic() - max(chunk.at, self._turned_at)) * 1e3
+        r = chunk.keys.shape[0]
+        first_host = self._count_behind(np.asarray(chunk.first), r)
+        keys_host = np.asarray(chunk.keys)
         self.stats["prefill_ms"] += dur_ms
         self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_batched"] += max(len(slots) - 1, 0)
+        self.stats["prefill_batched"] += max(len(chunk.slots) - 1, 0)
         tracer = get_tracer()
         emitted = 0
-        for i, slot in enumerate(slots):
+        for i, slot in enumerate(chunk.slots):
             req = self._slots[slot]
-            cl = cls[i]
+            cl = chunk.lens[i]
             self.stats["prefill_chunks"] += 1
             tracer.event(
                 "prefill_chunk", rid=req.id, n_tokens=cl, dur_ms=dur_ms,
@@ -1726,15 +1760,16 @@ class ServingEngine:
         )
         return [s for _, s in cands[:self.serve.prefill_batch]]
 
-    def _prefill_tick(self, slots: list[int]) -> int:
-        """Chunked mode: advance ``slots`` by one chunk each, in ONE
+    def _prefill_tick(self, slots: list[int]) -> _Chunk | None:
+        """Chunked mode: dispatch one chunk for each of ``slots``, in ONE
         batched dispatch per engine step; decode steps interleave between
         chunks, which is the whole point. Rows pad to ``prefill_batch`` so
         the dispatch compiles once regardless of how many prefills are in
-        flight (``prefill_batch=1`` is exactly the old one-row tick)."""
+        flight (``prefill_batch=1`` is exactly the old one-row tick). The
+        step settles what this returns (``_settle_chunk``)."""
         if not slots:
-            return 0
-        return self._prefill_rows(
+            return None
+        return self._dispatch_chunk(
             slots, self.serve.prefill_chunk, self.serve.prefill_batch
         )
 
@@ -1990,12 +2025,13 @@ class ServingEngine:
         return self._cache
 
     def step(self) -> int:
-        """One engine step: admit what fits, advance one prefill chunk
+        """One engine step: admit what fits, dispatch one prefill chunk
         (chunked mode), grow/preempt block tables (watermark mode), then
         one turn of the decode loop: dispatch the compiled decode step for
         every active row, and read back and emit the tokens of the step
-        dispatched the turn BEFORE, while this one runs. Returns tokens
-        emitted this step (prefill first-tokens + the samples read back)."""
+        dispatched the turn BEFORE, while this one runs; last, read the
+        chunk's first token. Returns tokens emitted this step (the samples
+        read back + prefill first-tokens)."""
         if not self.has_work():
             return 0
         tracer = get_tracer()
@@ -2046,15 +2082,23 @@ class ServingEngine:
             self._try_admit()
         # Whole-prompt mode prefills inside admission: that is prefill_ms.
         self.stats["admit_ms"] -= self.stats["prefill_ms"] - prefill_ms
-        # A prefill dispatch waits for its own first token, which the device
-        # computes after the decode step in flight: read that step back
-        # first, so that prefill_ms holds the chunk and nothing else. The
-        # loop then runs one step ahead between consecutive chunk-free steps.
+        # A prefill chunk is dispatched behind the decode step in flight and
+        # settled after this step's decode turn: the device goes from that
+        # step to the chunk to the next decode step while the host reads,
+        # emits and builds. The chunk's row joins the decode step after its
+        # first token is read. Where something below acts on the chunk's row
+        # before that - a speculative round, a growth that may preempt it -
+        # the chunk is settled at once, behind a read-back of the step in
+        # flight so that prefill_ms still holds the chunk and nothing else.
         slots = self._prefill_slots()
-        if slots and self._unread is not None:
+        at_once = bool(self._spec_k) or self.serve.admission == "watermark"
+        if slots and at_once and self._unread is not None:
             emitted += self._decode_turn(tracer, dispatch=False)
         with tracer.span("prefill"):
-            emitted += self._prefill_tick(slots)
+            chunk = self._prefill_tick(slots)
+            if chunk is not None and at_once:
+                emitted += self._settle_chunk(chunk)
+                chunk = None
         if self.serve.admission == "watermark" and self.active.any():
             with self._phase(tracer, "grow", "grow_ms"):
                 grown = self._grow_tables()
@@ -2071,6 +2115,9 @@ class ServingEngine:
             return emitted + self._spec_round(tracer)
         if dispatch or self._unread is not None:
             emitted += self._decode_turn(tracer, dispatch)
+        if chunk is not None:
+            with tracer.span("prefill"):
+                emitted += self._settle_chunk(chunk)
         return emitted
 
     def _decode_turn(self, tracer, dispatch: bool) -> int:
@@ -2088,14 +2135,16 @@ class ServingEngine:
         rows = self.active if dispatch else unread.rows
         with self._phase(tracer, "decode", "decode_ms", rows=int(rows.sum())):
             self._unread = self._dispatch_decode(tracer) if dispatch else None
-            if unread is None:
-                return 0
-            with tracer.span(
-                "readback" if self.mesh is None else "token_allgather",
-                rows=int(unread.rows.sum()),
-            ):
-                toks_host = self._count_behind(
-                    np.asarray(unread.tokens), self.serve.max_batch)
+            if unread is not None:
+                with tracer.span(
+                    "readback" if self.mesh is None else "token_allgather",
+                    rows=int(unread.rows.sum()),
+                ):
+                    toks_host = self._count_behind(
+                        np.asarray(unread.tokens), self.serve.max_batch)
+        self._turned_at = time.monotonic()
+        if unread is None:
+            return 0
         with self._phase(tracer, "emit", "emit_ms"):
             return self._emit_step(unread, toks_host)
 

@@ -45,6 +45,7 @@ from gpt_2_distributed_tpu.config import (
     FAMILY_FLAGS,
     MAMBA_LAYER,
     FamilyFlags,
+    JambaConfig,
     NemotronHConfig,
     SalaConfig,
 )
@@ -93,6 +94,16 @@ def _nemotron_rows(stats: dict, config: NemotronHConfig, block_size: int,
     return dense_attended(positions)
 
 
+def _jamba_rows(stats: dict, config: JambaConfig, block_size: int,
+                positions: np.ndarray, decode: bool) -> int:
+    """Every real token of a chunk goes through the selective scan of every
+    Mamba layer (``sscan_tokens``), every live row of a decode step through
+    its one-token update (``sscan_rows``)."""
+    stats["sscan_rows" if decode else "sscan_tokens"] += \
+        len(positions) * len(config.layers_of(MAMBA_LAYER))
+    return dense_attended(positions)
+
+
 _FLAGS = {row.config_type: row for row in FAMILY_FLAGS}
 
 
@@ -123,8 +134,21 @@ def _nemotron() -> Family:
         count_rows=_nemotron_rows)
 
 
+def _jamba() -> Family:
+    from gpt_2_distributed_tpu.models import jamba
+    from gpt_2_distributed_tpu.serving import jamba_programs
+
+    return Family(
+        name="a JambaConfig", flags=_FLAGS[JambaConfig],
+        init_params=jamba.init_params,
+        init_state=jamba_programs.init_state,
+        chunk_impl=jamba_programs.chunk_prefill_impl,
+        decode_impl=jamba_programs.decode_step_impl,
+        count_rows=_jamba_rows)
+
+
 _BUILDERS: dict[type, Callable[[], Family]] = {
-    SalaConfig: _sala, NemotronHConfig: _nemotron}
+    SalaConfig: _sala, NemotronHConfig: _nemotron, JambaConfig: _jamba}
 
 
 @functools.cache

@@ -33,8 +33,10 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
     token-expert pairs that fell to the experts held here, the held experts
     that got a row and the held experts, each summed over the expert layers.
     ``ssm_rows``: per decode step, live rows through a state-space update,
-    summed over those layers. A ``stats`` that predates a counter reads 0
-    there."""
+    summed over those layers. ``sscan_tokens``: per prefill dispatch, real
+    tokens through a selective scan; ``sscan_rows``: per decode step, live
+    rows through its one-token update; both summed over those layers. A
+    ``stats`` that predates a counter reads 0 there."""
     all_stats = list(all_stats)
 
     def total(key: str) -> float:
@@ -71,4 +73,6 @@ def step_clocks(all_stats: Iterable[Mapping[str, float]]) -> dict[str, float]:
         "moe_experts_touched": total("moe_experts_touched") / dispatches,
         "moe_expert_slots": total("moe_expert_slots") / dispatches,
         "ssm_rows": total("ssm_rows") / decodes,
+        "sscan_tokens": total("sscan_tokens") / prefills,
+        "sscan_rows": total("sscan_rows") / decodes,
     }

@@ -1,7 +1,8 @@
 """The decode loop's one-deep pipeline against the same engine made to collect
 after every dispatch: the cases every served family goes through
 (``test_serving.py``, ``test_sala_serving.py``, ``test_nemotron_serving.py``,
-``test_serving_sharded.py``), each a case of one parametrised test there.
+``test_jamba_serving.py``, ``test_serving_sharded.py``), each a case of one
+parametrised test there.
 
 A family's file hands ``run`` a ``make_engine(temperature=0.0, **serve)``
 and four prompts; a case serves the same requests twice on ONE engine (its
@@ -102,6 +103,22 @@ def freed_slot(make_engine, prompts, **_):
     return [h.generated for h in lagged]
 
 
+def chunk_steps(make_engine, prompts, **_):
+    """Chunked prefill beside a decoding row: a step that dispatches a chunk
+    does not read the decode step in flight back first. The chunk goes out
+    behind that step and the next decode step behind the chunk, so while a
+    row decodes every decode step but the first is dispatched over an unread
+    one; the chunk's row joins the step after its first token is read."""
+    eng = make_engine(max_batch=2)
+    new = (40, 6, 5)       # the first decodes on while the two others come and go
+    lagged, stats, _ = both_ways(eng, prompts[:3], new)
+    assert [len(h.generated) for h in lagged] == list(new)
+    assert stats["prefill_dispatches"] > 2
+    if eng.serve.prefill_chunk:
+        assert stats["decode_overlapped"] == stats["decode_steps"] - 1
+    return [h.generated for h in lagged]
+
+
 def eos(make_engine, prompts, **_):
     """``eos_id`` set and hit in mid-stream: the row has run one step past
     it by the time the host reads it; that step's token is dropped, the
@@ -180,6 +197,7 @@ CASES = {
     "greedy": greedy,
     "sampled": sampled,
     "freed-slot": freed_slot,
+    "chunk-steps": chunk_steps,
     "eos": eos,
     "preempted": preempted,
     "migrated": migrated,
