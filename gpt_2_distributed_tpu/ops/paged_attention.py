@@ -437,15 +437,201 @@ def paged_attention(
 
 # --- grouped queries over a SELECTED list of blocks ---------------------------
 #
-# A sparse layer's row attends over a list of its blocks, not over its whole
-# table, and ``G`` query heads share each KV head. Two forms, both XLA:
-# decode rows gather exactly their listed blocks (the bytes the selection
-# exists to save); a prefill chunk, whose thousands of queries each list other
-# blocks and together list nearly all, walks the row's table in tiles under a
-# mask, which keeps the products on the MXU. ``paged_attention`` above is
-# untouched by either: at ``kv_heads == heads`` it compiles to what it did.
+# A row of the state families' decode step attends over a list of its blocks
+# (the whole table as far as the row has got, or a sparse layer's selection),
+# and ``G`` query heads share each KV head. Decode has two forms, one contract:
+# on a TPU the kernel ``paged_gqa_decode`` reads each (row, KV head)'s listed
+# tiles straight from the pools and nothing past ``count``; elsewhere
+# ``paged_sparse_attention`` gathers the listed blocks in XLA - the tests'
+# reference and the path off the chip. A prefill chunk, whose thousands of
+# queries each list other blocks and together list nearly all, walks the row's
+# table in tiles under a mask in XLA (``paged_masked_attention``), which keeps
+# the products on the MXU. ``paged_attention`` above is untouched by any of
+# them.
 
 _MASKED = -1e30   # finite, so a row with nothing selected in a tile stays NaN-free
+
+
+def _gqa_decode_kernel(
+    layer_ref,    # scalar prefetch: [1] int32
+    blocks_ref,   # scalar prefetch: [B * KV * W] int32 pool blocks, as listed
+    logical_ref,  # scalar prefetch: [B * KV * W] int32 their index in the sequence
+    count_ref,    # scalar prefetch: [B * KV] int32 entries in use, at most W
+    pos_ref,      # scalar prefetch: [B] int32 query positions
+    q_ref,        # [1, 1, G, D]
+    k_hbm,        # [L * N, KV, bs, D] the pool, left where it is
+    v_hbm,
+    o_ref,        # [1, 1, G, D]
+    k_buf,        # VMEM [2, P, bs, D]: two groups of P tiles, one being read
+    v_buf,        #   while the other fills
+    sem,          # DMA semaphores [2, 2]: (K, V) x buffer
+    ring,         # SMEM [2] int32: the buffer the step's first group goes to,
+                  #   and whether the step before already started it
+    *,
+    per_group: int,
+    n_blocks: int,
+):
+    """One (row, KV head) a grid step, its list walked ``P`` tiles at a time.
+    The grid runs in order, and the last group of a step starts the copies of
+    the next step's first, so the copies run ahead across rows too."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    kv = pl.num_programs(1)
+    steps = pl.num_programs(0) * kv
+    row = b * kv + h
+    width = blocks_ref.shape[0] // steps
+    bs, d = k_buf.shape[2], k_buf.shape[3]
+    span = per_group * bs
+
+    def copies(at, g, buf, start):
+        """Start, or wait for, the copies of group ``g`` of step ``at``: the
+        K and V tiles of its blocks in use, into buffer ``buf``."""
+        def one(i, carry):
+            blk = blocks_ref[at * width + g * per_group + i] + layer_ref[0] * n_blocks
+            for j, (pool, into) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                copy = pltpu.make_async_copy(
+                    pool.at[blk, at % kv], into.at[buf, i], sem.at[j, buf])
+                if start:
+                    copy.start()
+                else:
+                    copy.wait()
+            return carry
+
+        n = jnp.minimum(per_group, count_ref[at] - g * per_group)
+        jax.lax.fori_loop(0, n, one, 0)
+
+    @pl.when(row == 0)
+    def _reset():
+        ring[0] = 0
+        ring[1] = 0
+
+    n = count_ref[row]
+    groups = (n + per_group - 1) // per_group
+    after = jnp.minimum(row + 1, steps - 1)
+    next_n = jnp.where(row + 1 < steps, count_ref[after], 0)
+
+    @pl.when((ring[1] == 0) & (groups > 0))
+    def _first():
+        copies(row, 0, ring[0], True)
+
+    q = q_ref[0, 0]                                          # [G, D]
+    pos = pos_ref[b]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    tile_of, key_of = lane // bs, lane % bs
+
+    def group(g, carry):
+        top, total, acc, buf = carry
+        other = 1 - buf
+        more = g + 1 < groups
+
+        @pl.when(more | (next_n > 0))
+        def _ahead():     # this step's next group, or the next step's first
+            copies(jnp.where(more, row, after), jnp.where(more, g + 1, 0), other, True)
+
+        copies(row, g, buf, False)
+        used = n - g * per_group      # tiles of this group in use, 1..P
+
+        def clear(i, c):
+            # an unfilled tile holds whatever VMEM held: its weight is 0, and
+            # 0 * NaN is not
+            v_buf[buf, i] = jnp.zeros((bs, d), v_buf.dtype)
+            return c
+
+        jax.lax.fori_loop(used, per_group, clear, 0)
+
+        def last_key(i, edge):
+            """Per lane, the last key offset its tile may attend (-1: none)."""
+            at = row * width + jnp.minimum(g * per_group + i, width - 1)
+            mine = jnp.where(i < used, pos - logical_ref[at] * bs, -1)
+            return jnp.where(tile_of == i, mine, edge)
+
+        edge = jax.lax.fori_loop(
+            0, per_group, last_key, jnp.full((1, span), -1, jnp.int32))
+        keep = key_of <= edge                                # [1, P*bs]
+        k = k_buf[buf].reshape(span, d)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) / (d ** 0.5)                                       # [G, P*bs] f32
+        s = jnp.where(keep, s, _MASKED)
+        new_top = jnp.maximum(top, s.max(axis=1, keepdims=True))
+        p = jnp.where(keep, jnp.exp(s - new_top), 0.0)
+        alpha = jnp.exp(top - new_top)
+        v = v_buf[buf].reshape(span, d)
+        acc = acc * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return new_top, total * alpha + p.sum(axis=1, keepdims=True), acc, other
+
+    g_heads = q.shape[0]
+    top, total, acc, buf = jax.lax.fori_loop(0, groups, group, (
+        jnp.full((g_heads, 1), _MASKED, jnp.float32),
+        jnp.zeros((g_heads, 1), jnp.float32),
+        jnp.zeros((g_heads, d), jnp.float32),
+        ring[0],
+    ))
+    ring[0] = buf
+    ring[1] = ((groups > 0) & (next_n > 0)).astype(jnp.int32)
+    o_ref[0, 0] = jnp.where(
+        total > 0.0, acc / jnp.maximum(total, 1e-37), 0.0).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_gqa_decode(
+    q: jnp.ndarray,            # [B, KV, G, D] one query position per row
+    k_pool: jnp.ndarray,       # [L, N, KV, bs, D]
+    v_pool: jnp.ndarray,
+    blocks: jnp.ndarray,       # [B, KV, W] int32 pool blocks, as listed
+    logical: jnp.ndarray,      # [B, KV, W] int32 their index in the sequence
+    count: jnp.ndarray,        # [B, KV] int32 entries of the list in use
+    pos: jnp.ndarray,          # [B] int32 query position (keys <= pos)
+    layer: jnp.ndarray,        # int32 scalar, traced
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The decode kernel behind ``paged_sparse_attention`` on a TPU. Grid
+    ``(B, KV)``; a step reads its (row, KV head)'s ``[bs, D]`` tile of each
+    listed block in use, ``P`` tiles a group (``paged_decode_grid``'s ``P``
+    at one head: as many as ``_VMEM_BUDGET`` holds double-buffered), and
+    attends all ``G`` query heads against a group in one product, with the
+    softmax online in float32. The pools stay in HBM (``memory_space=ANY``)
+    and the list is prefetched as scalars, so what the kernel's lowering
+    holds does not grow with ``W``. It is a ``jax.jit`` of its own with the
+    layer traced: the 2-4 attention layers of a decode program call one
+    lowered function (one ``tpu_custom_call`` in the program's text)."""
+    b, kv, g, d = q.shape
+    n, _, bs, _ = k_pool.shape[-4:]
+    w = blocks.shape[-1]
+    per_group = paged_decode_grid(b, 1, w, bs, d, k_pool.dtype.itemsize)[1]
+    tiles = pltpu.VMEM((2, per_group, bs, d), k_pool.dtype)
+    head = pl.BlockSpec((1, 1, g, d), lambda i, j, *_: (i, j, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_gqa_decode_kernel, per_group=per_group, n_blocks=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, kv),
+            in_specs=[head, pool, pool],
+            out_specs=head,
+            scratch_shapes=[
+                tiles, tiles,
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # the steps run in order: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_gqa_decode",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        blocks.reshape(-1).astype(jnp.int32),
+        logical.reshape(-1).astype(jnp.int32),
+        jnp.minimum(count, w).reshape(-1).astype(jnp.int32),
+        pos.astype(jnp.int32),
+        q,
+        k_pool.reshape(-1, kv, bs, d),
+        v_pool.reshape(-1, kv, bs, d),
+    )
 
 
 def paged_sparse_attention(
@@ -460,7 +646,16 @@ def paged_sparse_attention(
 ) -> jnp.ndarray:
     """``o[b, kv, g] = softmax(q . K^T / sqrt(D)) V`` over the keys at or
     before ``pos[b]`` of the ``count[b, kv]`` listed blocks. A row with
-    ``count == 0`` (an idle slot) gives 0. Returns [B, KV, G, D]."""
+    ``count == 0`` (an idle slot) gives 0. Returns [B, KV, G, D]. On a TPU
+    the kernel ``paged_gqa_decode``; elsewhere one gather of the listed
+    blocks, fp32 scores and softmax, probabilities cast back to the compute
+    dtype."""
+    if jax.devices()[0].platform == "tpu":
+        record_resolved_impl("paged_sparse_attention", "pallas (mosaic)")
+        return paged_gqa_decode(
+            q, k_pool, v_pool, blocks, logical, count, pos,
+            jnp.asarray(layer, jnp.int32))
+    record_resolved_impl("paged_sparse_attention", "xla (gather)")
     b, kv, g, d = q.shape
     bs = k_pool.shape[-2]
     w = blocks.shape[-1]
