@@ -258,40 +258,6 @@ class CoordinationPolicy:
 
 
 @dataclass(frozen=True)
-class TracePolicy:
-    """Structured-tracing policy (``gpt_2_distributed_tpu/obs/trace.py``).
-
-    Run-level like :class:`CheckpointPolicy` — never participates in
-    jit/compile caching. Default disabled: the tracer is then a pure no-op
-    (shared null span, no file ever opened), so instrumented hot paths cost
-    one branch per call site.
-
-    * ``trace_dir`` — where per-process ``trace-p{rank}.jsonl`` files land
-      (None = tracing off). Read back with ``scripts/obs_report.py``.
-    * ``max_file_bytes`` — rotation bound per process: the live file plus
-      one ``.1`` generation, so disk use is capped at twice this.
-    * ``xla_profile_at`` — on-demand device profiler window,
-      ``STEP[:NSTEPS]`` (None = no capture); host spans bridge into the
-      device timeline via ``jax.profiler.TraceAnnotation`` while active.
-    """
-
-    trace_dir: str | None = None
-    max_file_bytes: int = 64 * 1024 * 1024
-    xla_profile_at: str | None = None
-
-    @property
-    def enabled(self) -> bool:
-        return self.trace_dir is not None
-
-    def __post_init__(self) -> None:
-        if self.max_file_bytes < 4096:
-            raise ValueError(
-                f"max_file_bytes={self.max_file_bytes} must be >= 4096 "
-                f"(one meta record + headroom)"
-            )
-
-
-@dataclass(frozen=True)
 class ServeConfig:
     """Serving-engine shape signature + scheduler policy
     (``gpt_2_distributed_tpu/serving/engine.py``).

@@ -282,6 +282,8 @@ for _name, _dist in (
     ("decode_blocks_table", "mean"),   # all table slots of those rows
     ("prefill_tokens", "mean"),        # tokens one chunked prefill dispatch takes
     ("prefill_attended", "mean"),      # keys those tokens attend
+    ("prefill_dispatch_ms", "mean"),   # prefill chunk's argument building and call
+    ("intake_ms", "mean"),             # submit's host time per request accepted
     ("sparse_rows", "mean"),           # rows a step puts through block selection
     ("sparse_selected", "mean"),       # blocks those rows attend
     ("sparse_visible", "mean"),        # blocks those rows see

@@ -14,8 +14,10 @@ Design constraints, in order:
    ``event()`` returns immediately, and no file is ever opened.
    Instrumentation in the training step loop and the serving decode loop
    therefore costs one attribute load, one call into the profiler's
-   "is anyone recording" flag and two branches per call site (budget: 1 us
-   per span; PERF.md has the measurement).
+   "is anyone recording" flag and two branches per call site. Measured on a
+   TPU v5e host (PERF.md §3): 0.42 us a span, 1.8 us for the serving
+   engine's ``_Phase`` (a span with its clock and counter); under a
+   profiler capture 1.3 and 3.2 us.
 2. **Spans nest per thread.** Each thread owns a stack (``threading.local``);
    a span's parent is whatever span that same thread had open at entry.
    Cross-thread work (the checkpoint commit thread, the hang watchdog) gets

@@ -197,6 +197,10 @@ class _Phase:
             self._stats[key] += ms
         return self._span.__exit__(*exc)
 
+    def set(self, **attrs) -> None:
+        """Attributes of the span known only inside it."""
+        self._span.set(**attrs)
+
 
 class _Unread(NamedTuple):
     """A decode step that was dispatched and whose tokens the host has not
@@ -1149,8 +1153,14 @@ class ServingEngine:
             # decode_blocks_table: of those rows' block-table slots, the
             # ones that hold keys (ceil((pos + 1) / block_size) a row) and
             # all of them; the rest is the tail the paged kernel skips.
+            # prefill_dispatch_ms: decode_dispatch_ms' twin, a prefill
+            # chunk's argument building and program call to its return.
+            # submits / intake_ms: requests accepted by submit() and the
+            # host time of every call, the wait for the request's key
+            # included.
             "steps": 0, "step_ms": 0.0, "admit_ms": 0.0, "grow_ms": 0.0,
             "decode_dispatch_ms": 0.0, "emit_ms": 0.0,
+            "prefill_dispatch_ms": 0.0, "submits": 0, "intake_ms": 0.0,
             "decode_rows": 0, "decode_attended": 0,
             "decode_blocks_live": 0, "decode_blocks_table": 0,
             # Chunked prefill's twin of the two above (tokens a dispatch
@@ -1349,48 +1359,63 @@ class ServingEngine:
         (queue wait included). An overdue request is evicted at the next
         step boundary with finish reason ``"timeout"`` and its blocks
         freed — generated-so-far tokens stay on the handle.
+
+        The whole call is the span ``intake`` and the clock ``intake_ms``
+        (a refused request's too; ``submits`` counts the accepted). Its one
+        touch of the device, the key of an integer seed made and read to
+        the host, is the span ``request_key`` inside it.
         """
-        prompt = [int(t) for t in prompt]
-        check_generation_args(
-            self.config, len(prompt), max_new_tokens, self.top_k, batch=1
-        )
-        if len(prompt) + max_new_tokens > self._seq_limit:
-            raise ValueError(
-                f"prompt ({len(prompt)}) + max_new_tokens ({max_new_tokens}) "
-                f"exceeds max_seq_len ({self._seq_limit})"
+        tracer = get_tracer()
+        with self._phase(tracer, "intake", "intake_ms") as intake:
+            prompt = [int(t) for t in prompt]
+            intake.set(prompt_len=len(prompt))
+            check_generation_args(
+                self.config, len(prompt), max_new_tokens, self.top_k, batch=1
             )
-        need = self._blocks_needed(len(prompt), max_new_tokens)
-        # A request must fit in the SMALLEST data shard (shard 0 also hosts
-        # the null block) so admission can always place the queue head once
-        # the engine drains; dp=1 reduces to the whole-pool check.
-        usable = self.serve.num_blocks // self._dp - 1
-        if need > usable:
-            raise ValueError(
-                f"request needs {need} KV blocks but each data shard only "
-                f"has {usable} allocatable (num_blocks="
-                f"{self.serve.num_blocks}, block_size={self.serve.block_size}"
-                f", data={self._dp}) — it could never be admitted"
+            if len(prompt) + max_new_tokens > self._seq_limit:
+                raise ValueError(
+                    f"prompt ({len(prompt)}) + max_new_tokens "
+                    f"({max_new_tokens}) exceeds max_seq_len "
+                    f"({self._seq_limit})"
+                )
+            need = self._blocks_needed(len(prompt), max_new_tokens)
+            # A request must fit in the SMALLEST data shard (shard 0 also
+            # hosts the null block) so admission can always place the queue
+            # head once the engine drains; dp=1 reduces to the whole-pool
+            # check.
+            usable = self.serve.num_blocks // self._dp - 1
+            if need > usable:
+                raise ValueError(
+                    f"request needs {need} KV blocks but each data shard only "
+                    f"has {usable} allocatable (num_blocks="
+                    f"{self.serve.num_blocks}, block_size="
+                    f"{self.serve.block_size}, data={self._dp}) — it could "
+                    f"never be admitted"
+                )
+            with tracer.span("request_key"):
+                if isinstance(rng, int):
+                    rng = jax.random.PRNGKey(rng)
+                key = np.asarray(rng, np.uint32)
+            if rid is None:
+                rid = self._next_id
+                self._next_id += 1
+            intake.set(rid=rid)
+            if timeout_s is not None and timeout_s < 0:
+                raise ValueError(f"timeout_s must be >= 0, got {timeout_s}")
+            req = RequestHandle(rid, prompt, max_new_tokens, on_token)
+            req._key = key
+            req.submit_time = time.monotonic()
+            if timeout_s is not None:
+                req.deadline = req.submit_time + timeout_s
+                self._deadlines = True
+            req._enqueue_time = req.submit_time
+            self._queue.append(req)
+            self.stats["submits"] += 1
+            tracer.event(
+                "submit", ts=req.submit_time, rid=req.id,
+                prompt_len=len(prompt), max_new_tokens=max_new_tokens,
             )
-        if isinstance(rng, int):
-            rng = jax.random.PRNGKey(rng)
-        if rid is None:
-            rid = self._next_id
-            self._next_id += 1
-        if timeout_s is not None and timeout_s < 0:
-            raise ValueError(f"timeout_s must be >= 0, got {timeout_s}")
-        req = RequestHandle(rid, prompt, max_new_tokens, on_token)
-        req._key = np.asarray(rng, np.uint32)
-        req.submit_time = time.monotonic()
-        if timeout_s is not None:
-            req.deadline = req.submit_time + timeout_s
-            self._deadlines = True
-        req._enqueue_time = req.submit_time
-        self._queue.append(req)
-        get_tracer().event(
-            "submit", ts=req.submit_time, rid=req.id,
-            prompt_len=len(prompt), max_new_tokens=max_new_tokens,
-        )
-        return req
+            return req
 
     def _alloc_blocks(self, n: int, floor: int, shard: int = 0) -> list[int] | None:
         """n blocks from one data shard's free list while leaving `floor`
@@ -1625,37 +1650,39 @@ class ServingEngine:
         batched program call (rows padded to ``pad_rows`` with ``clen=0`` so
         the program's shape — and so its compile — is independent of how
         many prefills happen to be in flight). The call returns async:
-        ``_settle_chunk`` reads its first tokens."""
-        r = max(pad_rows, len(slots))
-        bt = np.zeros((r, self._m), np.int32)
-        chunk = np.zeros((r, width), np.int32)
-        start = np.zeros((r,), np.int32)
-        clen = np.zeros((r,), np.int32)
-        keys = np.zeros((r, 2), np.uint32)
-        for i, slot in enumerate(slots):
-            req = self._slots[slot]
-            s = req._prefill_pos
-            cl = min(width, len(req._work) - s)
-            bt[i] = self.block_table[slot]
-            chunk[i, :cl] = req._work[s:s + cl]
-            start[i] = s
-            clen[i] = cl
-            keys[i] = req._key
-        self._count_prefill(start, clen)
-        t0 = time.monotonic()
-        with self._mesh_scope():
-            if self.state is None:
-                first, out_keys, self.k_pool, self.v_pool = self._chunk_fn(
-                    self.params, self.k_pool, self.v_pool,
-                    bt, chunk, start, clen, keys,
-                )
-            else:
-                (first, out_keys, self.k_pool, self.v_pool,
-                 self.state) = self._chunk_fn(
-                    self.params, self.k_pool, self.v_pool, self.state,
-                    bt, chunk, start, clen, keys,
-                    np.asarray(slots, np.int32),
-                )
+        ``_settle_chunk`` reads its first tokens. The span ``chunk_dispatch``
+        and the clock ``prefill_dispatch_ms`` hold it all."""
+        with self._phase(get_tracer(), "chunk_dispatch", "prefill_dispatch_ms"):
+            r = max(pad_rows, len(slots))
+            bt = np.zeros((r, self._m), np.int32)
+            chunk = np.zeros((r, width), np.int32)
+            start = np.zeros((r,), np.int32)
+            clen = np.zeros((r,), np.int32)
+            keys = np.zeros((r, 2), np.uint32)
+            for i, slot in enumerate(slots):
+                req = self._slots[slot]
+                s = req._prefill_pos
+                cl = min(width, len(req._work) - s)
+                bt[i] = self.block_table[slot]
+                chunk[i, :cl] = req._work[s:s + cl]
+                start[i] = s
+                clen[i] = cl
+                keys[i] = req._key
+            self._count_prefill(start, clen)
+            t0 = time.monotonic()
+            with self._mesh_scope():
+                if self.state is None:
+                    first, out_keys, self.k_pool, self.v_pool = self._chunk_fn(
+                        self.params, self.k_pool, self.v_pool,
+                        bt, chunk, start, clen, keys,
+                    )
+                else:
+                    (first, out_keys, self.k_pool, self.v_pool,
+                     self.state) = self._chunk_fn(
+                        self.params, self.k_pool, self.v_pool, self.state,
+                        bt, chunk, start, clen, keys,
+                        np.asarray(slots, np.int32),
+                    )
         return _Chunk(first, out_keys, slots, clen[:len(slots)].tolist(), t0)
 
     def _settle_chunk(self, chunk: _Chunk) -> int:
@@ -1667,16 +1694,18 @@ class ServingEngine:
         the device does, at the dispatch or - where a decode step was still
         running then - at that step's read-back, the end of the decode
         turn's own clock (``_turned_at``). The turn's emit loop runs beside
-        the chunk and is on both clocks."""
-        chunk.first.block_until_ready()
-        dur_ms = (time.monotonic() - max(chunk.at, self._turned_at)) * 1e3
-        r = chunk.keys.shape[0]
-        first_host = self._count_behind(np.asarray(chunk.first), r)
-        keys_host = np.asarray(chunk.keys)
+        the chunk and is on both clocks. The wait and the two reads are the
+        span ``chunk_readback``."""
+        tracer = get_tracer()
+        with tracer.span("chunk_readback"):
+            chunk.first.block_until_ready()
+            dur_ms = (time.monotonic() - max(chunk.at, self._turned_at)) * 1e3
+            first_host = np.asarray(chunk.first)
+            keys_host = np.asarray(chunk.keys)
+        first_host = self._count_behind(first_host, chunk.keys.shape[0])
         self.stats["prefill_ms"] += dur_ms
         self.stats["prefill_dispatches"] += 1
         self.stats["prefill_batched"] += max(len(chunk.slots) - 1, 0)
-        tracer = get_tracer()
         emitted = 0
         for i, slot in enumerate(chunk.slots):
             req = self._slots[slot]

@@ -109,6 +109,11 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
     per_step = {"engine_host_ms": s["step_ms"] - s["prefill_ms"] - s["decode_ms"],
                 "admit_ms": s["admit_ms"], "grow_ms": s["grow_ms"],
                 "emit_ms": s["emit_ms"]}
+    per_other = {"prefill_dispatch_ms": "prefill_dispatches", "intake_ms": "submits"}
+    for key, count in per_other.items():
+        assert snap[key] == pytest.approx(s[key] / max(s[count], 1)), key
+    assert s["submits"] == 4 and snap["intake_ms"] > 0
+    assert (snap["prefill_dispatch_ms"] > 0) == (mode != "whole-watermark")
     per_decode = {"decode_dispatch_ms": s["decode_dispatch_ms"],
                   "decode_overlapped": s["decode_overlapped"],
                   "decode_wait_ms": s["decode_ms"] - s["draft_ms"] - s["decode_dispatch_ms"],
@@ -119,7 +124,10 @@ def test_step_clocks_nest_add_up_and_only_grow(mode, tiny_params, tiny_config):
         assert snap[key] == pytest.approx(total / s["steps"]), key
     for key, total in per_decode.items():
         assert snap[key] == pytest.approx(total / s["decode_steps"]), key
-    assert snap["engine_host_ms"] >= snap["admit_ms"] + snap["grow_ms"] + snap["emit_ms"] > 0
+    # a chunk's prefill_ms runs beside the turn's emit (`_clocks_partition`),
+    # so only with it added back do the host's phases fit in the step
+    assert (snap["engine_host_ms"] + s["prefill_ms"] / s["steps"]
+            >= snap["admit_ms"] + snap["grow_ms"] + snap["emit_ms"] > 0)
     assert snap["decode_wait_ms"] > 0
     assert step_clocks([s, s]) == pytest.approx(step_clocks([s]))
     assert all(key in METRIC_REGISTRY for key in snap)
@@ -345,7 +353,11 @@ def test_span_durations_are_the_counters(mode, tmp_path, tiny_params, tiny_confi
     ``decode`` spans of a run add up to ``decode_ms`` (``draft`` + ``verify``
     in a speculative round, and ``verify`` to ``verify_ms``), the
     ``dispatch`` spans inside them to ``decode_dispatch_ms``, and
-    ``obs_report`` lists dispatch and read-back under the phase."""
+    ``obs_report`` lists dispatch and read-back under the phase. The same of
+    a prefill chunk (``chunk_dispatch`` to ``prefill_dispatch_ms``, and
+    ``chunk_readback`` beside it, both inside ``prefill``) and of the
+    request intake (``intake`` to ``intake_ms``, a refused request's too,
+    with ``request_key`` inside it)."""
     from scripts.obs_report import load_trace_dir, step_breakdown
 
     tracer = get_tracer()
@@ -359,6 +371,8 @@ def test_span_durations_are_the_counters(mode, tmp_path, tiny_params, tiny_confi
             eng = _engine(mode, tiny_params, tiny_config)
         for i, p in enumerate((7, 12)):
             eng.submit(list(range(1, p + 1)), 6, rng=i)
+        with pytest.raises(ValueError, match="max_new_tokens"):
+            eng.submit([1, 2, 3], 0)
         eng.run_until_idle()
     finally:
         tracer.configure(None, enabled=False)
@@ -391,10 +405,31 @@ def test_span_durations_are_the_counters(mode, tmp_path, tiny_params, tiny_confi
     assert all(by_sid[r["parent"]]["name"] == "engine_step"
                for r in spans if r["name"] in (outer, "admit", "prefill", "emit"))
 
+    # intake: every call, the refused one too; the key inside the accepted
+    intakes = [r for r in spans if r["name"] == "intake"]
+    assert len(intakes) == 3 and s["submits"] == 2
+    assert agrees(total_ms("intake"), s["intake_ms"])
+    assert sorted(r["attrs"].get("rid", -1) for r in intakes) == [-1, 0, 1]
+    assert sorted(r["attrs"]["prompt_len"] for r in intakes) == [3, 7, 12]
+    keys = [r for r in spans if r["name"] == "request_key"]
+    assert len(keys) == 2 and all(by_sid[r["parent"]]["name"] == "intake" for r in keys)
+    assert all(r["parent"] is None for r in intakes)
+
+    # a prefill chunk's two halves, inside the two `prefill` spans of a step
+    chunks = [r for r in spans if r["name"] in ("chunk_dispatch", "chunk_readback")]
+    assert len(chunks) == 2 * s["prefill_dispatches"] or mode == "sharded"
+    assert all(by_sid[r["parent"]]["name"] == "prefill" for r in chunks)
+    assert agrees(total_ms("chunk_dispatch"), s["prefill_dispatch_ms"])
+
     phases = step_breakdown(records, step_name="engine_step")["phases"]
     assert set(phases[outer]["parts"]) == {"dispatch", readback}
     assert phases[outer]["parts"][readback]["n"] == s["decode_steps"]
     assert "parts" not in phases["emit"]
+    if mode == "sharded":      # whole-prompt prefill: no chunk
+        assert not chunks and s["prefill_dispatch_ms"] == 0
+    else:
+        assert set(phases["prefill"]["parts"]) == {"chunk_dispatch", "chunk_readback"}
+        assert phases["prefill"]["parts"]["chunk_readback"]["n"] == s["prefill_dispatches"]
 
 
 def _inside(inner, outer):
@@ -407,7 +442,8 @@ def test_spans_reach_a_capture_nobody_told_the_tracer_about(
         tmp_path, tiny_params, tiny_config):
     """``jax.profiler.start_trace`` with no trace directory: the engine's
     spans are on the host plane, nested under ``gpt2/engine_step``, on the
-    thread that steps the engine. With no capture either: no file, no span
+    thread that steps the engine, and ``gpt2/intake`` inside the caller's
+    own span around ``submit``. With no capture either: no file, no span
     object."""
     tracer = get_tracer()
     assert not tracer.enabled
@@ -422,6 +458,8 @@ def test_spans_reach_a_capture_nobody_told_the_tracer_about(
     jax.profiler.start_trace(str(capture), profiler_options=options)
     try:
         assert tracer.span("probe") is not _NULL_SPAN
+        with jax.profiler.TraceAnnotation("bench/submit"):
+            eng.submit([7, 8, 9, 10, 11], 2, rng=1)
         for _ in range(3):
             with jax.profiler.TraceAnnotation("bench/step"):
                 eng.step()
@@ -433,13 +471,26 @@ def test_spans_reach_a_capture_nobody_told_the_tracer_about(
     steps = found["gpt2/engine_step"]
     assert len(steps) == 3 and len(found["bench/step"]) == 3
     assert all(_inside(step, found["bench/step"]) for step in steps)
-    for child in ("gpt2/admit", "gpt2/prefill", "gpt2/decode", "gpt2/dispatch",
-                  "gpt2/readback", "gpt2/emit"):
+    for child in ("gpt2/admit", "gpt2/decode", "gpt2/dispatch", "gpt2/readback",
+                  "gpt2/emit"):
         assert len(found[child]) == 3, child
         assert all(_inside(ev, steps) for ev in found[child]), child
-    # the decode step's two halves lie inside it
+    # the 5-token prompt is two chunks of 4; a step with a chunk opens
+    # `prefill` twice (its dispatch, then its first token's read-back)
+    for half in ("gpt2/chunk_dispatch", "gpt2/chunk_readback"):
+        assert len(found[half]) == 2, half
+    assert len(found["gpt2/prefill"]) == 3 + len(found["gpt2/chunk_readback"])
+    assert all(_inside(ev, steps) for ev in found["gpt2/prefill"])
+    # the decode step's two halves lie inside it, a chunk's inside `prefill`
     for half in ("gpt2/dispatch", "gpt2/readback"):
         assert all(_inside(ev, found["gpt2/decode"]) for ev in found[half]), half
+    for half in ("gpt2/chunk_dispatch", "gpt2/chunk_readback"):
+        assert all(_inside(ev, found["gpt2/prefill"]) for ev in found[half]), half
+    # the intake inside the caller's span, the key's wait inside the intake
+    (intake,) = found["gpt2/intake"]
+    assert _inside(intake, found["bench/submit"])
+    (key,) = found["gpt2/request_key"]
+    assert _inside(key, [intake])
 
     # nothing attached: the shared no-op, and no file anywhere
     assert tracer.span("engine_step", n=1) is _NULL_SPAN
