@@ -367,7 +367,7 @@ class Smoke:
             tol = KERNEL_TOL * max(1.0, ref)
             self.check(err <= tol, f"{r.group(1)}: max-abs err {err:.3e} <= {tol:.3e} "
                                    f"({KERNEL_TOL:g} x max(1, |ref|max={ref:.2f}))")
-        self.check(self.kernel_lines(child, "attention") == {"flash (mosaic)"},
+        self.check(self.kernel_lines(child, "attention") == {"flash resident (mosaic)"},
                    f"flash attention ran compiled by Mosaic "
                    f"(resolved: {sorted(self.kernel_lines(child, 'attention'))})")
         self.check("pallas (mosaic)" in self.kernel_lines(child, "paged_attention")
@@ -422,7 +422,7 @@ class Smoke:
             self.check(child.rc == 0, f"{child.name}: rc 0")
             self.check("platform: tpu" in child.lines("out"),
                        f"{child.name}: the trainer's banner says platform: tpu")
-            self.check(self.kernel_lines(child, "attention") == {"flash (mosaic)"},
+            self.check(self.kernel_lines(child, "attention") == {"flash resident (mosaic)"},
                        f"{child.name}: attention resolved to the flash kernel compiled "
                        f"by Mosaic (resolved: {sorted(self.kernel_lines(child, 'attention'))})")
             for line in child.lines("out"):
@@ -668,8 +668,8 @@ class Smoke:
                        f"{name}: banner says platform: tpu, 4 devices")
             self.check(any(line.startswith(banners[name]) for line in child.lines("out")),
                        f"{name}: mesh banner starts {banners[name]!r}")
-            self.check(self.kernel_lines(child, "attention") == {"flash (mosaic)"},
-                       f"{name}: attention resolved to flash (mosaic) "
+            self.check(self.kernel_lines(child, "attention") == {"flash resident (mosaic)"},
+                       f"{name}: attention resolved to flash resident (mosaic) "
                        f"(resolved: {sorted(self.kernel_lines(child, 'attention'))})")
             mean = self.tb_series(tb, "train/loss") if os.path.isdir(tb) else {}
             # The tracker logs the running mean p_n of the losses so far;

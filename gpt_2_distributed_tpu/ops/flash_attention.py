@@ -3,35 +3,49 @@
 The reference materializes dense ``[B, H, T, T]`` score/prob tensors in HBM
 (``/root/reference/model.py:137-151``) — at seq 1024 that is the dominant HBM
 traffic and the activation-memory cap on micro-batch size (SURVEY.md §5.7).
-This kernel keeps everything O(T^2) resident in VMEM via the online-softmax
-flash recurrence, so nothing quadratic ever touches HBM.
+These kernels keep everything O(T^2) in VMEM, so nothing quadratic ever
+touches HBM. Two schedules, chosen from T at trace time (``flash_attention``;
+explicit ``block_q`` / ``block_k`` ask for the gridded one):
 
-Throughput design (what round-1/round-2 profiling taught):
+* **Resident, T <= 1024** (every GPT-2 context: ``n_positions`` is 1024).
+  One grid step per (batch, head) holds the head's whole q, k and v ([T, D]
+  each, 256 KB at T = 1024 with D = 64 padded to 128 lanes) in VMEM and walks
+  the causal triangle in strips of ``resident_strip(T)`` rows with static
+  slices. The forward's row strip takes its keys left of the diagonal in one
+  unmasked product and its diagonal block masked, and takes the softmax over
+  the whole row at once (no online rescaling). The backward walks key strips:
+  each strip's diagonal block, masked, then every row below it, unmasked; dk
+  and dv are summed per strip and written once, dq gathers in fp32 scratch.
+  Nothing is skipped, nothing fetched twice, and only the diagonal blocks pay
+  the mask. At T = 1024 (strips of 256) it computes 655k scores a head where
+  the causal triangle has 524k and the gridded schedule computes 786k.
+* **Gridded, T > 1024.** The grid is ``(batch, heads, nq, nk)`` with the
+  k-block index innermost; Mosaic double-buffers the K/V block copies across
+  grid steps, overlapping HBM loads with compute (a ``fori_loop`` over k
+  inside the kernel, the round-2 first attempt, serialized those loads and
+  measured notably slower). Grid steps with ``j > qi`` (above the diagonal)
+  skip all compute via ``pl.when`` — ~44% of score work at nq=2; the online
+  accumulators (m, l, acc) are VMEM scratch carried across the inner grid
+  dimension, and outputs are written at the last contributing step.
+
+Both schedules:
 
 * **bf16 MXU inputs.** All dots take bf16 operands with fp32 accumulation
   (``preferred_element_type``) — fp32 operands cost multiple MXU passes.
   Probabilities are cast to bf16 before the ``p @ v`` contraction, exactly
   like the dense XLA path (``ops/attention.py`` casts probs to q's dtype).
-* **k-blocks live in the GRID, not a fori_loop.** The grid is
-  ``(batch, heads, nq, nk)`` with the k-block index innermost; Mosaic
-  double-buffers the K/V block copies across grid steps, overlapping HBM
-  loads with compute. A ``fori_loop`` over k inside the kernel (the round-2
-  first attempt) serializes those loads and measured notably slower.
-* **Causal skipping via pl.when.** Grid steps with ``j > qi`` (above the
-  diagonal) skip all compute — ~44% of score work at nq=2. The online
-  accumulators (m, l, acc) are VMEM scratch carried across the inner grid
-  dimension; outputs are written at the diagonal step ``j == qi``.
 * **Head-major [B, H, T, D] blocks.** Mosaic's (sublane, lane) tiling lives
-  on the last two dims, so blocks must be [.., .., block_q, D]; slicing a
+  on the last two dims, so blocks must be [.., .., rows, D]; slicing a
   middle head dim inside the kernel is an unsupported relayout. The
   [B, T, H, D]-shaped entry point transposes at the boundary; XLA fuses that
   into the surrounding reshape.
 
-Backward is a custom VJP (one Pallas kernel): per q-block it regenerates the
-probabilities from the saved log-sum-exp (no stored probs), regenerates the
-*identical* dropout bits by rehashing the same absolute (batch, head, row,
-col) coordinates, and accumulates dq per q-block (VMEM scratch) plus dk/dv
-into full-[T, D] VMEM-resident fp32 outputs per (batch, head).
+Backward is a custom VJP (one Pallas kernel per call in either schedule): it
+regenerates the probabilities from the saved log-sum-exp (no stored probs)
+and the *identical* dropout bits by rehashing the same absolute (batch,
+head, row, col) coordinates — so the two schedules also draw the same mask.
+The gridded kernel accumulates dq per q-block (VMEM scratch) and dk/dv into
+full-[T, D] VMEM-resident fp32 outputs per (batch, head).
 
 Numerics vs. the dense path: the dense reference masks scores to -1e4
 (``model.py:144``); here masked lanes get -1e30 before the row max — for
@@ -61,7 +75,7 @@ from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30  # causal mask fill for fp32 row-max stability (see docstring)
 LOG2E = 1.4426950408889634  # exp(x) == exp2(x * LOG2E); folded into the q scale
-DEFAULT_BLOCK_Q = 512  # fastest on v5e at seq 1024 (256/512/1024 swept)
+DEFAULT_BLOCK_Q = 512  # gridded; fastest on v5e at seq 1024 (256/512/1024 swept)
 
 
 def default_blocks(t: int) -> tuple[int, int]:
@@ -356,12 +370,9 @@ _FWD_DIM_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 _BWD_DIM_SEMANTICS = ("parallel", "parallel", "arbitrary", "arbitrary")
 
 
-@functools.lru_cache(maxsize=None)
-def _build(dropout_rate: float, block_q: int, block_k: int, interpret: bool):
-    """Build the custom-VJP flash attention ([B, H, T, D]) for one config.
-
-    Device-local: callers shard over (batch, head) with ``jax.shard_map``
-    (see ``flash_attention`` and the module SPMD comment)."""
+def _gridded_calls(dropout_rate: float, block_q: int, block_k: int,
+                  interpret: bool):
+    """(forward, backward) ``pallas_call`` wrappers of the gridded schedule."""
 
     def _raw_fwd(seed, q, k, v):
         batch, heads, t, d = q.shape
@@ -407,16 +418,11 @@ def _build(dropout_rate: float, block_q: int, block_k: int, interpret: bool):
         )(seed, q, k, v)
         return o, lse
 
-    @jax.custom_vjp
-    def attn(q, k, v, seed):
-        o, _ = _raw_fwd(seed, q, k, v)
-        return o
-
-    def attn_fwd(q, k, v, seed):
-        o, lse = _raw_fwd(seed, q, k, v)
-        return o, (q, k, v, seed, o, lse)
-
-    def _raw_bwd(seed, q, k, v, do, lse, delta):
+    def _raw_bwd(seed, q, k, v, o, do, lse):
+        delta = jnp.sum(
+            do.astype(jnp.float32) * o.astype(jnp.float32),
+            axis=-1, keepdims=True,
+        )                                             # [B, H, T, 1]
         batch, heads, t, d = q.shape
         nq = t // block_q
         nk = t // block_k
@@ -472,13 +478,271 @@ def _build(dropout_rate: float, block_q: int, block_k: int, interpret: bool):
         )(seed, q, k, v, do, lse, delta)
         return dq, dk, dv
 
+    return _raw_fwd, _raw_bwd
+
+
+# ---------------------------------------------------------------------------
+# Resident schedule (T <= RESIDENT_MAX_T): one grid step per (batch, head)
+# holds the head's whole q, k, v ([T, D] each) in VMEM and walks the causal
+# triangle in strips with static slices. Every block it computes lies on or
+# below the diagonal, so nothing is skipped or fetched twice, and only the
+# diagonal [strip, strip] sub-blocks pay the triangular mask (one select
+# against a mask built once a step).
+# ---------------------------------------------------------------------------
+
+RESIDENT_MAX_T = 1024  # GPT-2's n_positions; longer sequences run gridded
+# 256 measured faster than 128 and 512 on v5e at [8, 12, 1024, 64]
+# (fwd+bwd 1.185 vs 1.256 and 1.221 ms) although it computes 11 % more
+# scores than 128: fewer, larger products win.
+RESIDENT_STRIP = 256
+
+
+def resident_strip(t: int) -> int | None:
+    """Strip height of the resident schedule at sequence length ``t``, or
+    None where ``t`` takes the gridded schedule."""
+    if t > RESIDENT_MAX_T or t % 128:
+        return None
+    return RESIDENT_STRIP if t % RESIDENT_STRIP == 0 else 128
+
+
+def _nt(a, b):
+    """a @ b^T, fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _nn(a, b):
+    """a @ b, fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tn(a, b):
+    """a^T @ b, fp32 accumulation."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# The resident kernels are written in lax primitives rather than jnp: a
+# kernel body is traced and lowered again at each of a step's call sites on
+# every start, and through jnp's wrappers one forward and backward pair took
+# 0.23 s to lower where it takes 0.12 (host CPU) - 24 sites, twice a start.
+
+
+def _total(xs):
+    return functools.reduce(jax.lax.add, xs)
+
+
+def _cast(x, dtype):
+    return jax.lax.convert_element_type(x, dtype)
+
+
+def _by_row(op, x, col):
+    """``op(x, col)`` with the [n, 1] column broadcast across each row."""
+    return op(x, jax.lax.broadcast_in_dim(col, x.shape, (0, 1)))
+
+
+def _per_row(reduce, x):
+    """``reduce`` over each row of [n, w], kept as an [n, 1] column."""
+    return jax.lax.broadcast_in_dim(reduce(x, (1,)), (x.shape[0], 1), (0,))
+
+
+def _below_diagonal(strip: int):
+    """[strip, strip] mask of the diagonal sub-block: col <= row."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (strip, strip), 1)
+            <= jax.lax.broadcasted_iota(jnp.int32, (strip, strip), 0))
+
+
+def _resident_fwd_kernel(
+    seed_ref,  # scalar prefetch: [1] int32
+    q_ref,     # [1, 1, T, D]
+    k_ref,     # [1, 1, T, D]
+    v_ref,     # [1, 1, T, D]
+    o_ref,     # [1, 1, T, D]
+    lse_ref,   # [1, 1, T, 1] f32, base-2 (m2 + log2 l) — internal to the VJP
+    *,
+    strip: int,
+    dropout_rate: float,
+):
+    """Row strip r (rows [lo, lo + strip), lo = r * strip) attends to keys
+    [0, lo) unmasked and to its diagonal block [lo, lo + strip) masked: the
+    whole row is in hand, so the softmax is taken directly (base 2, scale
+    folded into q as in the gridded kernel)."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    t, d = q_ref.shape[2:]
+    scale = LOG2E / (d ** 0.5)
+    below = _below_diagonal(strip)
+    masked = jax.lax.full((strip, strip), NEG_INF, jnp.float32)
+    for lo in range(0, t, strip):
+        rows = pl.ds(lo, strip)
+        parts = [(0, lo)] if lo else []
+        parts.append((lo, strip))                    # the diagonal block, last
+        q = _cast(_cast(q_ref[0, 0, rows], jnp.float32) * scale, q_ref.dtype)
+        s = [_nt(q, k_ref[0, 0, pl.ds(c0, w)]) for c0, w in parts]
+        s[-1] = jax.lax.select(below, s[-1], masked)
+        m = functools.reduce(
+            jax.lax.max, [_per_row(jax.lax.reduce_max, x) for x in s])
+        p = [jax.lax.exp2(_by_row(jax.lax.sub, x, m)) for x in s]
+        l = _total([_per_row(jax.lax.reduce_sum, x) for x in p])
+        if dropout_rate > 0.0:
+            threshold = jnp.uint32(int(dropout_rate * (2**32)))
+            p = [jnp.where(
+                _dropout_bits(seed_ref[0], b, h, lo, c0, x.shape) >= threshold,
+                x / (1.0 - dropout_rate), 0.0) for x, (c0, _) in zip(p, parts)]
+        acc = _total([_nn(_cast(x, v_ref.dtype), v_ref[0, 0, pl.ds(c0, w)])
+                      for x, (c0, w) in zip(p, parts)])
+        lse_ref[0, 0, rows] = m + jnp.log2(l)
+        o_ref[0, 0, rows] = _cast(_by_row(jax.lax.div, acc, l), o_ref.dtype)
+
+
+def _resident_bwd_kernel(
+    seed_ref,   # scalar prefetch: [1] int32
+    q_ref,      # [1, 1, T, D]
+    k_ref,      # [1, 1, T, D]
+    v_ref,      # [1, 1, T, D]
+    o_ref,      # [1, 1, T, D]
+    do_ref,     # [1, 1, T, D]
+    lse_ref,    # [1, 1, T, 1]
+    dq_ref,     # [1, 1, T, D]
+    dk_ref,     # [1, 1, T, D]
+    dv_ref,     # [1, 1, T, D]
+    qs_scr,     # VMEM scratch [T, D], q * scale in q's dtype
+    delta_scr,  # VMEM scratch [T, 1] f32, rowsum(do * o)
+    dq_scr,     # VMEM scratch [T, D] f32
+    *,
+    strip: int,
+    dropout_rate: float,
+):
+    """Key strip c (keys [c0, c0 + strip)) is attended by its diagonal block
+    of rows [c0, c0 + strip), masked, and by every row below it, unmasked:
+    its dk and dv are summed over those rows and written once; dq gathers
+    every strip's share in fp32 scratch and is written at the end. (Walking
+    key strips, not row strips, leaves one [T, D] accumulator read and
+    written per block instead of two: dk, dv. Measured 1.205 vs 1.249 ms
+    fwd+bwd at [8, 12, 1024, 64] on v5e.) Once a step, q is scaled into
+    scratch exactly as the forward scaled it, and delta = rowsum(do * o) is
+    taken here rather than by an XLA reduction before the call (1.185 vs
+    1.212 ms fwd+bwd). The base-2 folding is the gridded backward's."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    t, d = q_ref.shape[2:]
+    scale = LOG2E / (d ** 0.5)
+    kp = 1.0 - dropout_rate
+    below = _below_diagonal(strip)
+    masked = jax.lax.full((strip, strip), NEG_INF, jnp.float32)
+    qs_scr[...] = _cast(_cast(q_ref[0, 0], jnp.float32) * scale, qs_scr.dtype)
+    delta_scr[...] = _per_row(jax.lax.reduce_sum, jax.lax.mul(
+        _cast(do_ref[0, 0], jnp.float32), _cast(o_ref[0, 0], jnp.float32)))
+    dq_scr[...] = jnp.zeros_like(dq_scr)
+    for c0 in range(0, t, strip):
+        cols = pl.ds(c0, strip)
+        k, v = k_ref[0, 0, cols], v_ref[0, 0, cols]
+        parts = [(c0, strip)]                        # the diagonal block, first
+        if c0 + strip < t:
+            parts.append((c0 + strip, t - c0 - strip))
+        dk, dv = [], []
+        for r0, n in parts:
+            rows = pl.ds(r0, n)
+            q, do = qs_scr[rows], do_ref[0, 0, rows]
+            s = _nt(q, k)                            # [n, strip] f32, base-2
+            if r0 == c0:
+                s = jax.lax.select(below, s, masked)
+            p = jax.lax.exp2(_by_row(jax.lax.sub, s, lse_ref[0, 0, rows]))
+            dpd = _nt(do, v)                         # dL/d(dropped P)
+            if dropout_rate > 0.0:
+                keep = (_dropout_bits(seed_ref[0], b, h, r0, c0, s.shape)
+                        >= jnp.uint32(int(dropout_rate * (2**32))))
+                pd = jnp.where(keep, p / kp, 0.0)
+                dp = jnp.where(keep, dpd / kp, 0.0)
+            else:
+                pd, dp = p, dpd
+            ds = _cast(jax.lax.mul(p, _by_row(jax.lax.sub, dp, delta_scr[rows])),
+                       q.dtype)
+            dq_scr[rows] = jax.lax.add(dq_scr[rows], _nn(ds, k))
+            dk.append(_tn(ds, q))
+            dv.append(_tn(_cast(pd, do.dtype), do))
+        dk_ref[0, 0, cols] = _cast(_total(dk) * (1.0 / LOG2E), dk_ref.dtype)
+        dv_ref[0, 0, cols] = _cast(_total(dv), dv_ref.dtype)
+    dq_ref[0, 0] = _cast(dq_scr[...] * (scale / LOG2E), dq_ref.dtype)
+
+
+def _resident_calls(dropout_rate: float, strip: int, interpret: bool):
+    """(forward, backward) ``pallas_call`` wrappers of the resident schedule.
+    No output block is revisited across grid steps: both grid dimensions
+    are parallel. dk and dv leave the kernel in the inputs' dtype."""
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel"),
+        vmem_limit_bytes=64 * 1024 * 1024,
+    )
+
+    def _grid_spec(q, ins, outs, scratch=()):
+        """One step per (batch, head); every operand's block is the head's
+        whole [T, w] (w = D, or 1 for lse and delta)."""
+        batch, heads, t, _ = q.shape
+        whole = lambda w: pl.BlockSpec((1, 1, t, w), lambda b, h, *_: (b, h, 0, 0))
+        return pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(batch, heads),
+            in_specs=[whole(w) for w in ins], out_specs=[whole(w) for w in outs],
+            scratch_shapes=list(scratch))
+
+    def _raw_fwd(seed, q, k, v):
+        d = q.shape[3]
+        o, lse = pl.pallas_call(
+            functools.partial(_resident_fwd_kernel, strip=strip,
+                              dropout_rate=dropout_rate),
+            grid_spec=_grid_spec(q, (d, d, d), (d, 1)),
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(q.shape[:3] + (1,), jnp.float32),
+            ],
+            compiler_params=params,
+            interpret=interpret,
+        )(seed, q, k, v)
+        return o, lse
+
+    def _raw_bwd(seed, q, k, v, o, do, lse):
+        t, d = q.shape[2:]
+        return pl.pallas_call(
+            functools.partial(_resident_bwd_kernel, strip=strip,
+                              dropout_rate=dropout_rate),
+            grid_spec=_grid_spec(q, (d, d, d, d, d, 1), (d, d, d),
+                                 [pltpu.VMEM((t, d), q.dtype),
+                                  pltpu.VMEM((t, 1), jnp.float32),
+                                  pltpu.VMEM((t, d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+            compiler_params=params,
+            interpret=interpret,
+        )(seed, q, k, v, o, do, lse)
+
+    return _raw_fwd, _raw_bwd
+
+
+@functools.lru_cache(maxsize=None)
+def _build(dropout_rate: float, interpret: bool, strip: int | None = None,
+           block_q: int | None = None, block_k: int | None = None):
+    """Build the custom-VJP flash attention ([B, H, T, D]) for one config:
+    the resident schedule with strips of ``strip`` if given, else the
+    gridded one at ``block_q`` x ``block_k``.
+
+    Device-local: callers shard over (batch, head) with ``jax.shard_map``
+    (see ``flash_attention`` and the module SPMD comment)."""
+    if strip is not None:
+        _raw_fwd, _raw_bwd = _resident_calls(dropout_rate, strip, interpret)
+    else:
+        _raw_fwd, _raw_bwd = _gridded_calls(
+            dropout_rate, block_q, block_k, interpret)
+
+    @jax.custom_vjp
+    def attn(q, k, v, seed):
+        o, _ = _raw_fwd(seed, q, k, v)
+        return o
+
+    def attn_fwd(q, k, v, seed):
+        o, lse = _raw_fwd(seed, q, k, v)
+        return o, (q, k, v, seed, o, lse)
+
     def attn_bwd(res, do):
         q, k, v, seed, o, lse = res
-        delta = jnp.sum(
-            do.astype(jnp.float32) * o.astype(jnp.float32),
-            axis=-1, keepdims=True,
-        )                                             # [B, H, T, 1]
-        dq, dk, dv = _raw_bwd(seed, q, k, v, do, lse, delta)
+        dq, dk, dv = _raw_bwd(seed, q, k, v, o, do, lse)
         return dq, dk.astype(k.dtype), dv.astype(v.dtype), None
 
     attn.defvjp(attn_fwd, attn_bwd)
@@ -499,31 +763,37 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Causal flash attention, drop-in for ``ops.attention.causal_attention``.
 
-    Requires ``T % block_q == 0`` (the driver picks block_q <= T). ``rng``
-    seeds the in-kernel dropout hash when training. ``block_q``/``block_k``
-    default per sequence length (``default_blocks`` — the round-4 on-chip
-    sweep: big blocks amortize the ~1 us/grid-step Mosaic overhead that
-    dominates this kernel at D=64, at the price of coarser causal skipping).
+    Requires T to be a multiple of 128. ``rng`` seeds the in-kernel dropout
+    hash when training. With no ``block_q``/``block_k`` given, T <= 1024 runs
+    the resident schedule (``resident_strip``) and longer sequences the
+    gridded one at ``default_blocks`` (the round-4 on-chip sweep: big blocks
+    amortize the ~1 us/grid-step Mosaic overhead that dominates that kernel
+    at D=64, at the price of coarser causal skipping); either block size
+    given asks for the gridded schedule (``T % block_q == 0``).
     """
     t = q.shape[2]
-    dq, dk_ = default_blocks(t)
-    block_q = pick_block_q(t, block_q if block_q is not None else dq)
-    if block_q is None:
-        raise ValueError(
-            f"flash attention needs T divisible by a viable block size "
-            f"(1024/512/256/128), got T={t}"
-        )
-    block_k = pick_block_q(t, block_k if block_k is not None else dk_)
+    strip = resident_strip(t) if block_q is None and block_k is None else None
+    if strip is None:
+        dq, dk_ = default_blocks(t)
+        block_q = pick_block_q(t, block_q if block_q is not None else dq)
+        if block_q is None:
+            raise ValueError(
+                f"flash attention needs T divisible by a viable block size "
+                f"(1024/512/256/128), got T={t}"
+            )
+        block_k = pick_block_q(t, block_k if block_k is not None else dk_)
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
-    record_resolved_impl("attention", f"flash ({pallas_mode(interpret)})")
+    schedule = "gridded" if strip is None else "resident"
+    record_resolved_impl(
+        "attention", f"flash {schedule} ({pallas_mode(interpret)})")
     rate = float(dropout_rate) if (not deterministic and rng is not None) else 0.0
     if rate > 0.0:
         # Fold the jax PRNG key down to one int32 kernel seed.
         seed = jax.random.randint(rng, (1,), 0, jnp.iinfo(jnp.int32).max, jnp.int32)
     else:
         seed = jnp.zeros((1,), jnp.int32)
-    attn = _build(rate, block_q, block_k, interpret)
+    attn = _build(rate, interpret, strip, block_q, block_k)
 
     mesh = _ambient_mesh()
     if mesh is not None:

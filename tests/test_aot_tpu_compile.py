@@ -134,6 +134,23 @@ def test_flash_forward_backward_dropout(chip, shape):
     assert n == 2   # the forward kernel and the one backward kernel
 
 
+@pytest.mark.parametrize("backward", [False, True], ids=["forward", "backward"])
+def test_flash_resident_schedule_at_124m_1024(chip, monkeypatch, capsys, backward):
+    """124M at T = 1024 and micro-batch 8, as ``train-124m-1k`` runs it: the
+    default takes the resident schedule (a head's whole [T, D] q, k, v in
+    VMEM, one grid step a head), and Mosaic accepts its blocks and scratch
+    within a v5e's VMEM."""
+    from gpt_2_distributed_tpu.ops import spmd
+
+    monkeypatch.setattr(spmd, "_RESOLVED_IMPLS", set())
+    qkv = ((8, 12, 1024, 64), BF16)
+    attn = lambda q, k, v: flash_attention(q, k, v, interpret=False)
+    fn = jax.value_and_grad(_summed(attn), (0, 1, 2)) if backward else attn
+    assert _kernels(chip, fn, qkv, qkv, qkv) == (2 if backward else 1)
+    said = [l for l in capsys.readouterr().err.splitlines() if l.startswith("[kernels]")]
+    assert said == ["[kernels] attention: flash resident (mosaic)"]
+
+
 def test_flash_block_forward_backward(chip):
     qkv = ((4, 12, 512, 64), BF16)
     scalar = ((), I32)

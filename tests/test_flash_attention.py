@@ -185,6 +185,75 @@ def test_multiblock_dropout_bwd_matches_dense_oracle():
         )
 
 
+@pytest.mark.parametrize("T", [128, 512, 1024])
+def test_resident_fwd_bwd_matches_dense(T):
+    """The default schedule at T <= 1024 (one grid step per head, row and
+    key strips walked inside it): one strip at 128, several at 512 and
+    1024, where the unmasked blocks left of (and below) the diagonal run."""
+    q, k, v = make_qkv(B=1, H=1, T=T, seed=T)
+
+    def pulled(attn):
+        def loss(q, k, v):
+            o = attn(q, k, v)
+            return (o ** 2).sum(), o
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, o_d), gd = pulled(causal_attention)
+    (_, o_f), gf = pulled(lambda q, k, v: flash_attention(q, k, v, interpret=True))
+    np.testing.assert_allclose(np.asarray(o_f), np.asarray(o_d), atol=2e-5)
+    for a, b in zip(gd, gf):
+        scale = float(jnp.abs(a).max())
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), atol=3e-5 * max(scale, 1.0)
+        )
+
+
+def test_resident_causality():
+    """Keys and values after position t, mid-strip, leave rows <= t (and
+    their gradients) unchanged; the rows after it do change."""
+    q, k, v = make_qkv(B=1, H=1, T=512, seed=2)
+    t = 300
+    k2 = k.at[:, :, t + 1:].set(99.0)
+    v2 = v.at[:, :, t + 1:].set(99.0)
+
+    def rows_loss(q, k, v):
+        o = flash_attention(q, k, v, interpret=True)
+        return (o[:, :, :t + 1] ** 2).sum(), o
+
+    pulled = jax.jit(jax.value_and_grad(rows_loss, has_aux=True))
+    (_, o1), dq1 = pulled(q, k, v)
+    (_, o2), dq2 = pulled(q, k2, v2)
+    np.testing.assert_allclose(
+        np.asarray(o1[:, :, :t + 1]), np.asarray(o2[:, :, :t + 1]), atol=1e-6
+    )
+    assert not np.allclose(np.asarray(o1[:, :, t + 1:]), np.asarray(o2[:, :, t + 1:]))
+    np.testing.assert_allclose(np.asarray(dq1), np.asarray(dq2), atol=1e-6)
+
+
+def test_resident_and_gridded_share_the_dropout_mask():
+    """Both schedules hash the same absolute (b, h, row, col) coordinates,
+    so at one seed they drop the same probabilities: outputs and gradients
+    agree to fp32 rounding."""
+    q, k, v = make_qkv(B=1, H=2, T=512, seed=4)
+    key = jax.random.PRNGKey(11)
+
+    def run(**blocks):
+        def loss(q, k, v):
+            o = flash_attention(q, k, v, dropout_rate=0.1, rng=key,
+                                deterministic=False, interpret=True, **blocks)
+            return (o ** 2).sum(), o
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, o_r), g_r = run()
+    (_, o_g), g_g = run(block_q=128)
+    np.testing.assert_allclose(np.asarray(o_r), np.asarray(o_g), atol=1e-5)
+    for a, b in zip(g_g, g_r):
+        scale = float(jnp.abs(a).max())
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), atol=1e-5 * max(scale, 1.0)
+        )
+
+
 def test_pick_block_q():
     from gpt_2_distributed_tpu.ops.flash_attention import pick_block_q
 
@@ -275,7 +344,9 @@ def test_sharded_dropout_streams_differ_per_shard():
 @pytest.mark.parametrize("impl,seq_len,line", [
     ("auto", 128, "[kernels] attention: dense (xla)"),     # flash only on a TPU
     ("dense", 128, "[kernels] attention: dense (xla)"),
-    ("flash", 128, "[kernels] attention: flash (interpret)"),
+    ("flash", 128, "[kernels] attention: flash resident (interpret)"),
+    ("flash", 1024, "[kernels] attention: flash resident (interpret)"),
+    ("flash", 2048, "[kernels] attention: flash gridded (interpret)"),
 ])
 def test_resolved_attention_impl_is_said_once(monkeypatch, capsys, impl, seq_len, line):
     """The trace-time choice (platform, shape, mesh) is printed once per
