@@ -6,8 +6,10 @@ float32, the ``top_k`` largest of score + bias chosen, their weights the
 scores normalised over the chosen - held here or not - and scaled).
 ``expert_layer`` is told which experts it holds (``first``; their count is
 the weights'): it computes ``sum_i w_i E_i(x)`` over the held experts a token
-chose, ``E_i(x) = W_down_i relu(W_up_i x)^2``, and leaves out what the others
-would add. Nothing is dropped and nothing has a capacity. The shared expert
+chose, ``E_i(x) = W_down_i relu(W_up_i x)^2`` - or, ``gated``, the SwiGLU
+``E_i(x) = W_down_i (silu(W_gate_i x) * W_up_i x)`` with gate and up stacked
+as one ``[2F, C]`` matrix an expert, one grouped matmul - and leaves out what
+the others would add. Nothing is dropped and nothing has a capacity. The shared expert
 is the caller's, as is any exchange between holders: on one chip there is
 none, and nothing here stands in for one.
 
@@ -72,6 +74,12 @@ def relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+def swiglu(gate_up):
+    """``silu(gate) * up`` in float32 of ``[..., 2F]``, the gate first."""
+    gate, up = jnp.split(gate_up.astype(jnp.float32), 2, axis=-1)
+    return jax.nn.silu(gate) * up
+
+
 def _tile(dim: int, want: int) -> int:
     """The widest tile of at most ``want`` that is the dimension itself or a
     lane multiple dividing it."""
@@ -106,10 +114,11 @@ def grouped_matmul(rows, weights, group_sizes, transpose: bool = False,
 
 
 def expert_layer(x, chosen, weights, valid, w_up, w_down, first: int = 0,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None, gated: bool = False):
     """The routed part of an expert layer from the experts ``[first, first +
     count)``, whose weights ``w_up`` and ``w_down`` [count, F, C] are the
-    ones handed in.
+    ones handed in (``gated``: ``w_up`` [count, 2F, C], the gate's rows
+    first).
 
     ``x`` [T, C] the normed inputs in the matmuls' dtype, ``chosen`` /
     ``weights`` [T, k] the router's choice over ALL experts, ``valid`` [T]
@@ -134,7 +143,7 @@ def expert_layer(x, chosen, weights, valid, w_up, w_down, first: int = 0,
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     up = grouped_matmul(rows, w_up, given, True, interpret)
-    act = relu2(up.astype(jnp.float32)).astype(x.dtype)
+    act = (swiglu(up) if gated else relu2(up.astype(jnp.float32))).astype(x.dtype)
     # the last projection's accumulator as it is: float32 into the stream
     out = grouped_matmul(act, w_down, given, False, interpret, jnp.float32)[:pairs]
     back = jnp.zeros((pairs,), jnp.int32).at[order].set(jnp.arange(pairs, dtype=jnp.int32))

@@ -209,16 +209,17 @@ def spec_verify_attention(
 
 
 def paged_decode_grid(
-    b: int, h: int, m: int, bs: int, d: int, itemsize: int = 2
+    b: int, h: int, m: int, bs: int, d: int, itemsize: int = 2, pools: int = 2
 ) -> tuple[tuple[int, int], int]:
     """The kernel's grid ``(B, ceil(M / P))`` and ``P``, the blocks one step
     takes, from the shapes alone: as many ``[H, bs, D]`` tiles as
-    ``_VMEM_BUDGET`` holds for K and V, double-buffered — at least one, at
-    most the table's width. A tile is counted as VMEM lays it out (``bs``
-    to the dtype's sublane tile, ``D`` to 128 lanes)."""
+    ``_VMEM_BUDGET`` holds for K and V (``pools``: 1 for a latent pool
+    alone), double-buffered — at least one, at most the table's width. A
+    tile is counted as VMEM lays it out (``bs`` to the dtype's sublane tile,
+    ``D`` to 128 lanes)."""
     sublanes = 8 * 4 // itemsize
     tile = h * -(-bs // sublanes) * sublanes * -(-d // 128) * 128 * itemsize
-    p = max(1, min(m, _VMEM_BUDGET // (4 * tile)))
+    p = max(1, min(m, _VMEM_BUDGET // (2 * pools * tile)))
     return (b, -(-m // p)), p
 
 
@@ -447,7 +448,12 @@ def paged_attention(
 # queries each list other blocks and together list nearly all, walks the row's
 # table in tiles under a mask in XLA (``paged_masked_attention``), which keeps
 # the products on the MXU. ``paged_attention`` above is untouched by any of
-# them.
+# them. A latent pool (one row a position from which every head's key and
+# value are made, no V pool) is read two ways: a decode row scores the rows as
+# they lie with absorbed queries - the kernel's walk over the one pool,
+# ``paged_mla_decode`` - and a chunk decompresses each tile's rows to keys and
+# values and attends them (``paged_mla_prefill``: on a TPU the kernel
+# ``mla_prefill_attention``, the scores kept in VMEM; elsewhere in XLA).
 
 _MASKED = -1e30   # finite, so a row with nothing selected in a tile stays NaN-free
 
@@ -459,21 +465,33 @@ def _gqa_decode_kernel(
     count_ref,    # scalar prefetch: [B * KV] int32 entries in use, at most W
     pos_ref,      # scalar prefetch: [B] int32 query positions
     q_ref,        # [1, 1, G, D]
-    k_hbm,        # [L * N, KV, bs, D] the pool, left where it is
-    v_hbm,
-    o_ref,        # [1, 1, G, D]
-    k_buf,        # VMEM [2, P, bs, D]: two groups of P tiles, one being read
-    v_buf,        #   while the other fills
-    sem,          # DMA semaphores [2, 2]: (K, V) x buffer
-    ring,         # SMEM [2] int32: the buffer the step's first group goes to,
-                  #   and whether the step before already started it
-    *,
+    *refs,
     per_group: int,
     n_blocks: int,
+    value_lanes: int | None = None,
+    scale_dim: int | None = None,
 ):
     """One (row, KV head) a grid step, its list walked ``P`` tiles at a time.
     The grid runs in order, and the last group of a step starts the copies of
-    the next step's first, so the copies run ahead across rows too."""
+    the next step's first, so the copies run ahead across rows too.
+
+    ``refs`` are, for a K and a V pool: ``k_hbm``, ``v_hbm`` ([L * N, KV,
+    bs, D], the pools left where they are), ``o_ref`` [1, 1, G, D], ``k_buf``,
+    ``v_buf`` (VMEM [2, P, bs, D]: two groups of P tiles, one being read while
+    the other fills), ``sem`` (DMA semaphores [2, 2]: (K, V) x buffer) and
+    ``ring`` (SMEM [2] int32: the buffer the step's first group goes to, and
+    whether the step before already started it). For a latent pool alone
+    (``value_lanes``: ``paged_mla_decode``) there is no V pool and no V
+    buffer, ``sem`` is [1, 2], and a tile's first ``value_lanes`` lanes are
+    its value; ``o_ref`` is [1, 1, G, value_lanes]. The scores are scaled by
+    ``1 / sqrt(scale_dim)`` (D unless given)."""
+    if value_lanes is None:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, ring = refs
+        pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+    else:
+        k_hbm, o_ref, k_buf, sem, ring = refs
+        pools = ((k_hbm, k_buf),)
+        v_buf = k_buf
     b, h = pl.program_id(0), pl.program_id(1)
     kv = pl.num_programs(1)
     steps = pl.num_programs(0) * kv
@@ -487,7 +505,7 @@ def _gqa_decode_kernel(
         K and V tiles of its blocks in use, into buffer ``buf``."""
         def one(i, carry):
             blk = blocks_ref[at * width + g * per_group + i] + layer_ref[0] * n_blocks
-            for j, (pool, into) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+            for j, (pool, into) in enumerate(pools):
                 copy = pltpu.make_async_copy(
                     pool.at[blk, at % kv], into.at[buf, i], sem.at[j, buf])
                 if start:
@@ -550,12 +568,14 @@ def _gqa_decode_kernel(
         k = k_buf[buf].reshape(span, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) / (d ** 0.5)                                       # [G, P*bs] f32
+        ) / ((scale_dim or d) ** 0.5)                        # [G, P*bs] f32
         s = jnp.where(keep, s, _MASKED)
         new_top = jnp.maximum(top, s.max(axis=1, keepdims=True))
         p = jnp.where(keep, jnp.exp(s - new_top), 0.0)
         alpha = jnp.exp(top - new_top)
         v = v_buf[buf].reshape(span, d)
+        if value_lanes is not None:
+            v = v[:, :value_lanes]
         acc = acc * alpha + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
         return new_top, total * alpha + p.sum(axis=1, keepdims=True), acc, other
@@ -564,13 +584,59 @@ def _gqa_decode_kernel(
     top, total, acc, buf = jax.lax.fori_loop(0, groups, group, (
         jnp.full((g_heads, 1), _MASKED, jnp.float32),
         jnp.zeros((g_heads, 1), jnp.float32),
-        jnp.zeros((g_heads, d), jnp.float32),
+        jnp.zeros((g_heads, value_lanes or d), jnp.float32),
         ring[0],
     ))
     ring[0] = buf
     ring[1] = ((groups > 0) & (next_n > 0)).astype(jnp.int32)
     o_ref[0, 0] = jnp.where(
         total > 0.0, acc / jnp.maximum(total, 1e-37), 0.0).astype(o_ref.dtype)
+
+
+def _listed_decode_call(q, pools, blocks, logical, count, pos, layer, *,
+                        name: str, value_lanes: int | None, scale_dim: int | None,
+                        interpret: bool):
+    """``_gqa_decode_kernel`` over ``pools`` (K and V, or a latent pool
+    alone): grid ``(B, KV)``, the lists prefetched as scalars."""
+    b, kv, g, d = q.shape
+    n, _, bs, _ = pools[0].shape[-4:]
+    w = blocks.shape[-1]
+    per_group = paged_decode_grid(
+        b, 1, w, bs, d, pools[0].dtype.itemsize, len(pools))[1]
+    tiles = pltpu.VMEM((2, per_group, bs, d), pools[0].dtype)
+    dv = value_lanes or d
+    head = pl.BlockSpec((1, 1, g, d), lambda i, j, *_: (i, j, 0, 0))
+    out = pl.BlockSpec((1, 1, g, dv), lambda i, j, *_: (i, j, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_gqa_decode_kernel, per_group=per_group, n_blocks=n,
+                          value_lanes=value_lanes, scale_dim=scale_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, kv),
+            in_specs=[head] + [pool] * len(pools),
+            out_specs=out,
+            scratch_shapes=[
+                *[tiles] * len(pools),
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, dv), q.dtype),
+        # the steps run in order: each starts the next one's first copies
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=name,
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        blocks.reshape(-1).astype(jnp.int32),
+        logical.reshape(-1).astype(jnp.int32),
+        jnp.minimum(count, w).reshape(-1).astype(jnp.int32),
+        pos.astype(jnp.int32),
+        q,
+        *(p.reshape(-1, kv, bs, d) for p in pools),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -596,42 +662,36 @@ def paged_gqa_decode(
     holds does not grow with ``W``. It is a ``jax.jit`` of its own with the
     layer traced: the 2-4 attention layers of a decode program call one
     lowered function (one ``tpu_custom_call`` in the program's text)."""
-    b, kv, g, d = q.shape
-    n, _, bs, _ = k_pool.shape[-4:]
-    w = blocks.shape[-1]
-    per_group = paged_decode_grid(b, 1, w, bs, d, k_pool.dtype.itemsize)[1]
-    tiles = pltpu.VMEM((2, per_group, bs, d), k_pool.dtype)
-    head = pl.BlockSpec((1, 1, g, d), lambda i, j, *_: (i, j, 0, 0))
-    pool = pl.BlockSpec(memory_space=pl.ANY)
-    return pl.pallas_call(
-        functools.partial(_gqa_decode_kernel, per_group=per_group, n_blocks=n),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(b, kv),
-            in_specs=[head, pool, pool],
-            out_specs=head,
-            scratch_shapes=[
-                tiles, tiles,
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.SMEM((2,), jnp.int32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        # the steps run in order: each starts the next one's first copies
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-        name="paged_gqa_decode",
-    )(
-        jnp.reshape(layer, (1,)).astype(jnp.int32),
-        blocks.reshape(-1).astype(jnp.int32),
-        logical.reshape(-1).astype(jnp.int32),
-        jnp.minimum(count, w).reshape(-1).astype(jnp.int32),
-        pos.astype(jnp.int32),
-        q,
-        k_pool.reshape(-1, kv, bs, d),
-        v_pool.reshape(-1, kv, bs, d),
-    )
+    return _listed_decode_call(
+        q, (k_pool, v_pool), blocks, logical, count, pos, layer,
+        name="paged_gqa_decode", value_lanes=None, scale_dim=None,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("value_lanes", "scale_dim", "interpret"))
+def paged_mla_decode(
+    q: jnp.ndarray,            # [B, 1, H, D] absorbed queries: [W_UK^T q_nope ; q_pe ; 0]
+    pool: jnp.ndarray,         # [L, N, 1, bs, D] latent rows: [c ; k_pe ; 0]
+    blocks: jnp.ndarray,       # [B, 1, W] int32 pool blocks, as listed
+    logical: jnp.ndarray,      # [B, 1, W] int32 their index in the sequence
+    count: jnp.ndarray,        # [B, 1] int32 entries of the list in use
+    pos: jnp.ndarray,          # [B] int32 query position (keys <= pos)
+    layer: jnp.ndarray,        # int32 scalar, traced
+    *,
+    value_lanes: int,
+    scale_dim: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Absorbed latent attention, the kernel behind ``paged_mla_attention``
+    on a TPU: ``paged_gqa_decode``'s walk over ONE pool whose row is both
+    key and value. A grid step reads a row's ``[bs, R]`` tiles once, scores
+    all ``H`` heads against them in one product at ``1 / sqrt(scale_dim)``,
+    and accumulates ``u = sum p c`` from each tile's first ``value_lanes``
+    lanes. Returns u [B, 1, H, value_lanes]. A ``jax.jit`` of its own with
+    the layer traced: lowered once a program, however many layers call it."""
+    return _listed_decode_call(
+        q, (pool,), blocks, logical, count, pos, layer, name="paged_mla_decode",
+        value_lanes=value_lanes, scale_dim=scale_dim, interpret=interpret)
 
 
 def paged_sparse_attention(
@@ -671,6 +731,243 @@ def paged_sparse_attention(
     probs = jax.nn.softmax(scores, axis=-1)
     probs = jnp.where(count[:, :, None, None] > 0, probs, 0.0).astype(q.dtype)
     return jnp.einsum("bkgs,bksd->bkgd", probs, vc.reshape(b, kv, w * bs, d))
+
+
+def paged_mla_attention(
+    q: jnp.ndarray,            # [B, 1, H, D] absorbed queries: [W_UK^T q_nope ; q_pe ; 0]
+    pool: jnp.ndarray,         # [L, N, 1, bs, D] latent rows: [c ; k_pe ; 0]
+    blocks: jnp.ndarray,       # [B, 1, W] int32 pool blocks, as listed
+    logical: jnp.ndarray,      # [B, 1, W] int32 their index in the sequence
+    count: jnp.ndarray,        # [B, 1] int32 entries of the list in use
+    pos: jnp.ndarray,          # [B] int32 query position (keys <= pos)
+    layer=0,
+    *,
+    value_lanes: int,
+    scale_dim: int,
+) -> jnp.ndarray:
+    """Absorbed latent attention of one query a row: ``u[b, h] = sum_s
+    softmax_s(q[b, h] . row_s / sqrt(scale_dim)) c_s`` over the keys at or
+    before ``pos[b]`` of the ``count[b]`` listed blocks, ``c_s`` a row's
+    first ``value_lanes`` lanes. A row with ``count == 0`` gives 0. Returns
+    [B, 1, H, value_lanes]. On a TPU the kernel ``paged_mla_decode``;
+    elsewhere one gather of the listed blocks, float32 scores and softmax,
+    probabilities cast back to the compute dtype."""
+    if jax.devices()[0].platform == "tpu":
+        record_resolved_impl("paged_mla_decode", "pallas (mosaic)")
+        return paged_mla_decode(
+            q, pool, blocks, logical, count, pos, jnp.asarray(layer, jnp.int32),
+            value_lanes=value_lanes, scale_dim=scale_dim)
+    record_resolved_impl("paged_mla_decode", "xla (gather)")
+    b, _, h, r = q.shape
+    bs = pool.shape[-2]
+    w = blocks.shape[-1]
+    rows = pool[layer, blocks[:, 0], 0].reshape(b, w * bs, r)   # [B, W*bs, R]
+    scores = jnp.einsum("bhr,bsr->bhs", q[:, 0], rows,
+                        preferred_element_type=jnp.float32) / jnp.sqrt(float(scale_dim))
+    key_pos = (logical[:, 0, :, None] * bs + jnp.arange(bs)).reshape(b, w * bs)
+    keep = (jnp.arange(w)[None, :, None] < count[:, :, None]).repeat(bs, axis=2)
+    keep = keep.reshape(b, w * bs) & (key_pos <= pos[:, None])
+    probs = jax.nn.softmax(jnp.where(keep[:, None], scores, _MASKED), axis=-1)
+    probs = jnp.where(count[:, :, None] > 0, probs, 0.0).astype(q.dtype)
+    return jnp.einsum("bhs,bsr->bhr", probs, rows[..., :value_lanes])[:, None]
+
+
+# Keys a grid step of ``mla_prefill_attention`` decompresses and attends: a
+# [T, 512] float32 score tile of a 2,048-query chunk is 4 MB of VMEM.
+_PREFILL_KEYS = 512
+_PE_LANES = 128       # the rotary part of a query and of a row, padded to one lane tile
+
+
+def _mla_prefill_kernel(
+    start_ref,    # scalar prefetch: [1] int32 position of the chunk's first query
+    tiles_ref,    # scalar prefetch: [1] int32 key tiles that hold a visible key
+    q_nope_ref,   # [1, T, dn] one head's queries
+    q_pe_ref,     # [1, T, 128] their rotary part, zero-padded
+    lat_ref,      # [tk, lanes] the row's latent rows of this key tile
+    w_uk_ref,     # [1, R, dn] the head's up-projection to keys
+    w_uv_ref,     # [1, R, dv] and to values
+    o_ref,        # [1, T, dv]
+    top,          # VMEM [T, 1] f32 running max (base 2)
+    total,        # VMEM [T, 1] f32 running sum
+    acc,          # VMEM [T, dv] f32
+    *,
+    rank: int,
+    scale: float,
+):
+    """One (head, key tile) a grid step: the tile's latent rows decompressed
+    to the head's keys and values in VMEM, then attended by the chunk's
+    queries with the softmax online in float32 (base 2, ``scale`` folded into
+    the decompressed keys before their one rounding, and into the rotary
+    queries). A tile past the last visible key is neither fetched (its
+    block index repeats the last one's) nor computed; only tiles that reach
+    past the first query's position pay the causal mask."""
+    j = pl.program_id(1)
+    start, n = start_ref[0], tiles_ref[0]
+    tk = lat_ref.shape[0]
+
+    @pl.when(j == 0)
+    def _init():
+        top[...] = jnp.full_like(top, _MASKED)
+        total[...] = jnp.zeros_like(total)
+        acc[...] = jnp.zeros_like(acc)
+
+    def attend(masked: bool):
+        lat = lat_ref[...]
+        c = lat[:, :rank]
+        k_nope = (jnp.dot(c, w_uk_ref[0], preferred_element_type=jnp.float32)
+                  * scale).astype(c.dtype)
+        v = jnp.dot(c, w_uv_ref[0], preferred_element_type=jnp.float32).astype(c.dtype)
+        q_nope = q_nope_ref[0]
+        q_pe = (q_pe_ref[0].astype(jnp.float32) * scale).astype(c.dtype)
+        nt = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(q_nope, k_nope, nt, preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(q_pe, lat[:, rank:rank + _PE_LANES], nt,
+                                   preferred_element_type=jnp.float32))   # [T, tk]
+        if masked:
+            row = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            col = j * tk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            keep = col <= row
+            s = jnp.where(keep, s, _MASKED)
+        new_top = jnp.maximum(top[...], s.max(axis=1, keepdims=True))
+        p = jnp.exp2(s - new_top)
+        if masked:
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp2(top[...] - new_top)
+        total[...] = total[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc[...] = acc[...] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        top[...] = new_top
+
+    live = j < n
+    unmasked = (j + 1) * tk <= start + 1
+    pl.when(live & unmasked)(lambda: attend(False))
+    pl.when(live & jnp.logical_not(unmasked))(lambda: attend(True))
+
+    @pl.when(j == n - 1)
+    def _out():
+        o_ref[0] = (acc[...] / total[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mla_prefill_attention(
+    q_nope: jnp.ndarray,       # [T, H, dn] a chunk's queries, at start .. start + T - 1
+    q_pe: jnp.ndarray,         # [T, H, dr] their rotary part, rotated
+    latent: jnp.ndarray,       # [S, lanes] the row's latent rows [c ; k_pe ; 0] in order
+    start: jnp.ndarray,        # int32 scalar, the first query's position
+    w_uk: jnp.ndarray,         # [R, H, dn]
+    w_uv: jnp.ndarray,         # [R, H, dv]
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Expanded latent attention of a chunk's consecutive queries, the
+    kernel behind ``paged_mla_prefill`` on a TPU. Grid ``(H, S / 512)``: a
+    step decompresses 512 latent rows to the head's keys ``[c W_UK ; k_pe]``
+    and values ``c W_UV`` and attends all ``T`` queries against them, so
+    what a step holds follows the tile and no score leaves VMEM. Returns
+    [T, H, dv]. Needs ``R`` a multiple of 128 and ``lanes >= R + 128``."""
+    t, h, dn = q_nope.shape
+    dr = q_pe.shape[-1]
+    s_len, lanes = latent.shape
+    r, dv = w_uk.shape[0], w_uv.shape[-1]
+    tk = _PREFILL_KEYS
+    start = jnp.asarray(start, jnp.int32)
+    tiles = jnp.minimum((start + t + tk - 1) // tk, s_len // tk)
+    head = lambda d: pl.BlockSpec((1, t, d), lambda i, j, *_: (i, 0, 0))
+    weight = lambda d: pl.BlockSpec((1, r, d), lambda i, j, *_: (i, 0, 0))
+    rows = pl.BlockSpec((tk, lanes), lambda i, j, _, n: (jnp.minimum(j, n[0] - 1), 0))
+    q_pe = jnp.pad(q_pe, ((0, 0), (0, 0), (0, _PE_LANES - dr)))
+    o = pl.pallas_call(
+        functools.partial(_mla_prefill_kernel, rank=r, scale=LOG2E / (dn + dr) ** 0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(h, s_len // tk),
+            in_specs=[head(dn), head(_PE_LANES), rows, weight(dn), weight(dv)],
+            out_specs=head(dv),
+            scratch_shapes=[
+                pltpu.VMEM((t, 1), jnp.float32),
+                pltpu.VMEM((t, 1), jnp.float32),
+                pltpu.VMEM((t, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((h, t, dv), q_nope.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="mla_prefill",
+    )(start.reshape(1), tiles.reshape(1), q_nope.transpose(1, 0, 2),
+      q_pe.transpose(1, 0, 2), latent, w_uk.transpose(1, 0, 2), w_uv.transpose(1, 0, 2))
+    return o.transpose(1, 0, 2)
+
+
+def paged_mla_prefill(
+    q_nope: jnp.ndarray,       # [T, H, dn] queries of ONE row's chunk
+    q_pe: jnp.ndarray,         # [T, H, dr] their rotary part, rotated
+    pool: jnp.ndarray,         # [L, N, 1, bs, >= R + dr] latent rows: [c ; k_pe ; pad]
+    table: jnp.ndarray,        # [M] int32 the row's block table
+    pos: jnp.ndarray,          # [T] int32 query positions, ascending
+    w_uk: jnp.ndarray,         # [R, H, dn] the latent's up-projection to keys
+    w_uv: jnp.ndarray,         # [R, H, dv] and to values
+    layer=0,
+    tile_blocks: int = 8,
+) -> jnp.ndarray:
+    """Expanded latent attention of a chunk's queries over the row's table,
+    ``tile_blocks`` blocks at a time with a running softmax: each tile's
+    latent rows are decompressed to every head's key ``[c W_UK ; k_pe]`` and
+    value ``c W_UV``, and query ``t`` attends the keys at or before
+    ``pos[t]`` at ``1 / sqrt(dn + dr)``. What a tile holds follows the tile,
+    not the context, and the walk stops at the tile that holds ``pos[-1]``.
+    Returns [T, H, dv]. On a TPU, where the shapes allow it (``R`` a
+    multiple of 128, the rows at least ``R + 128`` lanes, the row's table
+    ``S`` = ``M * bs`` keys a multiple of 512) and ``pos`` are consecutive,
+    the kernel ``mla_prefill_attention`` over the row's rows gathered in
+    order; elsewhere this walk in XLA."""
+    t, h, dn = q_nope.shape
+    r, dr = w_uk.shape[0], q_pe.shape[-1]
+    dv = w_uv.shape[-1]
+    bs = pool.shape[-2]
+    m = table.shape[0]
+    if (jax.devices()[0].platform == "tpu" and r % 128 == 0 and dr <= _PE_LANES
+            and pool.shape[-1] >= r + _PE_LANES and (m * bs) % _PREFILL_KEYS == 0):
+        record_resolved_impl("paged_mla_prefill", "pallas (mosaic)")
+        latent = pool[layer, table, 0].reshape(m * bs, pool.shape[-1])
+        return mla_prefill_attention(q_nope, q_pe, latent, pos[0], w_uk, w_uv)
+    record_resolved_impl("paged_mla_prefill", "xla (tiles)")
+    tile_blocks = min(tile_blocks, m)
+    span = tile_blocks * bs
+    n_tiles = -(-m // tile_blocks)
+    table = jnp.pad(table, (0, n_tiles * tile_blocks - m))
+    scale = 1.0 / jnp.sqrt(jnp.asarray(dn + dr, jnp.float32))
+    dtype = q_nope.dtype
+
+    def tile(i, carry):
+        top, total, acc = carry                            # [H, T], [H, T], [H, T, dv]
+        blocks = jax.lax.dynamic_slice_in_dim(table, i * tile_blocks, tile_blocks)
+        rows = pool[layer, blocks, 0].reshape(span, -1)    # [span, R + dr + pad]
+        latent, k_pe = rows[:, :r], rows[:, r:r + dr]
+        k_nope = jnp.einsum("sr,rhd->hsd", latent, w_uk,
+                            preferred_element_type=jnp.float32).astype(dtype)
+        v = jnp.einsum("sr,rhd->hsd", latent, w_uv,
+                       preferred_element_type=jnp.float32).astype(dtype)
+        s = (jnp.einsum("thd,hsd->hts", q_nope, k_nope, preferred_element_type=jnp.float32)
+             + jnp.einsum("thd,sd->hts", q_pe, k_pe.astype(dtype),
+                          preferred_element_type=jnp.float32)) * scale
+        ok = (i * span + jnp.arange(span))[None] <= pos[:, None]   # [T, span]
+        s = jnp.where(ok[None], s, _MASKED)
+        new_top = jnp.maximum(top, s.max(axis=-1))
+        p = jnp.where(ok[None], jnp.exp(s - new_top[..., None]), 0.0)
+        alpha = jnp.exp(top - new_top)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hts,hsd->htd", p.astype(dtype), v, preferred_element_type=jnp.float32)
+        return new_top, total * alpha + p.sum(axis=-1), acc
+
+    init = (jnp.full((h, t), _MASKED, jnp.float32),
+            jnp.zeros((h, t), jnp.float32),
+            jnp.zeros((h, t, dv), jnp.float32))
+    _, total, acc = jax.lax.fori_loop(
+        0, jnp.minimum(pos[-1] // span + 1, n_tiles), tile, init)
+    o = acc / jnp.maximum(total, 1e-37)[..., None]
+    return o.transpose(1, 0, 2).astype(dtype)
 
 
 def paged_masked_attention(

@@ -5,7 +5,8 @@ The engine's admission, slots, block tables, prefill tick, growth, emit loop,
 ``_Phase`` spans and ``stats`` counters are the same for every family. What
 differs is gathered here, one ``Family`` a family, and the engine reads
 nothing else of a family but its configuration's ``kv_pool_view`` (the shape
-of its paged K/V: which layers hold any, in how many heads):
+of its paged K/V: which layers hold any, in how many heads, and whether one
+latent pool holds what keys and values are made of):
 
 * ``flags``: its jax-free row in ``config.FAMILY_FLAGS`` - presets and
   ``refuse``: what the engine cannot do for it yet, by name, at construction
@@ -44,6 +45,7 @@ from gpt_2_distributed_tpu.config import (
     EXPERT_LAYER,
     FAMILY_FLAGS,
     MAMBA_LAYER,
+    DeepseekV3Config,
     FamilyFlags,
     JambaConfig,
     NemotronHConfig,
@@ -104,6 +106,16 @@ def _jamba_rows(stats: dict, config: JambaConfig, block_size: int,
     return dense_attended(positions)
 
 
+def _deepseek_v3_rows(stats: dict, config: DeepseekV3Config, block_size: int,
+                      positions: np.ndarray, decode: bool) -> int:
+    """A dispatch with a live row asks every held expert of every expert
+    layer (``moe_expert_slots``); every layer attends all the keys up to a
+    query's own."""
+    if len(positions):
+        stats["moe_expert_slots"] += config.n_held * len(config.layers_of(EXPERT_LAYER))
+    return dense_attended(positions)
+
+
 _FLAGS = {row.config_type: row for row in FAMILY_FLAGS}
 
 
@@ -147,8 +159,23 @@ def _jamba() -> Family:
         count_rows=_jamba_rows)
 
 
+def _deepseek_v3() -> Family:
+    from gpt_2_distributed_tpu.models import deepseek_v3
+    from gpt_2_distributed_tpu.serving import deepseek_v3_programs
+
+    return Family(
+        name="a DeepseekV3Config", flags=_FLAGS[DeepseekV3Config],
+        init_params=deepseek_v3.init_params,
+        init_state=deepseek_v3_programs.init_state,
+        chunk_impl=deepseek_v3_programs.chunk_prefill_impl,
+        decode_impl=deepseek_v3_programs.decode_step_impl,
+        counters=deepseek_v3_programs.COUNTERS,
+        count_rows=_deepseek_v3_rows)
+
+
 _BUILDERS: dict[type, Callable[[], Family]] = {
-    SalaConfig: _sala, NemotronHConfig: _nemotron, JambaConfig: _jamba}
+    SalaConfig: _sala, NemotronHConfig: _nemotron, JambaConfig: _jamba,
+    DeepseekV3Config: _deepseek_v3}
 
 
 @functools.cache

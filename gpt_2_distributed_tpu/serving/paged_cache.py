@@ -299,12 +299,17 @@ def init_pools(
     compute_dtype: jnp.dtype = jnp.bfloat16,
     sharding=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The preallocated K and V pools, zeros of ``pool_shape``.
+    """The preallocated K and V pools, zeros of ``pool_shape``. A model
+    whose view says ``latent`` keeps one row a position that holds what
+    keys and values are made of: its one pool comes first, and the second is
+    empty (``[0]``), carried through the step programs untouched.
 
     ``sharding`` (a NamedSharding; block axis over 'data', head axis over
     'tp') places each pool directly on the serving mesh so no device ever
     materializes the full buffer."""
     shape = pool_shape(config, serve, sharded=sharding is not None)
+    if getattr(config, "latent", False):
+        return jnp.zeros(shape, compute_dtype), jnp.zeros((0,), compute_dtype)
     if sharding is not None:
         zeros = jax.jit(
             lambda: jnp.zeros(shape, compute_dtype), out_shardings=sharding
@@ -345,28 +350,32 @@ def draft_serve_view(
 
 
 def pool_bytes(config: GPT2Config, serve: ServeConfig, itemsize: int = 2) -> int:
-    """Bytes of K/V the two pools hold (the serving deployment's KV budget).
+    """Bytes of K/V the two pools hold (the serving deployment's KV budget;
+    a latent view's one pool).
     On a TPU they occupy more: row-major pads D = 64 to the 128 lanes, twice
     this (2.53 GB for 1.5B at 257 blocks, against 1.89 GB block-minor)."""
+    pools = 1 if getattr(config, "latent", False) else 2
     return (
-        2 * config.n_layer * serve.num_blocks * config.n_head
+        pools * config.n_layer * serve.num_blocks * config.n_head
         * serve.block_size * config.head_dim * itemsize
     )
 
 
 def _write_blocks(
     k_pool: jnp.ndarray,   # [L, N, H, bs, D]
-    v_pool: jnp.ndarray,
+    v_pool: jnp.ndarray | None,
     layer,                 # scalar int32
     dst: jnp.ndarray,      # [J] int32 destination blocks
     keep: jnp.ndarray,     # [J, bs] bool — positions of block j to take
     k: jnp.ndarray,        # [J, H, bs, D], or [J, H, 1, D]: one row for all
-    v: jnp.ndarray,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
+    v: jnp.ndarray | None,
+) -> tuple[jnp.ndarray, jnp.ndarray | None]:
     """``pool[layer, dst[j]] = where(keep[j], new[j], what the pool holds)``
     for j in order: each block is read, merged and put back with one
     ``dynamic_update_slice``, so the pool is updated in place whatever its
-    layout, and a position that is not kept keeps its bits."""
+    layout, and a position that is not kept keeps its bits. ``v_pool`` None
+    (with ``v`` None): a latent pool alone, handed back with None."""
+    pools, news = ((k_pool,), (k,)) if v_pool is None else ((k_pool, v_pool), (k, v))
     h, bs, d = k_pool.shape[2:]
 
     def one(j, pools):
@@ -383,10 +392,11 @@ def _write_blocks(
                 ),
                 at,
             )
-            for pool, new in zip(pools, (k, v))
+            for pool, new in zip(pools, news)
         )
 
-    return jax.lax.fori_loop(0, dst.shape[0], one, (k_pool, v_pool))
+    pools = jax.lax.fori_loop(0, dst.shape[0], one, pools)
+    return pools if v_pool is not None else (pools[0], None)
 
 
 def write_rows(
@@ -400,11 +410,13 @@ def write_rows(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The decode step's write: ``pool[layer, blk[b], :, off[b]] = new[b]``
     for every row, in row order (idle rows all land on the null block; the
-    last one wins there, and nothing reads it)."""
+    last one wins there, and nothing reads it). A latent pool alone: ``v_pool``
+    and ``v`` None."""
     bs = k_pool.shape[3]
     keep = jax.lax.iota(jnp.int32, bs)[None] == off[:, None]      # [B, bs]
     return _write_blocks(
-        k_pool, v_pool, layer, blk, keep, k[:, :, None], v[:, :, None]
+        k_pool, v_pool, layer, blk, keep, k[:, :, None],
+        None if v is None else v[:, :, None],
     )
 
 
@@ -427,7 +439,7 @@ def write_chunk(
     so each row's K/V is re-cut at its own block boundaries into
     ``[nb, H, bs, D]`` and written block by block: the partial first and
     last blocks, padding, and table slots past the row's end keep what
-    the pool holds."""
+    the pool holds. A latent pool alone: ``v_pool`` and ``v`` None."""
     r, c, h, d = k.shape
     bs = k_pool.shape[3]
     m = bt.shape[1]
@@ -448,7 +460,7 @@ def write_chunk(
 
     return _write_blocks(
         k_pool, v_pool, layer, dst.reshape(r * nb),
-        keep.reshape(r * nb, bs), blocks(k), blocks(v),
+        keep.reshape(r * nb, bs), blocks(k), None if v is None else blocks(v),
     )
 
 

@@ -66,7 +66,7 @@ def add_model_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--n_layer", type=int, default=None)
     p.add_argument("--first_layer", type=int, default=0,
                    help="with --n_layer and a layer-pattern model "
-                        "(minicpm-sala-*, nemotron*, jamba*): serve --n_layer "
+                        "(minicpm-sala-*, nemotron*, jamba*, kanana2*): serve --n_layer "
                         "consecutive layers of the preset's stack from this "
                         "one on (minicpm-sala-9b: 16 from 9 keeps the "
                         "published ratio of kinds)")
